@@ -71,6 +71,8 @@ def spawn(
             first_port=first_port,
             env=env_base,
         ).run()
+    from pathway_tpu.internals.accelerator import chip_env
+
     run_id = str(uuid.uuid4())
     # fresh per-run key authenticating exchange-mesh frames (all processes
     # share it; engine/distributed.py rejects unauthenticated frames)
@@ -84,6 +86,7 @@ def spawn(
     try:
         for process_id in range(processes):
             proc_env = env_base.copy()
+            proc_env.update(chip_env(process_id, processes, env_base))
             proc_env["PATHWAY_THREADS"] = str(threads)
             proc_env["PATHWAY_PROCESSES"] = str(processes)
             proc_env["PATHWAY_FIRST_PORT"] = str(first_port)

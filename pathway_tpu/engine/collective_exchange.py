@@ -517,8 +517,9 @@ def exchange(
             fetched = np.asarray(out_dev)
             _dres.record_d2h(fetched.nbytes)
         k1 = _time.perf_counter()
-    except Exception:
+    except Exception:  # noqa: BLE001 — nothing pushed yet: host path runs
         COLLECTIVE_STATS["errors"] += 1
+        _device_ops.record_error("collective_exchange")
         return None
     _device_ops.record_kernel(
         "collective_exchange.all_to_all", int((k1 - k0) * 1e9)
@@ -547,18 +548,20 @@ def exchange(
             # host in resident mode — that is the guaranteed net saving
             # even if every part later materializes
             _dres.record_saved(int(out_dev.nbytes) - trimmed_bytes)
-        except Exception:
+        except Exception:  # noqa: BLE001
             # resident egress failed — fetch the whole buffer and run
             # the host decode; nothing was pushed yet, so this is a
             # clean fallback, not a partial delivery
             _dres.RESIDENCY_STATS["declines"] += 1
+            _device_ops.record_error("resident_egress")
             parts = [None] * n
             resident_out = False
             try:
                 fetched = np.asarray(out_dev)
                 _dres.record_d2h(fetched.nbytes)
-            except Exception:
+            except Exception:  # noqa: BLE001
                 COLLECTIVE_STATS["errors"] += 1
+                _device_ops.record_error("collective_exchange")
                 return None
     if not resident_out:
         for d in range(n):

@@ -5,9 +5,10 @@ Three operator cores move to the accelerator, each operating directly on
 the columnar delta-batch arrays (+1/−1 diff semantics included):
 
 - **groupby semigroup reduction** — the per-commit segment reductions of
-  the columnar groupby state machine (``device.segment_count`` +
-  ``device.segment_sum``) become one batch of device scatter-adds over
-  the factorized key ``inverse``.  Dispatch is split from fetch
+  the columnar groupby state machine (``device.segment_count`` + the
+  integer ``device.segment_sum``s; float sums stay on the host) become
+  one batch of device scatter-adds over the factorized key ``inverse``.
+  Dispatch is split from fetch
   (:func:`segment_reduce_dispatch` → :meth:`SegmentReduceJob.fetch`) so
   the kernel launch overlaps the host group-id resolution loop — the
   same overlap discipline as the PR-9 async device pipeline.
@@ -28,14 +29,13 @@ the columnar delta-batch arrays (+1/−1 diff semantics included):
   bit-exact host spec.
 
 Bit-exactness discipline (PR 2): the host NumPy/C++ kernels remain the
-spec.  The device kernels only *reorder additions* (scatter-add) or
-*reproduce a deterministic algorithm* (stable sort matcher) — the
-multiply producing the weights happens on host with NumPy so its
-rounding is the spec's rounding by construction, and padding rows
-contribute exact zeros (a group sum can never be ``-0.0``: the host
-accumulator starts at ``+0.0`` and ``+0.0 + -0.0 == +0.0``).  The
-parity gate in tools/check.py re-runs the corpus with the JAX path
-forced on, per platform.
+spec.  The device kernels only *reorder integer additions*
+(scatter-add, exact in any order) or *reproduce a deterministic
+algorithm* (stable sort matcher) — the multiply producing the weights
+happens on host with NumPy, and padding rows contribute exact zeros.
+Float additions are not reordered: they stay with the spec (see
+:func:`segment_reduce_dispatch`).  The parity gate in tools/check.py
+re-runs the corpus with the JAX path forced on, per platform.
 
 Placement is measurement-driven, not static: the optimizer's placement
 pass (:mod:`pathway_tpu.optimize.placement`) seeds a per-operator
@@ -54,6 +54,7 @@ hysteresis.  ``PATHWAY_TPU_DEVICE_OPS`` is the control surface:
 
 from __future__ import annotations
 
+import logging
 import os
 import sys
 import threading
@@ -68,6 +69,7 @@ __all__ = [
     "forced",
     "hit_counts",
     "kernel_ns",
+    "record_error",
     "record_kernel",
     "reset_counters",
     "segment_reduce_dispatch",
@@ -80,6 +82,9 @@ _LOCK = threading.Lock()
 #: per-kernel launch counts / host-observed ns, mirroring native.hit_counts()
 _HITS: dict[str, int] = {}
 _NS: dict[str, int] = {}
+#: exceptions raised inside a device path whose caller then used the host
+#: result, by site — an error, not a placement decision
+_ERRORS: dict[str, int] = {}
 
 _JAX_OK: bool | None = None
 _BACKEND: str | None | bool = False  # False = not probed yet
@@ -155,6 +160,28 @@ def record_kernel(name: str, ns: int, hits: int = 1) -> None:
         _NS[name] = _NS.get(name, 0) + int(ns)
 
 
+def record_error(site: str) -> None:
+    """Count an exception raised inside a device path. Call it from the
+    ``except`` block that falls back to the host result (the fallback
+    keeps the engine's rollback invariant; it must not hide the failure):
+    the first error at each site is logged with its traceback."""
+    with _LOCK:
+        first = site not in _ERRORS
+        _ERRORS[site] = _ERRORS.get(site, 0) + 1
+    if first:
+        logging.getLogger("pathway_tpu.device_ops").error(
+            "device path %r raised; the host result is used instead "
+            "(counted in device_ops.stats()['errors'], logged once per site)",
+            site,
+            exc_info=True,
+        )
+
+
+def error_counts() -> dict[str, int]:
+    with _LOCK:
+        return dict(_ERRORS)
+
+
 def hit_counts() -> dict[str, int]:
     with _LOCK:
         return dict(_HITS)
@@ -176,6 +203,7 @@ def reset_counters() -> None:
     with _LOCK:
         _HITS.clear()
         _NS.clear()
+        _ERRORS.clear()
 
 
 def stats() -> dict:
@@ -187,6 +215,7 @@ def stats() -> dict:
         "forced": forced(),
         "hit_counts": hit_counts(),
         "kernel_ns": kernel_ns(),
+        "errors": error_counts(),
         "placement": _placement.POLICY.decisions(),
     }
 
@@ -254,8 +283,8 @@ class SegmentReduceJob:
         gdiffs = full[:nu]
         deltas = []
         for o in self._outs:
-            if o is None:
-                deltas.append(None)
+            if o is None or isinstance(o, np.ndarray):  # host float sum
+                deltas.append(o)
                 continue
             arr = np.asarray(o)
             d2h += arr.nbytes
@@ -277,14 +306,17 @@ def segment_reduce_dispatch(
     ``segment_count(inverse, diffs)`` plus one ``segment_sum`` per sum
     column, as a single batch of bucketed scatter-adds.
 
-    The weight products (``values.astype(int64) * diffs`` wrapping int64,
-    ``values * diffs`` float64) are computed on host with NumPy — the
-    device only reorders the additions, which is exact for ints and holds
-    bit-for-bit for floats on every platform the parity gate has run on
-    (XLA's scatter-add ordering is validated, not assumed)."""
+    The integer weight products (``values.astype(int64) * diffs``,
+    wrapping int64) are computed on host with NumPy — the device only
+    reorders the additions, which is exact for ints. Float columns are
+    summed on the host, here, by the spec's own ``segment_sum``: a TPU has
+    no native float64 (XLA emulates it; on a v5e the device sums differed
+    from NumPy by up to 5e-11 relative — CHANGES.md, PR 21), and a sum
+    must not depend on where its batch happened to be placed."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
+    from pathway_tpu.engine import device as _host
     from pathway_tpu.engine import device_residency as _dres
 
     t0 = _time.perf_counter_ns()
@@ -294,7 +326,7 @@ def segment_reduce_dispatch(
     inv = np.zeros(npad, np.int64)
     inv[:n] = inverse
     h2d = inv.nbytes
-    with enable_x64():
+    with jax.enable_x64(True):
         add = _scatter_add()
         inv_d = jnp.asarray(inv)
         w = np.zeros(npad, np.int64)
@@ -314,14 +346,7 @@ def segment_reduce_dispatch(
                     add(jnp.zeros(gpad, jnp.int64), inv_d, jnp.asarray(w))
                 )
             else:
-                w = np.zeros(npad, np.float64)
-                w[:n] = col * diffs
-                h2d += w.nbytes
-                outs.append(
-                    add(
-                        jnp.zeros(gpad, jnp.float64), inv_d, jnp.asarray(w)
-                    )
-                )
+                outs.append(_host.segment_sum(inverse, col, diffs, n_groups))
     _dres.record_h2d(h2d)
     return SegmentReduceJob(gd, outs, n_groups, n, t0)
 
@@ -341,8 +366,8 @@ def _match_pairs_device(
     when present the matcher consumes them in place of re-uploading the
     host array — values identical by construction (both views
     reinterpret the same wire bytes), so pair output cannot differ."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from pathway_tpu.engine import device_residency as _dres
 
@@ -352,7 +377,7 @@ def _match_pairs_device(
     if len(ra) > len(la):
         r_idx, l_idx = _match_pairs_device(ra, la, ra_dev, la_dev)
         return l_idx, r_idx
-    with enable_x64():
+    with jax.enable_x64(True):
         if la_dev is not None:
             la_d = la_dev
             _dres.record_saved(la.nbytes)

@@ -407,19 +407,23 @@ class DeviceResidentColumns(Columns):
         """Device view of packed column ``i`` (an on-device bitcast of
         the column's byte lanes — no transfer), or ``None`` once the
         buffer decayed.  Bit-identical to ``cols[i]`` by construction:
-        both reinterpret the same little-endian bytes."""
+        both reinterpret the same little-endian bytes.  A float64 column
+        has no view on a TPU, which has no native float64: the bitcast
+        there does not preserve the bits (v5e, CHANGES.md PR 21)."""
         dev = object.__getattribute__(self, "_dev_rows")
         if dev is None:
             return None
+        import jax
         from jax import lax
-        from jax.experimental import enable_x64
 
         dtype, width = self._layout[i]
+        if dtype == np.float64 and jax.default_backend() == "tpu":
+            return None
         off = 16 + (8 if self._has_diffs else 0)
         for j in range(i):
             off += self._layout[j][1]
         seg = dev[:, off : off + width]
-        with enable_x64():
+        with jax.enable_x64(True):
             out = lax.bitcast_convert_type(seg, dtype)
             if out.ndim == 2:  # same-width bitcast keeps the byte lane
                 out = out.reshape(out.shape[0])

@@ -23,6 +23,7 @@ Key design points vs the reference:
 from __future__ import annotations
 
 import itertools
+import logging
 import time as _time
 from typing import Any, Callable, Iterable, Sequence
 
@@ -35,6 +36,13 @@ from pathway_tpu.engine.reducers import Reducer
 from pathway_tpu.engine.value import ERROR, Error, Pointer, hash_values, is_error, ref_scalar, rows_differ
 from pathway_tpu.internals import metrics as _metrics
 from pathway_tpu.internals import tracing as _tracing
+
+_LOG = logging.getLogger("pathway_tpu.engine")
+
+
+class EngineError(RuntimeError):
+    """A row-level error that ended the run (``terminate_on_error``)."""
+
 
 #: sink-side row counter; one shared series — the per-commit delta is what
 #: stamps the ingest->sink latency histogram (internals/runner.py)
@@ -157,8 +165,13 @@ class Node:
         flush) that still have to reach sinks, so sinks must not close
         inside ``on_end`` itself."""
 
-    def report(self, key: Pointer | None, message: str) -> None:
-        self.scope.report_error(self, key, message)
+    def report(
+        self,
+        key: Pointer | None,
+        message: str,
+        exc: BaseException | None = None,
+    ) -> None:
+        self.scope.report_error(self, key, message, exc)
 
     def snapshot(self) -> dict[Pointer, tuple]:
         return dict(self.current)
@@ -480,7 +493,7 @@ class BatchApplyNode(Node):
                 if ok:
                     out.append(key, (value,), diff)
                 else:
-                    self.report(key, f"UDF error: {value!r}")
+                    self.report(key, f"UDF error: {value!r}", value)
                     out.append(key, (ERROR,), diff)
         return out
 
@@ -1142,7 +1155,10 @@ class JoinNode(Node):
             ):
                 try:
                     twin = payload.device_column(on_cols[0])
-                except Exception:
+                except Exception:  # noqa: BLE001 — host keys are the spec
+                    from pathway_tpu.engine import device_ops as _dops
+
+                    _dops.record_error("device_column")
                     twin = None
                 if twin is not None:
                     dev_jks = [twin]
@@ -1283,8 +1299,9 @@ class JoinNode(Node):
                     got = _dops.match_pairs(
                         uni[0], uni[1], l_dev=l_dev, r_dev=r_dev
                     )
-                except Exception:
-                    got = None  # device trouble: host matcher is the spec
+                except Exception:  # noqa: BLE001 — host matcher is the spec
+                    _dops.record_error("match_pairs")
+                    got = None
             if got is None:
                 l_idx, r_idx = _match_join_pairs_multi(*uni)
             else:
@@ -1824,7 +1841,8 @@ class _ColumnarGroups:
                     job = _dops.segment_reduce_dispatch(
                         inverse, diffs, vals, nu
                     )
-                except Exception:
+                except Exception:  # noqa: BLE001 — host kernels are the spec
+                    _dops.record_error("segment_reduce")
                     job = None
         if job is None:
             gdiffs = device.segment_count(inverse, diffs, nu)
@@ -2751,6 +2769,10 @@ class Scope:
         self.nodes: list[Node] = []
         self.error_log_default = ErrorLogNode(self)
         self._error_log_stack: list[ErrorLogNode] = [self.error_log_default]
+        #: ``pw.run(terminate_on_error=True)``: the first reported error
+        #: raises out of the run instead of poisoning its row
+        self.terminate_on_error = False
+        self._error_logged: set[int] = set()  # node indexes already logged
         self.worker_index = 0
         self.worker_count = 1
         #: set by the sharded/distributed schedulers: replica node state
@@ -2760,11 +2782,31 @@ class Scope:
 
     # -- error plumbing -----------------------------------------------------
 
-    def report_error(self, node: Node, key: Pointer | None, message: str) -> None:
+    def report_error(
+        self,
+        node: Node,
+        key: Pointer | None,
+        message: str,
+        exc: BaseException | None = None,
+    ) -> None:
         trace = f" at {node.trace}" if node.trace else ""
+        text = f"{node.name}{trace}: {message}"
         # nodes built inside `with pw.local_error_log()` carry their own log
         log = getattr(node, "error_log", None) or self._error_log_stack[-1]
-        log.log(f"{node.name}{trace}: {message}")
+        log.log(text)
+        if self.terminate_on_error:
+            raise EngineError(text) from exc
+        if node.index not in self._error_logged:
+            # a run whose every row fails must not look like success to a
+            # user who reads no error-log table: each operator's first
+            # error goes to the process log, with the traceback if any
+            self._error_logged.add(node.index)
+            _LOG.error(
+                "%s (further errors of this operator go to the error log "
+                "table only)",
+                text,
+                exc_info=exc,
+            )
 
     def error_log(self) -> ErrorLogNode:
         return ErrorLogNode(self)

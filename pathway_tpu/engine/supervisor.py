@@ -137,9 +137,14 @@ class MeshSupervisor:
         self._leader_lost = False
 
     def _build_envs(self) -> list[dict]:
+        from pathway_tpu.internals.accelerator import chip_env
+
         envs: list[dict] = []
         for process_id in range(self.processes):
             proc_env = self._env_base.copy()
+            proc_env.update(
+                chip_env(process_id, self.processes, self._env_base)
+            )
             proc_env["PATHWAY_THREADS"] = str(self.threads)
             proc_env["PATHWAY_PROCESSES"] = str(self.processes)
             proc_env["PATHWAY_FIRST_PORT"] = str(self.first_port)

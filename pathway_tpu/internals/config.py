@@ -46,9 +46,11 @@ class PathwayConfig:
     snapshot_access: str | None = _env_field("PATHWAY_SNAPSHOT_ACCESS")
     license_key: str | None = _env_field("PATHWAY_LICENSE_KEY")
     monitoring_server: str | None = _env_field("PATHWAY_MONITORING_SERVER")
-    terminate_on_error: bool = _env_bool_field(
-        "PATHWAY_TERMINATE_ON_ERROR", "true"
-    )
+    #: raise at the first row-level error instead of poisoning the row to
+    #: ERROR (``pw.run(terminate_on_error=...)`` overrides). Off unless
+    #: asked for: per-row ERROR values and the error-log tables are the
+    #: semantics every pipeline and test here is written against.
+    terminate_on_error: bool = _env_bool_field("PATHWAY_TERMINATE_ON_ERROR")
     process_id: str = _env_field("PATHWAY_PROCESS_ID", "0")
     threads: int = field(default_factory=lambda: _env_int("PATHWAY_THREADS", 1))
     processes: int = field(

@@ -58,6 +58,7 @@ def run(
     debug: bool = False,
     persistence_config: Any = None,
     strict: bool = False,
+    terminate_on_error: bool | None = None,
     **kwargs: Any,
 ) -> None:
     """Execute the captured graph (reference: pw.run, internals/run.py:12).
@@ -69,8 +70,14 @@ def run(
 
     ``strict=True`` runs the pre-execution static analyzer over the built
     graph and raises ``pathway_tpu.analysis.AnalysisError`` on any
-    error-severity finding before any data flows."""
+    error-severity finding before any data flows.
+
+    ``terminate_on_error=True`` (default: ``PATHWAY_TERMINATE_ON_ERROR``,
+    off) raises ``EngineError`` at the first row-level error — a failing
+    UDF, an error value reaching an operator — instead of poisoning the
+    row to ``ERROR``, logging it and carrying on."""
     from pathway_tpu.analysis import runtime as _analysis_runtime
+    from pathway_tpu.internals.accelerator import configure_compile_cache
     from pathway_tpu.internals.config import get_pathway_config
     from pathway_tpu.internals.runner import (
         DistributedGraphRunner,
@@ -78,6 +85,7 @@ def run(
         ShardedGraphRunner,
     )
 
+    configure_compile_cache()
     config = get_pathway_config()
     if persistence_config is None:
         # env-driven persistence (PATHWAY_PERSISTENT_STORAGE etc.,
@@ -119,6 +127,11 @@ def run(
         )
     else:
         runner = GraphRunner(persistence_config=persistence_config)
+
+    if terminate_on_error is None:
+        terminate_on_error = config.terminate_on_error
+    for worker in getattr(runner, "workers", [runner]):
+        worker.scope.terminate_on_error = terminate_on_error
 
     monitor = None
     http_server = None

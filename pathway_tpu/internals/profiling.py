@@ -6,7 +6,7 @@ The metrics plane (internals/metrics.py) answers *how much*, the tracer
 sampler walks every thread's stack (``sys._current_frames()``),
 aggregates them into folded-stack profiles, and tags each sampled stack
 with the scheduler phase it was caught in — ingest / operator /
-exchange / device / serving — the same taxonomy the PR-8 critical-path
+exchange / device / serving — the same categories the PR-8 critical-path
 buckets use, so a profile's phase totals reconcile with
 ``critical_path()`` shares (:func:`reconcile_with_critical_path`).
 
@@ -68,7 +68,7 @@ __all__ = [
     "reconcile_with_critical_path",
 ]
 
-#: phase tags, mirroring the PR-8 span taxonomy / critical-path buckets
+#: phase tags, mirroring the PR-8 span categories / critical-path buckets
 PHASES = ("ingest", "operator", "exchange", "device", "serving", "other")
 
 #: sampler duty-cycle share that triggers a period doubling — the same

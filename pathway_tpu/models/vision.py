@@ -144,8 +144,8 @@ def vision_forward(
     attn_fn=None,
 ) -> jax.Array:
     """``pixels [b, H, W, 3]`` (normalised floats) -> L2-normalised
-    embeddings ``[b, out_dim]``. ``attn_fn=None`` picks the backend
-    default (the Pallas flash kernel on TPU, dense elsewhere)."""
+    embeddings ``[b, out_dim]``. ``attn_fn=None`` picks
+    ``default_attn_fn()`` (dense attention)."""
     if attn_fn is None:
         from pathway_tpu.models.transformer import default_attn_fn
 
@@ -200,8 +200,7 @@ def preprocess_image_u8(img: Any, cfg: VisionConfig):
     """PIL image -> resized ``[H, W, 3]`` uint8. Host keeps bytes small;
     CLIP normalisation happens on device (normalize_u8) — a 4x smaller
     host->device transfer than shipping f32 pixels (38 MB -> 9.6 MB per
-    64-image batch at 224px, the difference between tunnel-bound and
-    compute-bound ingest)."""
+    64-image batch at 224px)."""
     import numpy as np
 
     img = img.convert("RGB").resize(

@@ -4,7 +4,10 @@ The reference's hot loop is native (Rust, src/engine/dataflow.rs); this
 package provides the equivalent native floor for the TPU build's host
 control plane: CPython C++ kernels for per-row object plumbing
 (enginecore.cpp), compiled on first import with g++ and cached next to the
-source. Everything degrades gracefully to the pure-Python implementations
+source under a name that carries the source's content hash — so a copied
+or freshly checked-out tree (whose mtimes mean nothing) builds exactly
+when the source it holds has no binary yet. Binaries are not committed.
+Everything degrades gracefully to the pure-Python implementations
 when no toolchain is available — behavior is identical, only slower.
 
 A failed build or import is NOT silent: the first failure logs one
@@ -25,6 +28,9 @@ Public surface:
 
 from __future__ import annotations
 
+import contextlib
+import glob
+import hashlib
 import importlib.util
 import logging
 import os
@@ -60,35 +66,49 @@ def _note_failure(message: str, *, warn: bool = True) -> None:
         )
 
 
+_CXXFLAGS = ("-O3", "-std=c++17", "-shared", "-fPIC")
+
+
 def _so_path() -> str:
+    """The binary for THIS source, interpreter and flag set."""
     tag = f"cpython-{sys.version_info.major}{sys.version_info.minor}"
-    return os.path.join(_DIR, f"_enginecore.{tag}.so")
+    digest = hashlib.sha256()
+    with open(_SRC, "rb") as src:
+        digest.update(src.read())
+    digest.update(" ".join(_CXXFLAGS).encode())
+    return os.path.join(
+        _DIR, f"_enginecore.{tag}.{digest.hexdigest()[:16]}.so"
+    )
 
 
 def _build() -> str | None:
     so = _so_path()
-    if os.path.exists(so) and os.path.getmtime(so) >= os.path.getmtime(_SRC):
+    if os.path.exists(so):
         return so
     include = sysconfig.get_path("include")
     import numpy as np
 
+    # a private temporary name: processes that start together on a fresh
+    # tree each compile, and whichever renames last wins an identical file
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = [
         "g++",
-        "-O3",
-        "-std=c++17",
-        "-shared",
-        "-fPIC",
+        *_CXXFLAGS,
         f"-I{include}",
         f"-I{np.get_include()}",
         _SRC,
         "-o",
-        so + ".tmp",
+        tmp,
     ]
     try:
         subprocess.run(
             cmd, check=True, capture_output=True, text=True, timeout=120
         )
-        os.replace(so + ".tmp", so)
+        os.replace(tmp, so)
+        for stale in glob.glob(os.path.join(_DIR, "_enginecore.*.so")):
+            if stale != so:
+                with contextlib.suppress(OSError):  # a sibling got there first
+                    os.remove(stale)
         return so
     except (subprocess.SubprocessError, OSError) as e:
         detail = getattr(e, "stderr", "") or str(e)

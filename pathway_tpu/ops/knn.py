@@ -144,6 +144,7 @@ def knn_search(
     return top_scores, top_idx
 
 
+@functools.partial(jax.jit, static_argnames=("k", "mesh", "metric"))
 def knn_search_sharded(
     state: DeviceKnnState,
     queries: jax.Array,

@@ -24,27 +24,15 @@ def named_sharding(mesh: Mesh, *spec: Any) -> NamedSharding:
 def shard_map_norep(
     fn: Callable, *, mesh: Mesh, in_specs: Any, out_specs: Any
 ) -> Callable:
-    """``shard_map`` with replication checking off, on any jax this repo
-    meets: >= 0.5 exposes it at top level (``check_vma``), older builds
-    only under ``jax.experimental.shard_map`` (``check_rep``). The kernels
-    here all reduce across an axis inside the mapped function, which the
+    """``jax.shard_map`` with replication checking off. The kernels here
+    all reduce across an axis inside the mapped function, which the
     checker cannot see through — hence always off."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -58,8 +58,7 @@ class BruteForceKnnFactory(TpuKnnFactory):
 class HostKnnFactory(TpuKnnFactory):
     """CPU/NumPy twin of :class:`TpuKnnFactory` — builds the
     :class:`~pathway_tpu.engine.external_index.HostKnnIndex` bit-exact
-    host spec.  Used by the parity corpus and as the accelerator-free
-    fallback for the streaming-RAG bench when the device probe fails."""
+    host spec, used by the parity corpus."""
 
     def build(self) -> Any:
         from pathway_tpu.engine.external_index import HostKnnIndex
