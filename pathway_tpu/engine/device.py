@@ -33,6 +33,7 @@ from typing import Any, Sequence
 import numpy as np
 
 from pathway_tpu.engine import expression as ex
+from pathway_tpu.internals import tracing as _tracing
 from pathway_tpu.native import kernels as _native
 
 # Batches smaller than this are cheaper to run through the per-row
@@ -463,7 +464,12 @@ class DeviceBatchHandle:
         if self._host is None:
             from pathway_tpu.engine import device_residency as _dres
 
-            self._host = np.asarray(self.dev)
+            # blocks until the device has produced the batch: on the run
+            # thread (a sink reading a lazy row) or on the pipeline's
+            # completion worker (decay)
+            with _tracing.stage("device.fetch_rows", wait=True) as fetch:
+                self._host = np.asarray(self.dev)
+                fetch.add(d2h_bytes=int(self._host.nbytes))
             _dres.record_d2h(int(self._host.nbytes))
         return self._host
 

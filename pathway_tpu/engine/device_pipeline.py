@@ -336,54 +336,40 @@ class DevicePipeline:
                 handle.decay()
             return
         self._raise_pending()
-        t0 = _time.perf_counter()
-        for handle in handles:
-            handle.prefetch()  # start the DMA; never await it here
-        self._ensure_worker()
-        blocked = False
-        ctx = _tracing.current()
-        with self._cv:
-            while (
-                len(self._staged)
-                + (1 if self._active_time is not None else 0)
-                >= self.controller.depth
-            ):
-                blocked = True
-                if ctx is not None:
-                    bp0 = _time.perf_counter()
-                self._cv.wait(timeout=60.0)
-                if ctx is not None:
+        with _tracing.detail(
+            "commit.device_stage", cat="pipeline", batches=len(handles)
+        ):
+            t0 = _time.perf_counter()
+            for handle in handles:
+                handle.prefetch()  # start the DMA; never await it here
+            self._ensure_worker()
+            blocked = False
+            with self._cv:
+                while (
+                    len(self._staged)
+                    + (1 if self._active_time is not None else 0)
+                    >= self.controller.depth
+                ):
+                    blocked = True
                     # genuine pipeline stall: host blocked on the device
                     # stage — attributed to the queue_wait bucket
-                    ctx.span(
-                        "device-backpressure",
-                        "wait",
-                        bp0,
-                        _time.perf_counter(),
-                        inflight=len(self._staged),
-                    )
-                err = self._take_error_locked()
-                if err is not None:
-                    raise err
-            self._staged.append((int(time), handles, t0))
-            self._g_depth.value = float(
-                len(self._staged)
-                + (1 if self._active_time is not None else 0)
-            )
-            self._cv.notify_all()
-            staged_depth = len(self._staged)
-            occupancy = self._occupancy
-        self.controller.observe(
-            staged_depth=staged_depth, blocked=blocked, occupancy=occupancy
-        )
-        if ctx is not None:
-            ctx.span(
-                "device-dispatch",
-                "pipeline",
-                t0,
-                _time.perf_counter(),
-                batches=len(handles),
-                inflight=staged_depth,
+                    with _tracing.stage(
+                        "commit.device_wait", cat="wait", wait=True
+                    ):
+                        self._cv.wait(timeout=60.0)
+                    err = self._take_error_locked()
+                    if err is not None:
+                        raise err
+                self._staged.append((int(time), handles, t0))
+                self._g_depth.value = float(
+                    len(self._staged)
+                    + (1 if self._active_time is not None else 0)
+                )
+                self._cv.notify_all()
+                staged_depth = len(self._staged)
+                occupancy = self._occupancy
+            self.controller.observe(
+                staged_depth=staged_depth, blocked=blocked, occupancy=occupancy
             )
 
     # -- completion side (worker thread) -------------------------------------
@@ -441,7 +427,7 @@ class DevicePipeline:
         _dres.decay_resident_batches()
         if self._worker is None:
             return
-        with self._cv:
+        with _tracing.stage("commit.device_wait", wait=True), self._cv:
             while (self._staged and self._staged[0][0] <= time) or (
                 self._active_time is not None and self._active_time <= time
             ):
@@ -455,7 +441,7 @@ class DevicePipeline:
         _dres.decay_resident_batches()
         if self._worker is None:
             return
-        with self._cv:
+        with _tracing.stage("commit.device_wait", wait=True), self._cv:
             while self._staged or self._active_time is not None:
                 self._cv.wait(timeout=60.0)
         self._raise_pending()

@@ -15,12 +15,10 @@ top-k. Host state is only the key<->slot mapping.
 
 from __future__ import annotations
 
-import time as _time
 from typing import Any, NamedTuple, Protocol, Sequence
 
 import numpy as np
 
-from pathway_tpu.engine import device_ops as _dops
 from pathway_tpu.engine.batch import DeltaBatch
 from pathway_tpu.engine.graph import Node, Scope
 from pathway_tpu.engine.value import Pointer, is_error
@@ -172,7 +170,6 @@ class DeviceKnnIndex:
         n = len(slots)
         if n == 0:
             return
-        t0 = _time.perf_counter_ns()
         b = _bucket(n)
         slots_arr = np.full((b,), 0, np.int32)
         slots_arr[:n] = slots
@@ -182,22 +179,28 @@ class DeviceKnnIndex:
         valid_arr[:n] = set_valid
         enabled = np.zeros((b,), bool)
         enabled[:n] = True
-        _dres.record_h2d(
+        h2d = (
             slots_arr.nbytes + vec_arr.nbytes + valid_arr.nbytes
             + enabled.nbytes
         )
-        self.state = knn_update(
-            self.state,
-            jnp.asarray(slots_arr),
-            jnp.asarray(vec_arr),
-            jnp.asarray(valid_arr),
-            jnp.asarray(enabled),
-        )
-        _dops.record_kernel(
-            "knn_update", _time.perf_counter_ns() - t0, hits=n
-        )
+        with _tracing.stage("knn.add.dispatch", rows=n, h2d_bytes=h2d):
+            _dres.record_h2d(h2d)
+            self.state = knn_update(
+                self.state,
+                jnp.asarray(slots_arr),
+                jnp.asarray(vec_arr),
+                jnp.asarray(valid_arr),
+                jnp.asarray(enabled),
+            )
 
     def add(self, keys: Sequence[Pointer], vectors: Sequence[Any]) -> None:
+        # the host's share of an add (key and slot maps, stacking, padding)
+        # is this stage's self time: the uploads and enqueues lie inside it
+        # as ``knn.add.dispatch``
+        with _tracing.stage("knn.add.host", rows=len(keys)):
+            self._add(keys, vectors)
+
+    def _add(self, keys: Sequence[Pointer], vectors: Sequence[Any]) -> None:
         from pathway_tpu.engine.device import LazyDeviceVector
 
         # Group lazy rows by their parent device batch — NOT by contiguous
@@ -298,25 +301,21 @@ class DeviceKnnIndex:
         enabled[:n] = True
         idx_pad = np.zeros((b,), np.int32)
         idx_pad[:n] = indices
-        t0 = _time.perf_counter_ns()
         # only the control arrays go up — the vectors are already resident
-        _dres.record_h2d(
-            slots_arr.nbytes + enabled.nbytes + idx_pad.nbytes
-        )
-        enabled_dev = jnp.asarray(enabled)
-        gathered = _gather_pad(
-            dev, jnp.asarray(idx_pad), enabled_dev
-        )
-        self.state = knn_update(
-            self.state,
-            jnp.asarray(slots_arr),
-            gathered,
-            enabled_dev,
-            enabled_dev,
-        )
-        _dops.record_kernel(
-            "knn_update", _time.perf_counter_ns() - t0, hits=n
-        )
+        h2d = slots_arr.nbytes + enabled.nbytes + idx_pad.nbytes
+        with _tracing.stage("knn.add.dispatch", rows=n, h2d_bytes=h2d):
+            _dres.record_h2d(h2d)
+            enabled_dev = jnp.asarray(enabled)
+            gathered = _gather_pad(
+                dev, jnp.asarray(idx_pad), enabled_dev
+            )
+            self.state = knn_update(
+                self.state,
+                jnp.asarray(slots_arr),
+                gathered,
+                enabled_dev,
+                enabled_dev,
+            )
         return True
 
     def remove(self, keys: Sequence[Pointer]) -> None:
@@ -413,43 +412,53 @@ class DeviceKnnIndex:
             return []
         k_eff = min(k, self.capacity)
         b = _bucket(n)
-        q_dev = None
         from pathway_tpu.engine.device import device_runs
 
-        runs = device_runs(list(queries))
-        if (
-            len(runs) == 1
-            and runs[0][2] is not None
-            and tuple(runs[0][2].shape[1:]) == (self.dim,)
-        ):
-            # query vectors still live on device (embedder output): gather
-            # there and fetch only the top-k — one small round trip total
-            dev, indices = runs[0][2], runs[0][3]
-            idx_pad = np.zeros((b,), np.int32)
-            idx_pad[:n] = indices
-            enabled = np.zeros((b,), bool)
-            enabled[:n] = True
-            q_dev = _gather_pad(dev, jnp.asarray(idx_pad), jnp.asarray(enabled))
-        if q_dev is None:
-            q = np.zeros((b, self.dim), np.float32)
-            for i, vec in enumerate(queries):
-                q[i] = np.asarray(vec, np.float32).reshape(self.dim)
-            _dres.record_h2d(q.nbytes)
-            q_dev = jnp.asarray(q)
-        t0 = _time.perf_counter_ns()
-        if self.mesh is not None:
-            scores, slots = knn_search_sharded(
-                self.state, q_dev, k_eff, self.mesh, self.metric
-            )
-        else:
-            scores, slots = knn_search(
-                self.state, q_dev, k_eff, self.metric
-            )
-        packed = np.asarray(_pack_results(scores, slots))
+        with _tracing.stage(
+            "knn.search.dispatch", queries=n, padded_queries=b
+        ) as dispatch:
+            q_dev = None
+            runs = device_runs(list(queries))
+            if (
+                len(runs) == 1
+                and runs[0][2] is not None
+                and tuple(runs[0][2].shape[1:]) == (self.dim,)
+            ):
+                # query vectors still live on device (embedder output):
+                # gather there and fetch only the top-k — one small round
+                # trip total
+                dev, indices = runs[0][2], runs[0][3]
+                idx_pad = np.zeros((b,), np.int32)
+                idx_pad[:n] = indices
+                enabled = np.zeros((b,), bool)
+                enabled[:n] = True
+                h2d = idx_pad.nbytes + enabled.nbytes
+                q_dev = _gather_pad(
+                    dev, jnp.asarray(idx_pad), jnp.asarray(enabled)
+                )
+            if q_dev is None:
+                q = np.zeros((b, self.dim), np.float32)
+                for i, vec in enumerate(queries):
+                    q[i] = np.asarray(vec, np.float32).reshape(self.dim)
+                h2d = q.nbytes
+                q_dev = jnp.asarray(q)
+            _dres.record_h2d(h2d)
+            dispatch.add(h2d_bytes=h2d)
+            if self.mesh is not None:
+                scores, slots = knn_search_sharded(
+                    self.state, q_dev, k_eff, self.mesh, self.metric
+                )
+            else:
+                scores, slots = knn_search(
+                    self.state, q_dev, k_eff, self.metric
+                )
+            packed_dev = _pack_results(scores, slots)
+        # the one blocking read of a search: the scan queues behind
+        # whatever the device was given before it
+        with _tracing.stage("knn.search.fetch", wait=True, queries=n) as fetch:
+            packed = np.asarray(packed_dev)
+            fetch.add(d2h_bytes=packed.nbytes)
         _dres.record_d2h(packed.nbytes)
-        _dops.record_kernel(
-            "knn_search", _time.perf_counter_ns() - t0, hits=n
-        )
         scores = packed[0].view(np.float32)[:n]
         slots = packed[1][:n]
         out: list[list[tuple[Pointer, float]]] = []
@@ -707,27 +716,20 @@ class ExternalIndexNode(Node):
                 rm_keys.append(key)
         # removes first so a same-commit delete+insert of a key nets to add
         if rm_keys or add_keys:
-            import time as _t
-
-            t0 = _t.perf_counter()
-            if rm_keys:
-                add_set = set(add_keys)
-                self.ext_index.remove(
-                    [k_ for k_ in rm_keys if k_ not in add_set]
-                )
-            if add_keys:
-                self.ext_index.add(add_keys, add_vecs)
+            with _tracing.stage(
+                "knn.update",
+                cat="pipeline",
+                adds=len(add_keys),
+                removes=len(rm_keys),
+            ):
+                if rm_keys:
+                    add_set = set(add_keys)
+                    self.ext_index.remove(
+                        [k_ for k_ in rm_keys if k_ not in add_set]
+                    )
+                if add_keys:
+                    self.ext_index.add(add_keys, add_vecs)
             _KNN_UPDATES.inc(len(rm_keys) + len(add_keys))
-            ctx = _tracing.current()
-            if ctx is not None:
-                ctx.span(
-                    "knn-update",
-                    "pipeline",
-                    t0,
-                    _t.perf_counter(),
-                    adds=len(add_keys),
-                    removes=len(rm_keys),
-                )
 
         # 2. answer new queries as-of-now; retract answers of deleted queries
         out = DeltaBatch()
@@ -751,22 +753,14 @@ class ExternalIndexNode(Node):
                     limit = int(lv)
             pending.append((key, vec, limit))
         if pending:
-            import time as _t
-
             max_k = max(limit for _k, _v, limit in pending)
-            t0 = _t.perf_counter()
-            results = self.ext_index.search([v for _k, v, _l in pending], max_k)
-            _KNN_QUERIES.inc(len(pending))
-            ctx = _tracing.current()
-            if ctx is not None:
-                ctx.span(
-                    "knn-search",
-                    "pipeline",
-                    t0,
-                    _t.perf_counter(),
-                    queries=len(pending),
-                    k=max_k,
+            with _tracing.stage(
+                "knn.search", cat="pipeline", queries=len(pending)
+            ):
+                results = self.ext_index.search(
+                    [v for _k, v, _l in pending], max_k
                 )
+            _KNN_QUERIES.inc(len(pending))
             for (key, _vec, limit), hits in zip(pending, results):
                 hits = hits[:limit]
                 # re-query of a live key replaces its previous answer (unless

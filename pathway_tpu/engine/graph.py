@@ -2679,16 +2679,18 @@ class SubscribeNode(Node):
         batch = self.take(0)
         rows = 0
         retractions = 0
-        for key, row, diff in batch:
-            if self.skip_errors and any(is_error(v) for v in row):
-                self.report(key, "error value in output row")
-                continue
-            self._saw_data = True
-            rows += 1
-            if diff < 0:
-                retractions += 1
-            if self._on_change is not None:
-                self._on_change(key, row, time, diff)
+        with _tracing.stage("sink.emit", cat="sink") as emit:
+            for key, row, diff in batch:
+                if self.skip_errors and any(is_error(v) for v in row):
+                    self.report(key, "error value in output row")
+                    continue
+                self._saw_data = True
+                rows += 1
+                if diff < 0:
+                    retractions += 1
+                if self._on_change is not None:
+                    self._on_change(key, row, time, diff)
+            emit.add(rows=rows)
         if rows:
             _OUTPUT_ROWS.inc(rows)
             tr = _tracing.current()
@@ -3082,8 +3084,10 @@ class Scheduler:
     def propagate(self, time: int) -> None:
         scope = self.scope
         probe = self.probe
-        trace = _tracing.current()
-        if probe or trace is not None:
+        # an operator's sweep is a stage only while a sampled commit or a
+        # profiler session is there to show it: no metric reads it
+        detail = _tracing.detail_on()
+        if probe:
             import time as _walltime
         while True:
             dirty = [n for n in scope.nodes if n.has_pending()]
@@ -3104,24 +3108,25 @@ class Scheduler:
             for node in scope.nodes:
                 if not node.has_pending():
                     continue
-                if probe or trace is not None:
+                if probe:
                     t0 = _walltime.perf_counter()
-                out = node.process(time)
-                if out is None:
-                    out = DeltaBatch()
-                # no eager consolidation: consumers consolidate in take()
-                # (cached), lazy state drain consolidates before applying
-                node._defer_state(out)
-                if trace is not None:
-                    t1 = _walltime.perf_counter()
-                    trace.span(
-                        getattr(node, "name", None)
-                        or type(node).__name__,
-                        "sink" if isinstance(node, SubscribeNode) else "op",
-                        t0,
-                        t1,
-                        node=node.index,
+                with (
+                    _tracing.stage(
+                        "op." + type(node).__name__,
+                        cat="sink" if isinstance(node, SubscribeNode) else "op",
+                        label=getattr(node, "name", None),
+                        batches=1,
                     )
+                    if detail
+                    else _tracing.NO_STAGE
+                ):
+                    out = node.process(time)
+                    if out is None:
+                        out = DeltaBatch()
+                    # no eager consolidation: consumers consolidate in
+                    # take() (cached), lazy state drain consolidates
+                    # before applying
+                    node._defer_state(out)
                 if probe:
                     st = self._stats_of(node)
                     st.time_spent += _walltime.perf_counter() - t0

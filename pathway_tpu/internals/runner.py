@@ -89,6 +89,14 @@ def _take_ingest_stamp(
     return best, sources
 
 
+def _commit_wait_ns(stamp: float | None, commit_started: float) -> int:
+    """How long the oldest row of this commit sat in its session before
+    the commit began (both ``time.monotonic`` stamps); 0 with no row."""
+    if stamp is None:
+        return 0
+    return max(0, int((commit_started - stamp) * 1e9))
+
+
 def _observe_commit_latency(
     stamp: float | None, commit_started: float, rows_before: float
 ) -> None:
@@ -105,14 +113,22 @@ def _observe_commit_latency(
     _INGEST_LATENCY.observe_n(max(0.0, _time.monotonic() - origin), rows)
 
 
+def _entries_taken(drivers: list) -> int:
+    """Rows the connector drivers have fed to their sessions so far."""
+    return sum(
+        getattr(getattr(d, "driver", d), "entries_total", 0) for d in drivers
+    )
+
+
 def _pump_drivers(w0: "GraphRunner", drivers: list, on_data, on_idle=None) -> None:
     """The one streaming poll loop (GraphRunner / ShardedGraphRunner /
     DistributedGraphRunner all drive it): poll every connector driver,
-    accumulate rows into input sessions, and call ``on_data()`` (which
-    commits) when a driver's autocommit deadline expires or a driver
-    finishes. Also drains passive loopback sources (AsyncTransformer) once
-    no live driver can still feed them, and backs off exponentially when
-    idle (``on_idle`` hooks extra idle work, e.g. coordinator pings).
+    accumulate rows into input sessions, and call ``on_data(commit)``
+    (which commits, inside the ``commit`` stage it is handed) when a
+    driver's autocommit deadline expires or a driver finishes. Also drains
+    passive loopback sources (AsyncTransformer) once no live driver can
+    still feed them, and backs off exponentially when idle (``on_idle``
+    hooks extra idle work, e.g. coordinator pings).
 
     The autocommit window (``autocommit_duration_ms`` on each connector,
     reference python/pathway/io/python/__init__.py read kwarg) is what
@@ -128,7 +144,21 @@ def _pump_drivers(w0: "GraphRunner", drivers: list, on_data, on_idle=None) -> No
     idle_spins = 0
     pending = False  # rows sit in input sessions awaiting a commit
     deadline = 0.0
+    # consecutive sweeps share one ``pump.poll`` stage: it closes when the
+    # loop commits or sleeps, so a busy feed makes one stage, not thousands
+    # (a raise leaves it open and uncounted; the run's own stage unwinds it)
+    poll = None
+    rows_before = 0
+
+    def end_poll() -> None:
+        nonlocal poll
+        _tracing.end(poll, rows=_entries_taken(drivers) - rows_before)
+        poll = None
+
     while live:
+        if poll is None:
+            poll = _tracing.begin("pump.poll")
+            rows_before = _entries_taken(drivers)
         produced = False
         flush_now = False
         for d in list(live):
@@ -150,7 +180,9 @@ def _pump_drivers(w0: "GraphRunner", drivers: list, on_data, on_idle=None) -> No
                 deadline = min(deadline, ac_deadline) if pending else ac_deadline
                 pending = True
         if pending and (flush_now or _time.monotonic() >= deadline):
-            on_data()
+            end_poll()
+            with _tracing.stage("commit") as commit:
+                on_data(commit)
             pending = False
             idle_spins = 0
             continue
@@ -159,9 +191,11 @@ def _pump_drivers(w0: "GraphRunner", drivers: list, on_data, on_idle=None) -> No
             continue  # keep draining the feed until the window closes
         if pending:
             # nothing new this sweep: sleep out (a slice of) the window
-            _time.sleep(
-                min(max(deadline - _time.monotonic(), 0.0), 0.001)
-            )
+            end_poll()
+            with _tracing.stage("pump.sleep"):
+                _time.sleep(
+                    min(max(deadline - _time.monotonic(), 0.0), 0.001)
+                )
             continue
         notified = False
         if live and all(
@@ -177,8 +211,10 @@ def _pump_drivers(w0: "GraphRunner", drivers: list, on_data, on_idle=None) -> No
                 notified = True
                 break
         if not notified:
+            end_poll()
             idle_spins += 1
-            _time.sleep(min(0.001 * idle_spins, 0.05))
+            with _tracing.stage("pump.sleep"):
+                _time.sleep(min(0.001 * idle_spins, 0.05))
             if on_idle is not None:
                 on_idle()
 
@@ -1028,7 +1064,8 @@ class GraphRunner:
         import time as _time
 
         t0 = _time.monotonic()
-        sched.run_static()
+        with _tracing.stage("commit"):
+            sched.run_static()
         if _serving.enabled():
             _device_pipeline.drain_until(sched.time)
             _serving.publish_on_commit([self.scope], sched.time)
@@ -1037,6 +1074,7 @@ class GraphRunner:
             self.monitor.on_commit(0, t0)
         return sched
 
+    @_tracing.traced_run
     def run(self) -> Scheduler:
         """Run to completion: static commit if no drivers, else the streaming
         loop (poll drivers, commit, until all report done)."""
@@ -1063,7 +1101,8 @@ class GraphRunner:
         if persistent:
             # flush replayed events as the first commit so downstream state
             # is rebuilt even if no new input arrives
-            sched.commit()
+            with _tracing.stage("commit"):
+                sched.commit()
         snapshot_mgr = self._operator_snapshot_manager()
         if snapshot_mgr is not None:
             # operator persistence: restore state directly, no event replay;
@@ -1077,11 +1116,15 @@ class GraphRunner:
                 batch = node.initial_batch()
                 if batch:
                     node.push(0, batch)
-        sched.propagate(sched.time)
+        # the static sources' commit, and the last one in finish(), are
+        # ``commit`` stages like the pump's: operators run nowhere else
+        with _tracing.stage("commit"):
+            sched.propagate(sched.time)
         sched.time += 1
-        def on_data() -> None:
+        def on_data(commit) -> None:
             commit_started = _time.monotonic()
             stamp, sources = _take_ingest_stamp(self.drivers)
+            commit.add(commit_wait_ns=_commit_wait_ns(stamp, commit_started))
             rows_before = _OUT_ROWS.value
             ctx = _tracing.TRACER.begin(
                 sched.time, origin_mono=stamp, sources=sources
@@ -1091,25 +1134,28 @@ class GraphRunner:
             _metrics.FLIGHT.record("commit", time=time)
             if ctx is not None:
                 _tracing.TRACER.end(time)
-            serving = _serving.enabled()
-            if persistent or snapshot_mgr is not None or serving:
-                # exactly-once seam: a checkpoint/offset for commit N may
-                # only be cut once N's staged device work has completed
-                # (read snapshots sit on the same seam: a published view
-                # must contain all of commit N, none of N+1)
-                _device_pipeline.drain_until(time)
-            for driver in persistent:
-                driver.on_commit(time)
-            if snapshot_mgr is not None:
-                snapshot_mgr.on_commit(self.scope, self.drivers, time)
-            if serving:
-                _serving.publish_on_commit([self.scope], time)
-            if self.monitor is not None:
-                self._sync_monitor_connectors()
-                self.monitor.on_commit(time, commit_started)
+            with _tracing.detail("commit.after"):
+                serving = _serving.enabled()
+                if persistent or snapshot_mgr is not None or serving:
+                    # exactly-once seam: a checkpoint/offset for commit N
+                    # may only be cut once N's staged device work has
+                    # completed (read snapshots sit on the same seam: a
+                    # published view must contain all of commit N, none
+                    # of N+1)
+                    _device_pipeline.drain_until(time)
+                for driver in persistent:
+                    driver.on_commit(time)
+                if snapshot_mgr is not None:
+                    snapshot_mgr.on_commit(self.scope, self.drivers, time)
+                if serving:
+                    _serving.publish_on_commit([self.scope], time)
+                if self.monitor is not None:
+                    self._sync_monitor_connectors()
+                    self.monitor.on_commit(time, commit_started)
 
         _pump_drivers(self, self.drivers, on_data)
-        sched.finish()
+        with _tracing.stage("commit"):
+            sched.finish()
         _tracing.TRACER.export()
         for driver in persistent:
             driver.on_commit(sched.time)
@@ -1227,6 +1273,7 @@ class ShardedGraphRunner:
         self.scheduler = sched  # telemetry sampler reads stats here
         return sched
 
+    @_tracing.traced_run
     def run(self, sched=None):
         import time as _time
 
@@ -1247,11 +1294,13 @@ class ShardedGraphRunner:
         if self.monitor is not None:
             # aggregated cross-worker operator stats (ShardedScheduler.stats)
             self.monitor.scheduler = sched
-        sched.commit()
+        with _tracing.stage("commit"):
+            sched.commit()
 
-        def on_data() -> None:
+        def on_data(commit) -> None:
             started = _time.monotonic()
             stamp, sources = _take_ingest_stamp(drivers)
+            commit.add(commit_wait_ns=_commit_wait_ns(stamp, started))
             rows_before = _OUT_ROWS.value
             ctx = _tracing.TRACER.begin(
                 sched.time, origin_mono=stamp, sources=sources
@@ -1261,25 +1310,29 @@ class ShardedGraphRunner:
             _metrics.FLIGHT.record("commit", time=time)
             if ctx is not None:
                 _tracing.TRACER.end(time)
-            serving = _serving.enabled()
-            if persistent or snapshot_mgr is not None or serving:
-                # exactly-once seam: checkpoint only fully-completed commits
-                _device_pipeline.drain_until(time)
-            for d in persistent:
-                d.on_commit(time)
-            if snapshot_mgr is not None:
-                snapshot_mgr.on_commit(scopes, drivers, time)
-            if serving:
-                # one snapshot spanning every worker replica: reads merge
-                # the key-sharded views back into the synchronous answer
-                _serving.publish_on_commit(scopes, time)
-            if self.monitor is not None:
-                w0.monitor = self.monitor
-                w0._sync_monitor_connectors()
-                self.monitor.on_commit(time, started)
+            with _tracing.detail("commit.after"):
+                serving = _serving.enabled()
+                if persistent or snapshot_mgr is not None or serving:
+                    # exactly-once seam: checkpoint only fully-completed
+                    # commits
+                    _device_pipeline.drain_until(time)
+                for d in persistent:
+                    d.on_commit(time)
+                if snapshot_mgr is not None:
+                    snapshot_mgr.on_commit(scopes, drivers, time)
+                if serving:
+                    # one snapshot spanning every worker replica: reads
+                    # merge the key-sharded views back into the
+                    # synchronous answer
+                    _serving.publish_on_commit(scopes, time)
+                if self.monitor is not None:
+                    w0.monitor = self.monitor
+                    w0._sync_monitor_connectors()
+                    self.monitor.on_commit(time, started)
 
         _pump_drivers(w0, drivers, on_data)
-        sched.finish()
+        with _tracing.stage("commit"):
+            sched.finish()
         if not drivers and _serving.enabled():
             # static run: the single up-front commit bypassed on_data
             _device_pipeline.drain_until(sched.time)
@@ -1407,6 +1460,7 @@ class DistributedGraphRunner:
             self.workers, attach=self.process_id == 0
         )
 
+    @_tracing.traced_run
     def run(self):
         from pathway_tpu.engine.distributed import (
             DistributedScheduler,
@@ -1796,7 +1850,8 @@ class DistributedGraphRunner:
             # would shift every later commit timestamp off the
             # uninterrupted run's numbering, breaking sink bit-identity.
             transport.broadcast(("cmd", "commit"))
-            barrier_time = sched.commit_local()
+            with _tracing.stage("commit"):
+                barrier_time = sched.commit_local()
             if snapshot_mgr is not None:
                 # followers snapshot EVERY commit (including this one);
                 # the leader must too, or a worker that dies before the
@@ -1808,12 +1863,13 @@ class DistributedGraphRunner:
                 snapshot_mgr.on_commit(sched.scopes, drivers, barrier_time)
         last_sign_of_life = _time.monotonic()
 
-        def on_data() -> None:
+        def on_data(commit) -> None:
             nonlocal last_sign_of_life
             started = _time.monotonic()
             try:
                 transport.raise_if_peer_dead()
                 stamp, sources = _take_ingest_stamp(drivers)
+                commit.add(commit_wait_ns=_commit_wait_ns(stamp, started))
                 rows_before = _OUT_ROWS.value
                 # begin BEFORE the broadcast: the context tuple rides the
                 # first exchange round's frames so followers adopt it at
@@ -1836,24 +1892,27 @@ class DistributedGraphRunner:
                 )
                 sched.trace_peer_spans.clear()
             _observe_commit_latency(stamp, started, rows_before)
-            serving = _serving.enabled()
-            if persistent or snapshot_mgr is not None or serving:
-                # exactly-once seam: checkpoint only fully-completed commits
-                _device_pipeline.drain_until(time)
-            for d in persistent:
-                d.on_commit(time)
-            if snapshot_mgr is not None:
-                snapshot_mgr.on_commit(sched.scopes, drivers, time)
-            if serving:
-                # leader publishes its own shard; followers publish theirs
-                # in _follow — rollback republication truncates stale views
-                _serving.publish_on_commit(sched.scopes, time)
-            if fault_plan is not None:
-                fault_plan.on_commit(self.process_id, time)
-            if self.monitor is not None:
-                w0.monitor = self.monitor
-                w0._sync_monitor_connectors()
-                self.monitor.on_commit(time, started)
+            with _tracing.detail("commit.after"):
+                serving = _serving.enabled()
+                if persistent or snapshot_mgr is not None or serving:
+                    # exactly-once seam: checkpoint only fully-completed
+                    # commits
+                    _device_pipeline.drain_until(time)
+                for d in persistent:
+                    d.on_commit(time)
+                if snapshot_mgr is not None:
+                    snapshot_mgr.on_commit(sched.scopes, drivers, time)
+                if serving:
+                    # leader publishes its own shard; followers publish
+                    # theirs in _follow — rollback republication truncates
+                    # stale views
+                    _serving.publish_on_commit(sched.scopes, time)
+                if fault_plan is not None:
+                    fault_plan.on_commit(self.process_id, time)
+                if self.monitor is not None:
+                    w0.monitor = self.monitor
+                    w0._sync_monitor_connectors()
+                    self.monitor.on_commit(time, started)
             last_sign_of_life = started
             maybe_quiesce(time)
 
@@ -1883,8 +1942,9 @@ class DistributedGraphRunner:
                 last_sign_of_life = _time.monotonic()
 
         _pump_drivers(w0, drivers, on_data, on_idle)
-        transport.broadcast(("cmd", "finish"))
-        sched.finish_local()
+        with _tracing.stage("commit"):
+            transport.broadcast(("cmd", "finish"))
+            sched.finish_local()
         _tracing.TRACER.export()  # leader holds the assembled mesh traces
         for d in persistent:
             d.on_commit(sched.time)

@@ -45,12 +45,24 @@ exchange's pack/unpack marshalling), ``device`` (native ``kernel_ns``
 deltas), and ``host_compute`` (the residual) — the four sum to the
 commit wall exactly, so downstream consumers (bench JSON, the
 async-device-pipeline work) can trust the decomposition.
+
+Stages (:func:`stage`, :class:`StageTable`) are the always-on layer
+under the sampled spans: one pair of ``perf_counter_ns`` reads per stage
+feeds (1) a per-run table of calls, total and self time and counts
+(:func:`stage_totals`), zeroed when a ``pw.run()`` begins, (2) a
+``jax.profiler.TraceAnnotation("pw:<name>")`` while a profiler session
+runs, so the program's stages lie on the device trace's clock, and (3)
+the sampled commit's span list, when there is one. A stage that no
+metric reads from the table is opened with :func:`detail` instead: it
+exists only while reader (2) or (3) is there to see it.
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import os
+import sys
 import tempfile
 import threading
 import time as _time
@@ -73,6 +85,16 @@ __all__ = [
     "critical_path",
     "chrome_trace",
     "validate_chrome_trace",
+    "StageTable",
+    "STAGES",
+    "stage",
+    "detail",
+    "detail_on",
+    "NO_STAGE",
+    "begin",
+    "end",
+    "stage_totals",
+    "traced_run",
 ]
 
 #: spans kept per commit per worker before dropping (bounds frame size)
@@ -980,7 +1002,11 @@ def critical_path(trace: dict) -> dict:
     device collective has no wire, which is exactly what the
     collective_exchange bench leg compares), device is the native
     ``kernel_ns`` delta, and host-compute is the residual (clamped at
-    zero, flagged via ``clamped``)."""
+    zero, flagged via ``clamped``). A ``cat="device_wait"`` span (a
+    ``wait`` stage: the host standing at a blocking read of the device)
+    has no bucket of its own: it stays in the residual, where that time
+    lay before the stages named it, so the four buckets and what
+    ``device_pipeline.Controller.observe`` reads keep their meaning."""
     wall = max(1e-9, trace["end_wall"] - trace["origin_wall"])
     queue = max(0.0, trace["begin_wall"] - trace["origin_wall"])
     exchange = 0.0
@@ -1201,6 +1227,309 @@ def _active_trace_id() -> str | None:
         return rctx.trace_id
     ctx = TRACER._ctx
     return ctx.trace_id if ctx is not None else None
+
+
+# -- stages: per-run totals, profiler annotations, sampled spans --------------
+
+#: name of the stage that spans a whole ``run()`` of a runner; its self
+#: time is what the run thread did outside every other stage
+RUN_STAGE = "run"
+
+_now = _time.perf_counter_ns
+
+#: ``jax.profiler.TraceAnnotation`` once jax has been imported by someone
+#: else (None until then: the relational paths never import jax for this);
+#: looked for when a run begins and by :func:`detail_on`, not per stage
+_trace_annotation: Any = None
+
+
+def _annotation() -> Any:
+    global _trace_annotation
+    if _trace_annotation is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation
+
+
+class _ThreadStages:
+    """One thread's open stages and its table ``name -> [calls, total_ns,
+    child_ns, wait, counts]``; written by that thread alone."""
+
+    __slots__ = ("thread", "stack", "table")
+
+    def __init__(self, thread: threading.Thread) -> None:
+        self.thread = thread
+        self.stack: list[_Stage] = []
+        self.table: dict[str, list] = {}
+
+
+class _Stage:
+    """One open stage. ``with stage(...) as st`` or :func:`begin` /
+    :func:`end`; ``st.add(rows=n)`` adds counts known only inside."""
+
+    __slots__ = (
+        "owner", "thread", "name", "cat", "wait", "label", "counts", "t0",
+        "child_ns", "annotation",
+    )
+
+    def __init__(
+        self,
+        owner: "StageTable",
+        thread: _ThreadStages,
+        name: str,
+        cat: str | None,
+        wait: bool,
+        label: str | None,
+        counts: dict,
+    ) -> None:
+        self.owner = owner
+        self.thread = thread
+        self.name = name
+        self.cat = cat
+        self.wait = wait
+        self.label = label
+        self.counts = counts
+        self.child_ns = 0
+        self.annotation = None
+
+    def add(self, **counts: int) -> None:
+        have = self.counts
+        if not have:
+            self.counts = counts
+            return
+        for key, value in counts.items():
+            have[key] = have.get(key, 0) + value
+
+    def __enter__(self) -> "_Stage":
+        self.thread.stack.append(self)
+        annotation = _trace_annotation  # resolved when the run began
+        if annotation is not None and annotation.is_enabled():
+            # a jax.profiler session is running: the stage goes into it
+            self.annotation = annotation("pw:" + self.name)
+            self.annotation.__enter__()
+        self.t0 = _now()
+        return self
+
+    def __exit__(self, *exc: Any) -> bool:
+        t1 = _now()
+        if self.annotation is not None:
+            self.annotation.__exit__(None, None, None)
+        th = self.thread
+        stack = th.stack
+        while stack and stack.pop() is not self:
+            pass  # stages an exception left open above this one
+        dur = t1 - self.t0
+        if stack:
+            stack[-1].child_ns += dur
+        name = self.name
+        entry = th.table.get(name)
+        if entry is None:
+            entry = th.table[name] = [0, 0, 0, self.wait, {}]
+        entry[0] += 1
+        entry[1] += dur
+        entry[2] += self.child_ns
+        counts = self.counts
+        if counts:
+            have = entry[4]
+            for key, value in counts.items():
+                have[key] = have.get(key, 0) + value
+        ctx = TRACER._ctx
+        if ctx is not None and th is self.owner._run_thread:
+            # the sampled commit's context belongs to the run thread
+            args = dict(counts)
+            if self.label is not None:
+                args["label"] = self.label
+            cat = self.cat or ("device_wait" if self.wait else "stage")
+            ctx.span(name, cat, self.t0 / 1e9, t1 / 1e9, **args)
+        return False
+
+
+class StageTable:
+    """Process-wide stage totals of the current (or last) run (singleton:
+    :data:`STAGES`).
+
+    Every thread writes a table of its own, so the hot path takes no lock
+    and a name met on two threads (a device fetch on the run thread and
+    on the pipeline's completion worker) is two rows. ``self_ns`` is a
+    stage's duration less what the stages it caused on the same thread
+    covered, so the self times of the run thread's stages, the run's own
+    included, sum to ``run_wall_ns`` exactly."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._threads: list[_ThreadStages] = []  # guarded-by: self._lock
+        self._local = threading.local()
+        self._run_thread: _ThreadStages | None = None
+        self._root: _Stage | None = None
+        self._run_wall_ns = 0
+
+    def _thread(self) -> _ThreadStages:
+        try:
+            return self._local.stages
+        except AttributeError:
+            th = self._local.stages = _ThreadStages(threading.current_thread())
+            with self._lock:
+                self._threads.append(th)
+            return th
+
+    def stage(
+        self,
+        name: str,
+        cat: str | None = None,
+        wait: bool = False,
+        label: str | None = None,
+        **counts: int,
+    ) -> _Stage:
+        """A stage to enter. ``wait`` flags one whose exit blocks on the
+        device (its sampled span has the category ``device_wait``);
+        ``cat`` and ``label`` go to the sampled commit's span."""
+        try:
+            thread = self._local.stages
+        except AttributeError:
+            thread = self._thread()
+        return _Stage(self, thread, name, cat, wait, label, counts)
+
+    def begin_run(self) -> _Stage | None:
+        """Zero every thread's table and open the run's own stage on this
+        thread. A run begun while another is open (an iterate body, a
+        second runner on another thread) leaves the table alone."""
+        if self._root is not None:
+            return None
+        _annotation()
+        th = self._thread()
+        with self._lock:
+            self._threads = [
+                t for t in self._threads if t is th or t.thread.is_alive()
+            ]
+            for t in self._threads:
+                t.table.clear()
+        th.stack.clear()
+        self._run_thread = th
+        self._run_wall_ns = 0
+        self._root = self.stage(RUN_STAGE).__enter__()
+        return self._root
+
+    def end_run(self, root: _Stage | None) -> None:
+        if root is None or root is not self._root:
+            return
+        root.__exit__(None, None, None)
+        self._run_wall_ns = root.thread.table[RUN_STAGE][1]
+        self._root = None
+
+    def totals(self) -> dict:
+        """``{"run_wall_ns", "running", "stages", "threads"}``: ``stages``
+        is the run thread's table (the calling thread's before any run),
+        ``threads`` the other threads' by thread name; a row is
+        ``{"calls", "total_ns", "self_ns", "wait", "counts"}``. While a
+        run is open its own stage is not yet a row and ``run_wall_ns`` is
+        the time since it began."""
+        run_thread = self._run_thread or self._thread()
+        root = self._root
+        if root is not None:
+            wall = _now() - root.t0
+        else:
+            wall = self._run_wall_ns
+        with self._lock:
+            threads = list(self._threads)
+        out: dict = {
+            "run_wall_ns": wall,
+            "running": root is not None,
+            "stages": {},
+            "threads": {},
+        }
+        for th in threads:
+            rows = {
+                name: {
+                    "calls": e[0],
+                    "total_ns": e[1],
+                    "self_ns": e[1] - e[2],
+                    "wait": e[3],
+                    "counts": dict(e[4]),
+                }
+                for name, e in th.table.copy().items()
+            }
+            if th is run_thread:
+                out["stages"] = rows
+            elif rows:
+                name = th.thread.name
+                if name in out["threads"]:
+                    name = f"{name}#{th.thread.ident}"
+                out["threads"][name] = rows
+        return out
+
+
+#: the process-wide stage table every instrumented layer writes
+STAGES = StageTable()
+
+stage = STAGES.stage
+stage_totals = STAGES.totals
+
+
+class _NoStage:
+    """What :func:`detail` hands out while nobody is looking: falsy, and
+    a no-op to enter, to add to and to leave."""
+
+    __slots__ = ()
+
+    def __bool__(self) -> bool:
+        return False
+
+    def add(self, **counts: int) -> None:
+        pass
+
+    def __enter__(self) -> "_NoStage":
+        return self
+
+    def __exit__(self, *exc: Any) -> bool:
+        return False
+
+
+NO_STAGE = _NoStage()
+
+
+def detail_on() -> bool:
+    """Whether a sampled commit is current or a ``jax.profiler`` session
+    is running: only then is a :func:`detail` stage recorded."""
+    if TRACER._ctx is not None:
+        return True
+    annotation = _trace_annotation or _annotation()
+    return annotation is not None and annotation.is_enabled()
+
+
+def detail(name: str, **kwargs: Any) -> "_Stage | _NoStage":
+    """A stage that no metric reads from the table (an operator's sweep,
+    the pieces of an embed call): recorded, in all three readers, only
+    while :func:`detail_on`; otherwise its time stays in its parent's
+    self time and the hot path pays one test."""
+    return STAGES.stage(name, **kwargs) if detail_on() else NO_STAGE
+
+
+def begin(name: str, **kwargs: Any) -> _Stage:
+    """Open a stage where a ``with`` does not fit; close it with
+    :func:`end`."""
+    return STAGES.stage(name, **kwargs).__enter__()
+
+
+def end(st: _Stage, **counts: int) -> None:
+    if counts:
+        st.add(**counts)
+    st.__exit__(None, None, None)
+
+
+def traced_run(run: Any) -> Any:
+    """Decorator for a runner's ``run()``: the stage table is zeroed when
+    it begins and holds the run's wall when it returns or raises."""
+
+    @functools.wraps(run)
+    def wrapper(*args: Any, **kwargs: Any) -> Any:
+        root = STAGES.begin_run()
+        try:
+            return run(*args, **kwargs)
+        finally:
+            STAGES.end_run(root)
+
+    return wrapper
 
 
 # flight-recorder integration: every event recorded (and every dump

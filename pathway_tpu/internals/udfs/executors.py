@@ -18,6 +18,7 @@ from typing import Any, Callable, Sequence
 
 from typing import Awaitable
 
+from pathway_tpu.internals import tracing as _tracing
 from pathway_tpu.internals.udfs.retries import AsyncRetryStrategy
 
 RowResult = tuple[bool, Any]  # (ok, value-or-exception)
@@ -161,28 +162,32 @@ class BatchExecutor(Executor):
 
     def run(self, fn, rows, retry=None):
         out: list[RowResult] = []
-        step = self.max_batch_size or len(rows) or 1
+        cap = step = self.max_batch_size or len(rows) or 1
         if self.sizer is not None:
             suggested = self.sizer()
             if suggested:
                 step = max(1, min(step, int(suggested)))
+        narrowed = int(step < cap)
         for start in range(0, len(rows), step):
             chunk = rows[start : start + step]
-            cols = tuple(list(c) for c in zip(*chunk))
-            try:
-                if retry is not None:
-                    results = retry.invoke_sync(lambda: fn(*cols))
-                else:
-                    results = fn(*cols)
-                results = list(results)
-                if len(results) != len(chunk):
-                    raise ValueError(
-                        f"batch UDF returned {len(results)} results "
-                        f"for {len(chunk)} rows"
-                    )
-                out.extend((True, r) for r in results)
-            except Exception as e:  # noqa: BLE001
-                out.extend((False, e) for _ in chunk)
+            with _tracing.stage(
+                "udf.batch", rows=len(chunk), narrowed=narrowed
+            ):
+                cols = tuple(list(c) for c in zip(*chunk))
+                try:
+                    if retry is not None:
+                        results = retry.invoke_sync(lambda: fn(*cols))
+                    else:
+                        results = fn(*cols)
+                    results = list(results)
+                    if len(results) != len(chunk):
+                        raise ValueError(
+                            f"batch UDF returned {len(results)} results "
+                            f"for {len(chunk)} rows"
+                        )
+                    out.extend((True, r) for r in results)
+                except Exception as e:  # noqa: BLE001
+                    out.extend((False, e) for _ in chunk)
         return out
 
 

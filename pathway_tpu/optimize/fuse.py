@@ -97,28 +97,16 @@ class FusedChainNode(g.Node):
         if not (batch._insert_only or batch._raw_insert_only):
             batch = batch.consolidate()
         insert_only = batch._insert_only or batch._raw_insert_only
-        trace = _tracing.current()
         if insert_only and len(batch) >= device.VECTOR_THRESHOLD:
-            if trace is not None:
-                import time as _walltime
-
-                t0 = _walltime.perf_counter()
+            with _tracing.detail(
+                "op.fused_sweep",
+                cat="op",
+                label=getattr(self, "name", None),
+                rows=len(batch),
+            ):
                 fast = self._columnar_sweep(batch)
-                if fast is not None:
-                    trace.span(
-                        f"fused-sweep:{getattr(self, 'name', '') or self.index}",
-                        "op",
-                        t0,
-                        _walltime.perf_counter(),
-                        mode="columnar",
-                        rows=len(batch),
-                        stages=len(self._stages),
-                    )
-                    return fast
-            else:
-                fast = self._columnar_sweep(batch)
-                if fast is not None:
-                    return fast
+            if fast is not None:
+                return fast
         out = DeltaBatch()
         if not insert_only:
             state = self.current  # tail output state: retract once, up front

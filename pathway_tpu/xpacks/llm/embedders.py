@@ -15,6 +15,8 @@ from typing import Any, Callable
 
 import numpy as np
 
+from pathway_tpu.engine import device_residency as _dres
+from pathway_tpu.internals import tracing as _tracing
 from pathway_tpu.internals.udfs import (
     UDF,
     AsyncRetryStrategy,
@@ -191,19 +193,37 @@ class TpuEncoderEmbedder(UDF):
         self.device_resident = _resolve_device_resident(device_resident)
 
         def embed_batch(texts: list) -> list:
-            ids, mask = self.tokenizer.encode_batch(
-                [str(t) for t in texts], self.max_len
-            )
-            ids, mask, real = pad_to_buckets(
-                ids, mask, seq_bucket_min=self.seq_bucket_min
-            )
-            if self._mask_from_ids and bool(np.array_equal(mask, ids != 0)):
-                vecs_dev = self._jit_embed_ids(jnp.asarray(ids))
-            else:
-                vecs_dev = self._jit_embed(
-                    jnp.asarray(ids), jnp.asarray(mask)
+            # the pieces of a call are stages only while someone looks
+            # (tracing.detail); ``embed.dispatch`` is always one
+            with _tracing.detail("embed.tokenize") as st:
+                ids, mask = self.tokenizer.encode_batch(
+                    [str(t) for t in texts], self.max_len
                 )
-            return _rows_from_device(vecs_dev, real, self.device_resident)
+                if st:
+                    st.add(tokens=int(np.count_nonzero(mask)))
+            with _tracing.detail("embed.pad") as st:
+                ids, mask, real = pad_to_buckets(
+                    ids, mask, seq_bucket_min=self.seq_bucket_min
+                )
+                ids_only = self._mask_from_ids and bool(
+                    np.array_equal(mask, ids != 0)
+                )
+                st.add(rows=real, padded_rows=len(ids), padded_tokens=ids.size)
+            with _tracing.stage("embed.dispatch") as st:
+                # the jitted steps are looked up here, at call time: the
+                # benchmark wraps these two attributes
+                if ids_only:
+                    h2d = ids.nbytes
+                    vecs_dev = self._jit_embed_ids(jnp.asarray(ids))
+                else:
+                    h2d = ids.nbytes + mask.nbytes
+                    vecs_dev = self._jit_embed(
+                        jnp.asarray(ids), jnp.asarray(mask)
+                    )
+                _dres.record_h2d(h2d)
+                st.add(h2d_bytes=h2d)
+            with _tracing.detail("embed.rows_out", rows=real):
+                return _rows_from_device(vecs_dev, real, self.device_resident)
 
         super().__init__(
             embed_batch,

@@ -711,3 +711,513 @@ class TestTraceSurvivesRecovery:
             tracing.validate_chrome_trace(obj)
             epochs += [t["epoch"] for t in obj["otherData"]["traces"]]
         assert epochs and max(epochs) >= 1, epochs
+
+
+# -- stages: per-run totals, profiler annotations, sampled spans --------------
+
+#: every stage of ISSUE 25's table that a single-worker RAG run passes
+#: through, with the counts each carries
+RAG_STAGES = {
+    "pump.poll": ("rows",),
+    "pump.sleep": (),
+    "commit": ("commit_wait_ns",),
+    "op.BatchApplyNode": ("batches",),
+    "op.ExternalIndexNode": ("batches",),
+    "op.SubscribeNode": ("batches",),
+    "commit.device_stage": ("batches",),
+    "commit.device_wait": (),
+    "commit.after": (),
+    "sink.emit": ("rows",),
+    "udf.batch": ("rows", "narrowed"),
+    "embed.tokenize": ("tokens",),
+    "embed.pad": ("rows", "padded_rows", "padded_tokens"),
+    "embed.dispatch": ("h2d_bytes",),
+    "embed.rows_out": ("rows",),
+    "knn.add.host": ("rows",),
+    "knn.add.dispatch": ("rows", "h2d_bytes"),
+    "knn.search.dispatch": ("queries", "padded_queries", "h2d_bytes"),
+    "knn.search.fetch": ("queries", "d2h_bytes"),
+}
+N_DOCS, N_QUERIES = 24, 3
+
+
+def _self_sum(totals: dict) -> int:
+    return sum(row["self_ns"] for row in totals["stages"].values())
+
+
+def _run_counting_stream(n: int = 20) -> list:
+    """A small relational streaming ``pw.run()``: n rows in, their sum out."""
+    import pathway_tpu as pw
+    from pathway_tpu.internals.parse_graph import G
+
+    G.clear()
+
+    class Numbers(pw.io.python.ConnectorSubject):
+        def run(self) -> None:
+            for i in range(n):
+                self.next(a=i)
+
+    table = pw.io.python.read(
+        Numbers(), schema=pw.schema_from_types(a=int),
+        autocommit_duration_ms=5,
+    )
+    total = table.reduce(s=pw.reducers.sum(pw.this.a))
+    seen: list = []
+    pw.io.subscribe(
+        total,
+        on_change=lambda key, row, time, is_addition: seen.append(
+            (row["s"], is_addition)
+        ),
+    )
+    pw.run()
+    return seen
+
+
+class TestStageTable:
+    def test_self_ns_is_duration_less_children(self):
+        table = tracing.StageTable()
+        with table.stage("outer"):
+            with table.stage("inner"):
+                with table.stage("leaf"):
+                    pass
+            with table.stage("inner"):
+                pass
+        rows = table.totals()["stages"]
+        outer, inner, leaf = rows["outer"], rows["inner"], rows["leaf"]
+        assert (outer["calls"], inner["calls"], leaf["calls"]) == (1, 2, 1)
+        assert outer["self_ns"] == outer["total_ns"] - inner["total_ns"]
+        assert inner["self_ns"] == inner["total_ns"] - leaf["total_ns"]
+        assert leaf["self_ns"] == leaf["total_ns"]
+        assert _self_sum(table.totals()) == outer["total_ns"]
+
+    def test_counts_sum_over_calls(self):
+        table = tracing.StageTable()
+        for rows in (3, 4):
+            with table.stage("udf.batch", rows=rows, narrowed=0) as st:
+                st.add(tokens=10 * rows)
+        st = table.stage("knn.search.fetch", wait=True).__enter__()
+        st.add(queries=2)
+        st.__exit__(None, None, None)
+        rows = table.totals()["stages"]
+        assert rows["udf.batch"]["counts"] == {
+            "rows": 7, "narrowed": 0, "tokens": 70,
+        }
+        assert rows["udf.batch"]["wait"] is False
+        assert rows["knn.search.fetch"]["wait"] is True
+        assert rows["knn.search.fetch"]["counts"] == {"queries": 2}
+
+    def test_a_raise_inside_a_stage_unwinds_the_stack(self):
+        table = tracing.StageTable()
+        with table.stage("outer"):
+            with pytest.raises(ZeroDivisionError):
+                with table.stage("raises"):
+                    left_open = table.stage("left_open").__enter__()
+                    assert left_open is not None
+                    1 / 0
+            with table.stage("after"):
+                pass
+        rows = table.totals()["stages"]
+        assert "left_open" not in rows  # never closed: never counted
+        assert rows["outer"]["self_ns"] == (
+            rows["outer"]["total_ns"]
+            - rows["raises"]["total_ns"]
+            - rows["after"]["total_ns"]
+        )
+
+    def test_another_thread_writes_a_table_of_its_own(self):
+        table = tracing.StageTable()
+        root = table.begin_run()
+
+        def worker() -> None:
+            with table.stage("device.fetch_rows", wait=True, d2h_bytes=8):
+                pass
+
+        thread = threading.Thread(target=worker, name="stage-worker")
+        with table.stage("device.fetch_rows", wait=True, d2h_bytes=4):
+            thread.start()
+            thread.join(timeout=30)
+        assert not thread.is_alive()
+        table.end_run(root)
+        totals = table.totals()
+        assert totals["stages"]["device.fetch_rows"]["counts"] == {
+            "d2h_bytes": 4
+        }
+        assert totals["threads"]["stage-worker"]["device.fetch_rows"][
+            "counts"
+        ] == {"d2h_bytes": 8}
+        # the other thread's stage is outside the run thread's sum
+        assert _self_sum(totals) == totals["run_wall_ns"]
+
+    def test_a_run_inside_a_run_leaves_the_table_alone(self):
+        table = tracing.StageTable()
+        root = table.begin_run()
+        with table.stage("commit"):
+            inner = table.begin_run()  # an iterate body's runner
+            assert inner is None
+            table.end_run(inner)
+        assert table.totals()["running"] is True
+        table.end_run(root)
+        totals = table.totals()
+        assert totals["running"] is False
+        assert set(totals["stages"]) == {"commit", tracing.RUN_STAGE}
+        assert totals["run_wall_ns"] == totals["stages"]["run"]["total_ns"]
+
+    def test_a_detail_stage_is_nothing_while_nobody_looks(self):
+        assert not tracing.TRACER.enabled and not tracing.detail_on()
+        before = tracing.stage_totals()
+        st = tracing.detail("test.detail_off", rows=1)
+        assert st is tracing.NO_STAGE and not st
+        with st as entered:
+            entered.add(rows=2)
+        after = tracing.stage_totals()
+        assert "test.detail_off" not in after["stages"]
+        assert set(after["stages"]) == set(before["stages"])
+
+    def test_a_detail_stage_is_recorded_in_a_sampled_commit(self):
+        tracing.TRACER.configure(enabled=True, sample=1, clear=True)
+        try:
+            ctx = tracing.TRACER.begin(1)
+            assert ctx is not None and tracing.detail_on()
+            with tracing.detail("test.detail_on", rows=3) as st:
+                assert st
+            tracing.TRACER.end(1)
+            assert not tracing.detail_on()
+        finally:
+            tracing.TRACER.drop()
+            tracing.TRACER.configure(enabled=False, clear=True)
+            tracing.TRACER.epoch = 0
+        totals = tracing.stage_totals()
+        rows = {**totals["stages"], **{
+            name: row
+            for table in totals["threads"].values()
+            for name, row in table.items()
+        }}
+        assert rows["test.detail_on"]["counts"]["rows"] >= 3
+
+    @pytest.mark.parametrize(
+        "cat,bucket",
+        [("wait", "queue_wait_s"), ("device_wait", "host_compute_s")],
+    )
+    def test_a_device_wait_span_stays_in_the_residual(self, cat, bucket):
+        """A ``wait`` stage's span (``device_wait``) is no queue wait: the
+        buckets the device pipeline's controller reads keep their meaning."""
+        trace = {
+            "origin_wall": 10.0, "begin_wall": 10.0, "end_wall": 11.0,
+            "device_s": 0.0,
+            "spans": [
+                {"name": "blocked", "cat": cat, "ts": 0, "dur": 400_000},
+            ],
+        }
+        cp = tracing.critical_path(trace)
+        other = ({"queue_wait_s", "host_compute_s"} - {bucket}).pop()
+        assert cp[bucket] == pytest.approx(
+            1.0 if bucket == "host_compute_s" else 0.4
+        )
+        assert cp[other] == pytest.approx(
+            0.0 if other == "queue_wait_s" else 0.6
+        )
+
+    def test_begin_and_end_where_a_with_does_not_fit(self):
+        st = tracing.begin("test.begin_end", rows=1)
+        tracing.end(st, rows=2)
+        local = tracing.stage_totals()
+        rows = {**local["stages"], **{
+            name: row
+            for table in local["threads"].values()
+            for name, row in table.items()
+        }}
+        assert rows["test.begin_end"]["counts"]["rows"] >= 3
+
+
+class TestStagesOfARun:
+    def test_run_thread_stages_sum_to_the_run_wall(self):
+        seen = _run_counting_stream()
+        assert seen[-1] == (sum(range(20)), True)
+        totals = tracing.stage_totals()
+        assert totals["running"] is False and totals["run_wall_ns"] > 0
+        stages = totals["stages"]
+        assert {"run", "pump.poll", "commit", "sink.emit"} <= set(stages)
+        assert abs(_self_sum(totals) - totals["run_wall_ns"]) <= (
+            0.01 * totals["run_wall_ns"]
+        )
+        assert stages["pump.poll"]["counts"]["rows"] == 20
+        assert stages["sink.emit"]["counts"]["rows"] == len(seen)
+        # every commit has its wait, sampled or not (tracing is off here)
+        assert not tracing.TRACER.enabled
+        assert "commit_wait_ns" in stages["commit"]["counts"]
+        # with no sampled commit and no profiler session, the stages no
+        # metric reads are not recorded: their time is their parent's own
+        assert not {name for name in stages if name.startswith("op.")}
+        assert "commit.after" not in stages
+
+    def test_operators_run_under_a_commit_stage_and_nowhere_else(self):
+        """The static sources' first commit and the last one in finish()
+        are ``commit`` stages too, so ``commit`` less its named children
+        is the scheduler's own time."""
+        import pathway_tpu as pw
+        from pathway_tpu.internals.parse_graph import G
+
+        G.clear()
+
+        class Numbers(pw.io.python.ConnectorSubject):
+            def run(self) -> None:
+                for i in range(5):
+                    self.next(a=i)
+
+        streamed = pw.io.python.read(
+            Numbers(), schema=pw.schema_from_types(a=int),
+            autocommit_duration_ms=5,
+        )
+        static = pw.debug.table_from_rows(
+            pw.schema_from_types(a=int), [(100,), (101,)]
+        )
+        open_stages: list = []
+
+        def on_change(key, row, time, is_addition) -> None:
+            open_stages.append(
+                [st.name for st in tracing.STAGES._thread().stack]
+            )
+
+        pw.io.subscribe(streamed, on_change=on_change)
+        pw.io.subscribe(static, on_change=on_change)
+        pw.run()
+        assert len(open_stages) == 7
+        for names in open_stages:
+            assert names[:2] == ["run", "commit"], names
+            assert names[-1] == "sink.emit", names
+        stages = tracing.stage_totals()["stages"]
+        assert stages["sink.emit"]["total_ns"] <= stages["commit"]["total_ns"]
+        assert "run.finish" not in stages
+
+    def test_the_next_run_zeroes_the_table(self):
+        _run_counting_stream(n=30)
+        first = tracing.stage_totals()["stages"]
+        _run_counting_stream(n=4)
+        second = tracing.stage_totals()["stages"]
+        assert first["pump.poll"]["counts"]["rows"] == 30
+        assert second["pump.poll"]["counts"]["rows"] == 4
+        assert second["run"]["calls"] == 1
+
+    def test_stages_import_no_jax(self):
+        """A relational run must not import jax for its annotations."""
+        import subprocess
+
+        prog = textwrap.dedent(
+            """
+            import sys
+            import pathway_tpu as pw
+            from pathway_tpu.internals import tracing
+
+            class S(pw.io.python.ConnectorSubject):
+                def run(self):
+                    for i in range(5):
+                        self.next(a=i)
+
+            t = pw.io.python.read(
+                S(), schema=pw.schema_from_types(a=int),
+                autocommit_duration_ms=5,
+            )
+            pw.io.subscribe(t, on_change=lambda **kw: None)
+            pw.run()
+            assert "commit" in tracing.stage_totals()["stages"]
+            print("jax" in sys.modules)
+            """
+        )
+        env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
+        out = subprocess.run(
+            [sys.executable, "-c", prog], env=env, capture_output=True,
+            text=True, timeout=120,
+        )
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.strip().splitlines()[-1] == "False"
+
+
+@pytest.fixture(scope="class")
+def rag_run(tmp_path_factory):
+    """One toy RAG ``pw.run()`` (HashTokenizer, MiniLM at 16 tokens,
+    ``DataIndex(TpuKnnFactory)`` on the CPU backend) with every commit
+    sampled, inside a ``jax.profiler`` session."""
+    import jax
+    import numpy as np
+
+    import pathway_tpu as pw
+    from pathway_tpu.engine import device_ops
+    from pathway_tpu.internals.parse_graph import G
+    from pathway_tpu.stdlib.indexing import DataIndex, TpuKnnFactory
+    from pathway_tpu.xpacks.llm.embedders import TpuEncoderEmbedder
+
+    G.clear()
+    embedder = TpuEncoderEmbedder("minilm_l6", max_len=16, max_batch_size=8)
+    docs_acked = threading.Event()
+
+    class Docs(pw.io.python.ConnectorSubject):
+        def run(self) -> None:
+            for i in range(N_DOCS):
+                self.next(doc_id=i, text=f"w{i} w{i + 1} w{i % 5}")
+
+    class Queries(pw.io.python.ConnectorSubject):
+        def run(self) -> None:
+            # the queries come once the documents are in and the pump,
+            # finding nothing to poll, has slept at least once
+            docs_acked.wait(timeout=120)
+            pause = threading.Event()  # never set: a bounded wait
+            for _ in range(12000):
+                if "pump.sleep" in tracing.stage_totals()["stages"]:
+                    break
+                pause.wait(timeout=0.01)
+            for i in range(N_QUERIES):
+                self.next(query_id=i, text=f"w{i} w{i + 1}")
+
+    docs = pw.io.python.read(
+        Docs(), schema=pw.schema_from_types(doc_id=int, text=str),
+        autocommit_duration_ms=20,
+    )
+    docs = docs.select(doc_id=pw.this.doc_id, emb=embedder(pw.this.text))
+    index = DataIndex(
+        docs,
+        TpuKnnFactory(
+            dimensions=embedder.get_embedding_dimension(), metric="cos",
+            capacity=64,
+        ),
+        docs.emb,
+    )
+    queries = pw.io.python.read(
+        Queries(), schema=pw.schema_from_types(query_id=int, text=str),
+        autocommit_duration_ms=20,
+    )
+    queries = queries.select(
+        query_id=pw.this.query_id, qemb=embedder(pw.this.text)
+    )
+    answers = index.query_as_of_now(queries, queries.qemb, number_of_matches=2)
+    rows: list = []
+
+    def on_doc(key, row, time, is_addition) -> None:
+        rows.append(np.asarray(row["emb"]).shape)
+        if len(rows) == N_DOCS:
+            docs_acked.set()
+
+    pw.io.subscribe(docs, on_change=on_doc)
+    pw.io.subscribe(
+        answers,
+        on_change=lambda key, row, time, is_addition: rows.append(
+            row["_pw_index_reply_ids"]
+        ),
+    )
+    kernels_before = dict(device_ops.kernel_ns())
+    trace_dir = tmp_path_factory.mktemp("xplane")
+    tracing.TRACER.configure(enabled=True, sample=1, clear=True)
+    try:
+        with jax.profiler.trace(str(trace_dir)):
+            pw.run()
+        yield {
+            "rows": rows,
+            "totals": tracing.stage_totals(),
+            "traces": tracing.TRACER.traces(),
+            "trace_dir": trace_dir,
+            "kernels_before": kernels_before,
+            "kernels_after": dict(device_ops.kernel_ns()),
+        }
+    finally:
+        tracing.TRACER.drop()
+        tracing.TRACER.configure(enabled=False, clear=True)
+        tracing.TRACER.epoch = 0
+        G.clear()
+
+
+class TestStagesOfARagRun:
+    def test_every_stage_of_the_table_appears_with_its_counts(self, rag_run):
+        assert len(rag_run["rows"]) == N_DOCS + N_QUERIES
+        stages = rag_run["totals"]["stages"]
+        assert set(RAG_STAGES) <= set(stages), set(RAG_STAGES) - set(stages)
+        for name, counts in RAG_STAGES.items():
+            assert stages[name]["calls"] >= 1
+            assert set(counts) <= set(stages[name]["counts"]), name
+        assert stages["udf.batch"]["counts"]["rows"] == N_DOCS + N_QUERIES
+        assert stages["embed.pad"]["counts"]["rows"] == N_DOCS + N_QUERIES
+        assert stages["knn.add.host"]["counts"]["rows"] == N_DOCS
+        assert stages["knn.add.dispatch"]["counts"]["rows"] == N_DOCS
+        assert stages["knn.search.fetch"]["counts"]["queries"] == N_QUERIES
+        assert stages["sink.emit"]["counts"]["rows"] == N_DOCS + N_QUERIES
+        assert stages["embed.dispatch"]["calls"] == stages["udf.batch"]["calls"]
+        # int32 ids, and nothing else, go up for a text: 4 bytes a padded token
+        assert stages["embed.dispatch"]["counts"]["h2d_bytes"] == (
+            4 * stages["embed.pad"]["counts"]["padded_tokens"]
+        )
+
+    def test_the_partition_holds_with_device_stages(self, rag_run):
+        totals = rag_run["totals"]
+        assert _self_sum(totals) == totals["run_wall_ns"]
+        # children lie inside their parents
+        stages = totals["stages"]
+        inside = sum(
+            stages[name]["total_ns"]
+            for name in ("embed.tokenize", "embed.pad", "embed.dispatch",
+                         "embed.rows_out")
+        )
+        assert inside <= stages["udf.batch"]["total_ns"]
+        assert stages["udf.batch"]["total_ns"] <= stages["commit"]["total_ns"]
+
+    def test_stages_that_block_on_the_device_are_flagged_wait(self, rag_run):
+        stages = rag_run["totals"]["stages"]
+        waits = {name for name, row in stages.items() if row["wait"]}
+        assert {"knn.search.fetch", "commit.device_wait"} <= waits
+        assert waits <= {
+            "knn.search.fetch", "commit.device_wait", "device.fetch_rows"
+        }
+        # the completion worker's fetches are rows of its own thread
+        for table in rag_run["totals"]["threads"].values():
+            assert set(table) <= {"device.fetch_rows"}, table
+
+    def test_a_knn_enqueue_is_not_device_kernel_time(self, rag_run):
+        new = {
+            name
+            for name, ns in rag_run["kernels_after"].items()
+            if ns != rag_run["kernels_before"].get(name, 0)
+        }
+        assert not {"knn_update", "knn_search"} & new
+        for trace in rag_run["traces"]:
+            assert not any(
+                "knn" in name for name in trace["device_kernel_ns"]
+            )
+
+    def test_a_profiler_session_holds_the_stages_as_annotations(self, rag_run):
+        from jax.profiler import ProfileData
+
+        paths = sorted(rag_run["trace_dir"].glob(
+            "plugins/profile/*/*.xplane.pb"
+        ))
+        assert paths, "the profiler session wrote no .xplane.pb"
+        data = ProfileData.from_file(str(paths[-1]))
+        names = {
+            ev.name
+            for plane in data.planes
+            for line in plane.lines
+            for ev in line.events
+            if ev.name.startswith("pw:")
+        }
+        assert {"pw:commit", "pw:embed.dispatch", "pw:udf.batch",
+                "pw:knn.add.dispatch", "pw:pump.poll"} <= names
+
+    def test_a_sampled_commit_exports_the_new_stages(self, rag_run):
+        traces = [t for t in rag_run["traces"] if t.get("kind") is None]
+        assert traces
+        events = tracing.validate_chrome_trace(tracing.chrome_trace(traces))
+        names = {e["name"] for e in events if e.get("ph") == "X"}
+        assert {
+            "udf.batch", "embed.tokenize", "embed.dispatch", "knn.update",
+            "knn.add.dispatch", "knn.search.fetch", "sink.emit",
+            "op.BatchApplyNode", "commit.device_stage",
+        } <= names
+        by_name = {e["name"]: e for e in events if e.get("ph") == "X"}
+        assert by_name["knn.search.fetch"]["cat"] == "device_wait"
+        assert by_name["op.BatchApplyNode"]["cat"] == "op"
+        assert by_name["sink.emit"]["cat"] == "sink"
+        assert by_name["udf.batch"]["args"]["rows"] >= 1
+        for trace in traces:
+            cp = trace["critical_path"]
+            if not cp["clamped"]:
+                assert cp["queue_wait_s"] + cp["exchange_s"] + cp[
+                    "device_s"
+                ] + cp["host_compute_s"] == pytest.approx(
+                    cp["wall_s"], rel=0.01, abs=1e-5
+                )
