@@ -114,24 +114,6 @@ REGISTRY: dict[str, FlagSpec] = {
             "row floor below which device kernels decline",
         ),
         _spec(
-            "PATHWAY_TPU_DEVICE_BATCH",
-            STARTUP,
-            "engine.device_pipeline",
-            "initial adaptive device micro-batch size",
-        ),
-        _spec(
-            "PATHWAY_TPU_DEVICE_BATCH_MIN",
-            STARTUP,
-            "engine.device_pipeline",
-            "adaptive micro-batch lower bound",
-        ),
-        _spec(
-            "PATHWAY_TPU_DEVICE_BATCH_MAX",
-            STARTUP,
-            "engine.device_pipeline",
-            "adaptive micro-batch upper bound",
-        ),
-        _spec(
             "PATHWAY_TPU_DEVICE_INFLIGHT",
             STARTUP,
             "engine.device_pipeline",

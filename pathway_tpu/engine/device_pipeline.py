@@ -30,16 +30,14 @@ This module turns the barrier into a pipeline stage:
   (default 2, ``PATHWAY_TPU_DEVICE_INFLIGHT``) may be in flight;
   staging commit N+depth blocks until commit N retires, bounding HBM to
   ``depth`` commits' worth of batches (the sync path bounds it to 1).
-- **feedback-driven batch sizing** — :class:`AdaptiveBatchController`
-  reads the PR-5 queue-depth gauge and the PR-8 critical-path buckets
-  each device commit and adapts the device micro-batch size (consumed
-  by ``BatchExecutor`` via :func:`suggested_batch_size`) and the
-  connector autocommit window scale (:func:`ingest_window_scale`,
-  consumed by ``InputDriver.effective_autocommit_s``): when the device
-  stage is the bottleneck it grows batches/windows to amortize dispatch,
-  when the host residual dominates it shrinks them to start overlap
-  earlier — TeleRAG-style lookahead, driven by measurement instead of
-  a static schedule.
+- **ingest window feedback** — :class:`IngestWindowController` scales
+  the connector autocommit window (:func:`ingest_window_scale`, consumed
+  by ``InputDriver.effective_autocommit_s``) once per device commit: a
+  commit that found the pipeline full, or had to block on the in-flight
+  bound, widens the window x1.25 (up to x4) so a saturated device stage
+  gets fewer, fatter commits; an idle completion stage (occupancy under
+  0.25) relaxes it back toward 1.0.  How many rows one device call holds
+  is not decided here: that is the UDF's ``max_batch_size``.
 
 ``PATHWAY_TPU_ASYNC_DEVICE=0`` is the escape hatch: the commit boundary
 then decays inline, bit-identical to the pre-pipeline engine (PR-2
@@ -66,15 +64,14 @@ from pathway_tpu.internals import metrics as _metrics
 from pathway_tpu.internals import tracing as _tracing
 
 __all__ = [
-    "AdaptiveBatchController",
     "DevicePipeline",
+    "IngestWindowController",
     "PIPELINE",
     "async_enabled",
     "commit_boundary",
     "drain",
     "drain_until",
     "reset",
-    "suggested_batch_size",
     "ingest_window_scale",
 ]
 
@@ -109,73 +106,33 @@ def async_enabled() -> bool:
     )
 
 
-def _env_int(name: str, default: int, floor: int = 1) -> int:
+def _env_int(name: str, default: int) -> int:
     try:
-        return max(floor, int(os.environ.get(name, str(default))))
+        return max(1, int(os.environ.get(name, str(default))))
     except ValueError:
         return default
 
 
-class AdaptiveBatchController:
-    """Feedback loop closing PRs 5-8's measurement machinery into sizing.
+class IngestWindowController:
+    """The in-flight bound and the autocommit window's feedback loop.
 
-    Inputs, read once per *device* commit (host-only commits never touch
-    the controller):
-
-    - pipeline pressure — staged depth and whether staging had to block
-      on the in-flight bound (the device stage is saturated);
-    - completion-stage occupancy (EMA, 0..1);
-    - the host queue-depth gauge (``pathway_queue_depth``, PR 5);
-    - the last sampled commit's critical-path buckets (PR 8), when
-      tracing is on — ``host_compute_s`` vs ``device_s`` decides which
-      side of the pipe is the bottleneck when occupancy is ambiguous.
-
-    Outputs:
-
-    - ``batch_size`` — suggested device micro-batch rows; consumed by
-      ``BatchExecutor`` (it only ever *narrows* the user's configured
-      ``max_batch_size``, never exceeds it);
-    - ``depth`` — staged-commit bound (double buffering by default);
-    - ``window_scale`` — multiplier on connector autocommit windows
-      (1.0..4.0): a saturated device stage wants fewer, fatter commits.
-
-    The rules are deliberately monotone and clamped so the loop cannot
-    oscillate unboundedly: saturation doubles the batch and widens the
-    window; an idle completion stage with a host-bound critical path
-    halves the batch and narrows the window back toward 1.0.
+    ``depth`` is the staged-commit bound (double buffering by default,
+    ``PATHWAY_TPU_DEVICE_INFLIGHT``).  ``window_scale`` multiplies
+    connector autocommit windows (1.0..4.0) and moves once per *device*
+    commit (host-only commits never tick): a blocked or full pipeline
+    widens it x1.25, an idle completion stage relaxes it /1.25, and in
+    between it holds — monotone and clamped, so it cannot oscillate
+    unboundedly.
     """
 
     #: occupancy below which the device stage counts as starved
     IDLE_OCCUPANCY = 0.25
 
     def __init__(self) -> None:
-        self.min_batch = _env_int("PATHWAY_TPU_DEVICE_BATCH_MIN", 32)
-        self.max_batch = _env_int("PATHWAY_TPU_DEVICE_BATCH_MAX", 65536)
-        self.batch_size = _env_int(
-            "PATHWAY_TPU_DEVICE_BATCH", 1024, floor=self.min_batch
-        )
         self.depth = _env_int("PATHWAY_TPU_DEVICE_INFLIGHT", 2)
         self.window_scale = 1.0
         self.ticks = 0
         self.grows = 0
-        self.shrinks = 0
-        self._queue_gauge = None
-
-    def _host_queue_depth(self) -> float:
-        g = self._queue_gauge
-        if g is None:
-            g = self._queue_gauge = _metrics.REGISTRY.gauge(
-                "pathway_queue_depth",
-                "operators with pending delta batches (backpressure)",
-            )
-        return g.value
-
-    @staticmethod
-    def _last_critical_path() -> dict | None:
-        if not _tracing.TRACER.enabled:
-            return None
-        traces = _tracing.TRACER.traces()
-        return traces[-1]["critical_path"] if traces else None
 
     def observe(
         self, *, staged_depth: int, blocked: bool, occupancy: float
@@ -183,36 +140,19 @@ class AdaptiveBatchController:
         """One device-commit tick of the feedback loop."""
         self.ticks += 1
         if blocked or staged_depth >= self.depth:
-            # the completion stage is the bottleneck: amortize dispatch
-            # with fatter device batches and fewer, larger commits
-            self.batch_size = min(self.max_batch, self.batch_size * 2)
+            # the completion stage is the bottleneck: fewer, larger commits
             self.window_scale = min(4.0, self.window_scale * 1.25)
             self.grows += 1
-            return
-        if occupancy < self.IDLE_OCCUPANCY:
-            cp = self._last_critical_path()
-            host_bound = cp is None or cp.get("host_compute_s", 0.0) >= cp.get(
-                "device_s", 0.0
-            )
-            if host_bound and self._host_queue_depth() >= 0.0:
-                # device starved while the host sweats: smaller batches
-                # reach the device sooner, and the ingest window relaxes
-                # back toward its configured value
-                if self.batch_size > self.min_batch:
-                    self.batch_size = max(
-                        self.min_batch, self.batch_size // 2
-                    )
-                    self.shrinks += 1
-                self.window_scale = max(1.0, self.window_scale / 1.25)
+        elif occupancy < self.IDLE_OCCUPANCY:
+            # device starved: the window relaxes back to its configured value
+            self.window_scale = max(1.0, self.window_scale / 1.25)
 
     def stats(self) -> dict:
         return {
-            "batch_size": self.batch_size,
             "depth": self.depth,
             "window_scale": round(self.window_scale, 3),
             "ticks": self.ticks,
             "grows": self.grows,
-            "shrinks": self.shrinks,
         }
 
 
@@ -237,7 +177,7 @@ class DevicePipeline:
         self._busy_s = 0.0  # guarded-by: self._cv
         self._occ_mark: float | None = None  # guarded-by: self._cv
         self._occupancy = 0.0  # guarded-by: self._cv
-        self.controller = AdaptiveBatchController()
+        self.controller = IngestWindowController()
         self._g_depth = _metrics.REGISTRY.gauge(
             "pathway_device_queue_depth",
             "device-pipeline commits staged or completing",
@@ -269,7 +209,7 @@ class DevicePipeline:
             self._occ_mark = None
             self._occupancy = 0.0
             self._g_occ.value = 0.0
-        self.controller = AdaptiveBatchController()
+        self.controller = IngestWindowController()
 
     def _ensure_worker(self) -> None:
         w = self._worker
@@ -533,16 +473,6 @@ def stop_worker() -> None:
 
 def reset() -> None:
     PIPELINE.reset()
-
-
-def suggested_batch_size() -> int | None:
-    """The adaptive controller's current device micro-batch suggestion;
-    None in sync mode (executors then use their configured cap).  A
-    ``BatchExecutor`` sizer only ever narrows the configured
-    ``max_batch_size`` with this value, never exceeds it."""
-    if not async_enabled():
-        return None
-    return PIPELINE.controller.batch_size
 
 
 def ingest_window_scale() -> float:

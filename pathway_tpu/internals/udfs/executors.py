@@ -145,33 +145,23 @@ class BatchExecutor(Executor):
     and returns a list of results — the jit-microbatch entry point.
 
     ``max_batch_size`` splits oversized commits so padded device buffers
-    stay bounded.  ``sizer`` (optional callable -> int | None) lets the
-    device pipeline's adaptive controller narrow the chunk size at run
-    time; it can only shrink below the configured cap, never exceed it.
+    stay bounded: every chunk holds exactly that many rows except a
+    commit's tail (no cap: the whole commit is one chunk).  The
+    ``udf.batch`` stage counts a chunk short of the cap as ``narrowed``.
     """
 
     kind = "batch"
 
-    def __init__(
-        self,
-        max_batch_size: int | None = None,
-        sizer: Callable[[], int | None] | None = None,
-    ) -> None:
+    def __init__(self, max_batch_size: int | None = None) -> None:
         self.max_batch_size = max_batch_size
-        self.sizer = sizer
 
     def run(self, fn, rows, retry=None):
         out: list[RowResult] = []
-        cap = step = self.max_batch_size or len(rows) or 1
-        if self.sizer is not None:
-            suggested = self.sizer()
-            if suggested:
-                step = max(1, min(step, int(suggested)))
-        narrowed = int(step < cap)
+        step = self.max_batch_size or len(rows) or 1
         for start in range(0, len(rows), step):
             chunk = rows[start : start + step]
             with _tracing.stage(
-                "udf.batch", rows=len(chunk), narrowed=narrowed
+                "udf.batch", rows=len(chunk), narrowed=int(len(chunk) < step)
             ):
                 cols = tuple(list(c) for c in zip(*chunk))
                 try:
@@ -207,8 +197,5 @@ def async_executor(
     return AsyncExecutor(capacity=capacity, timeout=timeout)
 
 
-def batch_executor(
-    max_batch_size: int | None = None,
-    sizer: Callable[[], int | None] | None = None,
-) -> BatchExecutor:
-    return BatchExecutor(max_batch_size=max_batch_size, sizer=sizer)
+def batch_executor(max_batch_size: int | None = None) -> BatchExecutor:
+    return BatchExecutor(max_batch_size=max_batch_size)

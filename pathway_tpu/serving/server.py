@@ -13,10 +13,8 @@ concurrent queries off the dataflow's back:
   admitted it is always answered — possibly from a stale snapshot,
   never with a 5xx.
 - **Micro-batching**: concurrently-arriving KNN queries are packed into
-  one snapshot ``search`` call, sized by the PR-9
-  ``AdaptiveBatchController`` (the same controller that sizes device
-  update batches, so serving batches track device backpressure) within
-  a short packing window (``PATHWAY_TPU_SERVING_BATCH_WINDOW_MS``).
+  one snapshot ``search`` call of at most 1,024 rows within a short
+  packing window (``PATHWAY_TPU_SERVING_BATCH_WINDOW_MS``).
 - **Snapshot reads**: every answer comes from a refcounted immutable
   :class:`~pathway_tpu.serving.snapshot.ReadSnapshot` — queries touch
   no operator state and hold no scheduler lock.
@@ -126,17 +124,8 @@ def stamp_header_value(stamp) -> str:
         return repr(stamp)
 
 
-def _suggested_batch() -> int:
-    """Micro-batch capacity from the device pipeline's adaptive
-    controller — when the device side is backpressured the controller
-    grows its batches, and serving follows so queries amortize into
-    fewer top_k dispatches."""
-    try:
-        from pathway_tpu.engine import device_pipeline as _dp
-
-        return max(1, int(_dp.PIPELINE.controller.batch_size))
-    except Exception:
-        return 1024
+#: rows one packing window may hold before it closes early
+_MICRO_BATCH_ROWS = 1024
 
 
 class _MicroBatcher:
@@ -193,12 +182,11 @@ class _MicroBatcher:
                 if self._stop:
                     pending, self._pending = self._pending, []
                 else:
-                    # packing window: wait briefly for more arrivals, up
-                    # to the controller-suggested batch capacity
-                    cap = _suggested_batch()
+                    # packing window: wait briefly for more arrivals
                     deadline = _time.perf_counter() + self.window_s
                     while (
-                        sum(len(i["vecs"]) for i in self._pending) < cap
+                        sum(len(i["vecs"]) for i in self._pending)
+                        < _MICRO_BATCH_ROWS
                         and not self._stop
                     ):
                         left = deadline - _time.perf_counter()

@@ -50,12 +50,30 @@ def _resolve_device_resident(device_resident: "bool | None") -> bool:
     )
 
 
-def _adaptive_sizer():
-    """Device-pipeline feedback into the embed micro-batch: the adaptive
-    controller can only narrow the configured ``max_batch_size``."""
-    from pathway_tpu.engine import device_pipeline
+#: padded tokens the encoder takes at a time inside one jitted step.  XLA's
+#: dense attention costs more device time a row as a batch grows (TPU v5e:
+#: MiniLM at 128 tokens 24 us a row in blocks of 32 rows, 36 as one block
+#: of 256; BGE-base at 512 tokens 0.95 ms in blocks of 8, 1.04 in one of
+#: 32, 1.17 in one of 64), so a step of ``max_batch_size`` rows is one
+#: dispatch whose program walks the batch in blocks of this many tokens:
+#: the size that was fastest a row at every shape timed (PERF.md, PR 26)
+_STEP_BLOCK_TOKENS = 4096
 
-    return device_pipeline.suggested_batch_size()
+
+def _in_row_blocks(step: Callable, *arrays: Any) -> Any:
+    """``step(*arrays)`` for ``[b, t]`` arrays (``b`` and ``t`` powers of
+    two from 8 up, ``pad_to_buckets``), run inside the traced program as
+    successive passes over row blocks of ``_STEP_BLOCK_TOKENS`` tokens
+    when the batch holds more than one."""
+    import jax
+
+    b, t = arrays[0].shape
+    rows = max(8, _STEP_BLOCK_TOKENS // t)
+    if b <= rows:
+        return step(*arrays)
+    blocks = tuple(a.reshape(b // rows, rows, t) for a in arrays)
+    out = jax.lax.map(lambda block: step(*block), blocks)
+    return out.reshape(b, *out.shape[2:])
 
 
 def _rows_from_device(vecs_dev: Any, real: int, device_resident: bool) -> list:
@@ -179,10 +197,20 @@ class TpuEncoderEmbedder(UDF):
         self._mask_from_ids = pad == 0
         if self._mask_from_ids:
             self._jit_embed_ids = functools.partial(
-                jax.jit(lambda p, ids: embed(p, ids, ids != 0, cfg)), params
+                jax.jit(
+                    lambda p, ids: _in_row_blocks(
+                        lambda x: embed(p, x, x != 0, cfg), ids
+                    )
+                ),
+                params,
             )
         self._jit_embed = functools.partial(
-            jax.jit(lambda p, ids, mask: embed(p, ids, mask, cfg)), params
+            jax.jit(
+                lambda p, ids, mask: _in_row_blocks(
+                    lambda x, m: embed(p, x, m, cfg), ids, mask
+                )
+            ),
+            params,
         )
 
         # device-resident rows skip the device→host→device round trip
@@ -227,9 +255,7 @@ class TpuEncoderEmbedder(UDF):
 
         super().__init__(
             embed_batch,
-            executor=batch_executor(
-                max_batch_size=max_batch_size, sizer=_adaptive_sizer
-            ),
+            executor=batch_executor(max_batch_size=max_batch_size),
             deterministic=True,
             cache_strategy=cache_strategy,
             cache_name=(
@@ -341,9 +367,7 @@ class TpuImageEmbedder(UDF):
             weights_part = f"seed{seed}"
         super().__init__(
             embed_batch,
-            executor=batch_executor(
-                max_batch_size=max_batch_size, sizer=_adaptive_sizer
-            ),
+            executor=batch_executor(max_batch_size=max_batch_size),
             deterministic=True,
             cache_strategy=cache_strategy,
             cache_name=f"TpuImageEmbedder:{preset}:{weights_part}",

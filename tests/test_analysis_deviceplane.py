@@ -487,7 +487,7 @@ class TestFlagLiveness:
             """\
             import os
 
-            _BATCH = int(os.environ.get("PATHWAY_TPU_DEVICE_BATCH", "256"))
+            _INFLIGHT = int(os.environ.get("PATHWAY_TPU_DEVICE_INFLIGHT", "2"))
             """,
         )
         assert _codes(report) == []
@@ -655,5 +655,5 @@ class TestRealTree:
         ):
             assert name in LIVE_FLAGS
         # startup flags must never be classified live by accident
-        assert "PATHWAY_TPU_DEVICE_BATCH" in REGISTRY
-        assert "PATHWAY_TPU_DEVICE_BATCH" not in LIVE_FLAGS
+        assert "PATHWAY_TPU_DEVICE_INFLIGHT" in REGISTRY
+        assert "PATHWAY_TPU_DEVICE_INFLIGHT" not in LIVE_FLAGS

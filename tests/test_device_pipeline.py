@@ -1,6 +1,6 @@
 """Async device pipeline (engine/device_pipeline.py): the double-buffered
-commit staging/completion queues, the adaptive batch controller, and the
-``PATHWAY_TPU_ASYNC_DEVICE`` escape hatch.
+commit staging/completion queues, the ingest window controller, the
+``PATHWAY_TPU_ASYNC_DEVICE`` escape hatch, and the batch executor's step.
 
 The synchronous inline-decay boundary is the bit-exact spec: every parity
 test here runs the same program with the pipeline on and off and asserts
@@ -103,7 +103,6 @@ class TestPipelineUnit:
         assert handle.dev is None  # decayed before the boundary returned
         assert handle.host().shape == (4, 2)
         assert dp.PIPELINE.inflight() == 0
-        assert dp.suggested_batch_size() is None
 
     def test_async_defers_completion_until_drain(self, async_on):
         gate = threading.Event()
@@ -220,8 +219,8 @@ class TestPipelineUnit:
         stats = dp.PIPELINE.stats()
         assert stats["enabled"] and stats["inflight"] == 0
         assert stats["dispatch_complete_p99_ms"] >= 0.0
-        assert set(stats["controller"]) >= {
-            "batch_size", "depth", "window_scale", "ticks"
+        assert set(stats["controller"]) == {
+            "depth", "window_scale", "ticks", "grows"
         }
 
     def test_host_only_commit_is_free(self, async_on):
@@ -288,56 +287,79 @@ class TestWorkerShutdown:
         assert leaked() == [], f"daemons survived the run: {leaked()}"
 
 
-# -- unit: adaptive controller -------------------------------------------------
+# -- unit: ingest window controller ---------------------------------------------
 
 
-class TestAdaptiveController:
-    def test_grows_and_clamps_on_saturation(self):
-        c = dp.AdaptiveBatchController()
-        start = c.batch_size
+class TestWindowController:
+    def test_window_grows_and_clamps_on_saturation(self):
+        c = dp.IngestWindowController()
         c.observe(staged_depth=0, blocked=True, occupancy=1.0)
-        assert c.batch_size == start * 2 and c.grows == 1
-        assert c.window_scale == pytest.approx(1.25)
+        assert c.window_scale == pytest.approx(1.25) and c.grows == 1
         for _ in range(30):
             c.observe(staged_depth=c.depth, blocked=False, occupancy=1.0)
-        assert c.batch_size == c.max_batch
-        assert c.window_scale <= 4.0
+        assert c.window_scale == 4.0 and c.grows == 31
 
-    def test_shrinks_when_device_starved_and_host_bound(self):
-        # tracing off -> no critical-path sample -> host-bound by default
-        assert not tracing.TRACER.enabled
-        c = dp.AdaptiveBatchController()
-        start = c.batch_size
+    def test_window_relaxes_to_unity_when_device_starved(self):
+        c = dp.IngestWindowController()
+        c.window_scale = 4.0
         c.observe(staged_depth=0, blocked=False, occupancy=0.0)
-        assert c.batch_size == start // 2 and c.shrinks == 1
+        assert c.window_scale == pytest.approx(3.2)
         for _ in range(30):
             c.observe(staged_depth=0, blocked=False, occupancy=0.0)
-        assert c.batch_size == c.min_batch
-        assert c.window_scale == 1.0
+        assert c.window_scale == 1.0 and c.grows == 0 and c.ticks == 31
 
     def test_busy_midband_holds_steady(self):
-        c = dp.AdaptiveBatchController()
-        start = c.batch_size
+        c = dp.IngestWindowController()
+        c.window_scale = 2.0
         c.observe(staged_depth=0, blocked=False, occupancy=0.6)
-        assert c.batch_size == start and c.grows == 0 and c.shrinks == 0
+        assert c.window_scale == 2.0 and c.grows == 0 and c.ticks == 1
 
-    def test_env_knobs(self, monkeypatch):
-        monkeypatch.setenv("PATHWAY_TPU_DEVICE_BATCH", "64")
-        monkeypatch.setenv("PATHWAY_TPU_DEVICE_BATCH_MIN", "16")
-        monkeypatch.setenv("PATHWAY_TPU_DEVICE_BATCH_MAX", "128")
+    def test_inflight_bound_read_from_env(self, monkeypatch):
         monkeypatch.setenv("PATHWAY_TPU_DEVICE_INFLIGHT", "3")
-        c = dp.AdaptiveBatchController()
-        assert (c.batch_size, c.min_batch, c.max_batch, c.depth) == (
-            64, 16, 128, 3
-        )
+        c = dp.IngestWindowController()
+        assert c.depth == 3 and c.stats()["depth"] == 3
+        c.observe(staged_depth=2, blocked=False, occupancy=1.0)
+        assert c.grows == 0  # two staged is under the bound of three
         c.observe(staged_depth=3, blocked=False, occupancy=1.0)
-        assert c.batch_size == 128  # clamped at the env max
+        assert c.grows == 1 and c.window_scale == pytest.approx(1.25)
 
 
-# -- unit: executor sizing -----------------------------------------------------
+# -- unit: executor step --------------------------------------------------------
 
 
-class TestExecutorSizer:
+def _idle_ticks(n=10):
+    """What a starved device looks like to the controller, ``n`` commits
+    long (it once halved the embed step at each)."""
+    for _ in range(n):
+        dp.PIPELINE.controller.observe(
+            staged_depth=0, blocked=False, occupancy=0.0
+        )
+
+
+def _embed_real_rows(n_rows, max_batch_size=256):
+    """Real (unpadded) rows of each jitted step a ``TpuEncoderEmbedder``
+    makes for one commit of ``n_rows`` texts (the encoder itself stubbed)."""
+    from pathway_tpu.xpacks.llm.embedders import TpuEncoderEmbedder
+
+    emb = TpuEncoderEmbedder(
+        "minilm_l6", max_len=16, max_batch_size=max_batch_size,
+        device_resident=False,
+    )
+    dim = emb.get_embedding_dimension()
+    real = []
+
+    def step(ids, mask=None):
+        ids = np.asarray(ids)
+        real.append(int((ids != 0).any(axis=1).sum()))
+        return np.zeros((len(ids), dim), np.float32)
+
+    emb._jit_embed_ids = emb._jit_embed = step
+    out = emb._executor.run(emb._fn, [(f"w{i} w{i + 1}",) for i in range(n_rows)])
+    assert len(out) == n_rows and all(ok for ok, _ in out)
+    return real
+
+
+class TestExecutorStep:
     @staticmethod
     def _chunks(executor, n_rows=8):
         sizes = []
@@ -350,25 +372,58 @@ class TestExecutorSizer:
         assert [v for ok, v in out] == list(range(n_rows))
         return sizes
 
-    def test_sizer_narrows_configured_cap(self):
-        sizes = self._chunks(batch_executor(max_batch_size=8, sizer=lambda: 2))
-        assert sizes == [2, 2, 2, 2]
+    @pytest.mark.parametrize(
+        "cap,n_rows,expected",
+        [
+            (4, 8, [4, 4]),
+            (4, 10, [4, 4, 2]),
+            (256, 2030, [256] * 7 + [238]),
+            (64, 3, [3]),  # a commit under the cap is one chunk
+        ],
+    )
+    def test_chunks_hold_the_cap_and_a_shorter_tail(self, cap, n_rows, expected):
+        sizes = self._chunks(batch_executor(max_batch_size=cap), n_rows)
+        assert sizes == expected
 
-    def test_sizer_never_exceeds_cap(self):
-        sizes = self._chunks(
-            batch_executor(max_batch_size=4, sizer=lambda: 100)
-        )
-        assert sizes == [4, 4]
+    def test_no_cap_means_one_chunk(self):
+        assert self._chunks(batch_executor(), n_rows=2030) == [2030]
 
-    def test_falsy_sizer_value_is_ignored(self):
-        sizes = self._chunks(batch_executor(sizer=lambda: None))
-        assert sizes == [8]
+    def test_idle_device_does_not_narrow_the_step(self, async_on):
+        _idle_ticks()
+        sizes = self._chunks(batch_executor(max_batch_size=8), n_rows=20)
+        assert sizes == [8, 8, 4]
 
-    def test_suggested_batch_size_tracks_mode(self, monkeypatch):
-        monkeypatch.setenv("PATHWAY_TPU_ASYNC_DEVICE", "1")
-        assert dp.suggested_batch_size() == dp.PIPELINE.controller.batch_size
-        monkeypatch.setenv("PATHWAY_TPU_ASYNC_DEVICE", "0")
-        assert dp.suggested_batch_size() is None
+    @pytest.mark.parametrize(
+        "cap,calls,narrowed",
+        [(4, 3, 1), (None, 1, 0)],  # 2.5 caps: the tail is short; no cap: never
+    )
+    def test_stage_counts_rows_and_short_chunks(self, cap, calls, narrowed):
+        root = tracing.STAGES.begin_run()
+        try:
+            self._chunks(batch_executor(max_batch_size=cap), n_rows=10)
+        finally:
+            tracing.STAGES.end_run(root)
+        row = tracing.stage_totals()["stages"]["udf.batch"]
+        assert row["calls"] == calls
+        assert row["counts"] == {"rows": 10, "narrowed": narrowed}
+
+    def test_embedder_steps_at_its_cap_after_idle_ticks(self, async_on):
+        _idle_ticks()
+        assert _embed_real_rows(600) == [256, 256, 88]
+
+    def test_embedder_chunking_ignores_tracing(self, async_on):
+        assert not tracing.TRACER.enabled
+        _idle_ticks()
+        off = _embed_real_rows(150, max_batch_size=64)
+        tracing.TRACER.configure(enabled=True, sample=1, clear=True)
+        try:
+            _idle_ticks()
+            on = _embed_real_rows(150, max_batch_size=64)
+        finally:
+            tracing.TRACER.drop()
+            tracing.TRACER.configure(enabled=False, clear=True)
+            tracing.TRACER.epoch = 0
+        assert off == on == [64, 64, 22]
 
 
 # -- critical-path shares (tracing satellite) ---------------------------------

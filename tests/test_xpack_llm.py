@@ -324,3 +324,49 @@ def test_embedder_mask_from_ids_path_matches_explicit_mask():
     # two distinct jitted programs: semantically equal, but fusion order
     # may differ per backend — tight tolerance, not bit equality
     assert np.allclose(via_ids, explicit, atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("path", ["ids_only", "explicit_mask"])
+def test_embedder_step_in_row_blocks_matches_whole_batch(path, monkeypatch):
+    """A step over more tokens than one block (the jitted program then
+    walks the batch a block of rows at a time) embeds every row as the
+    whole-batch forward does, in the rows' order."""
+    import jax.numpy as jnp
+
+    from pathway_tpu.models import embed
+    from pathway_tpu.xpacks.llm import embedders
+
+    # 32 rows a block at 16 tokens: four blocks, at a size a CPU test affords
+    monkeypatch.setattr(embedders, "_STEP_BLOCK_TOKENS", 512)
+    emb = TpuEncoderEmbedder(
+        "minilm_l6", max_len=16, max_batch_size=128, device_resident=False
+    )
+    rows = 128
+    ids = np.random.default_rng(0).integers(1, 3000, (rows, 16), dtype=np.int32)
+    ids[:, 9:] = 0
+    ids[::3, 5:] = 0
+    mask = ids != 0
+    if path == "ids_only":
+        lowered = emb._jit_embed_ids.func.lower(emb._params, jnp.asarray(ids))
+        got = emb._jit_embed_ids(jnp.asarray(ids))
+    else:
+        lowered = emb._jit_embed.func.lower(
+            emb._params, jnp.asarray(ids), jnp.asarray(mask)
+        )
+        got = emb._jit_embed(jnp.asarray(ids), jnp.asarray(mask))
+    assert "while" in lowered.as_text()  # the blocks' loop is in the program
+    whole = embed(emb._params, jnp.asarray(ids), jnp.asarray(mask), emb.config)
+    got, whole = np.asarray(got), np.asarray(whole)
+    assert got.shape == whole.shape == (rows, emb.get_embedding_dimension())
+    # bfloat16 compute, another order of accumulation: rounding, no more
+    assert np.abs(got - whole).max() < 5e-3
+    # a row swapped with its neighbour would be far off
+    assert np.abs(got - np.roll(whole, 1, axis=0)).max() > 0.05
+
+
+def test_embedder_step_of_one_block_has_no_loop():
+    import jax.numpy as jnp
+
+    emb = TpuEncoderEmbedder("minilm_l6", max_len=16, device_resident=False)
+    ids = jnp.ones((256, 16), jnp.int32)  # 4,096 tokens: one block
+    assert "while" not in emb._jit_embed_ids.func.lower(emb._params, ids).as_text()
