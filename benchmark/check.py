@@ -1,4 +1,6 @@
-"""The comparison that decides ``correct``.
+"""The comparison that decides ``correct`` in a live-index cell
+(``pipelines/live_index.py`` hands over to it), and the counter of compile
+requests that every pipeline's window is held to.
 
 Once the window has closed it compares what the timed path produced — the
 embeddings and answers the sinks saw, the rows the index holds — with the
@@ -76,9 +78,9 @@ def index_facts(index, obs, prefilled: int, seed: int, schedule) -> dict:
     from pathway_tpu.engine import collective_exchange as cx
     from pathway_tpu.engine import device_ops as dops
 
-    done = ~np.isnan(obs.doc_ack[:-1])  # the primer is no sample
+    done = ~np.isnan(obs.documents.ack[:-1])  # the primer is no sample
     sample = draw_sample(seed, done, schedule.documents.tokens, SAMPLE_TEXTS)
-    slots = [index.key_to_slot.get(obs.doc_key[i]) for i in sample]
+    slots = [index.key_to_slot.get(obs.evidence["doc_key"][i]) for i in sample]
     held = [s for s in slots if s is not None]
     rows = np.asarray(index.state.vectors[jnp.asarray(held, jnp.int32)]) if held else None
     by_slot = dict(zip(held, rows)) if held else {}
@@ -102,32 +104,32 @@ def sampled_texts(seed: int, schedule, obs, facts: dict):
     the sampled queries — with what the sink saw, and how many of them are
     documents."""
     texts = [schedule.documents.texts[i] for i in facts["doc_sample"]]
-    seen = [obs.doc_emb[i] for i in facts["doc_sample"]]
+    seen = [obs.evidence["doc_emb"][i] for i in facts["doc_sample"]]
     n_docs = len(texts)
     if schedule.queries is not None:
-        done = ~np.isnan(obs.query_ack[:-1])
+        done = ~np.isnan(obs.queries.ack[:-1])
         for i in draw_sample(seed, done, schedule.queries.tokens, SAMPLE_TEXTS):
             texts.append(schedule.queries.texts[i])
-            seen.append(obs.query_emb[i])
+            seen.append(obs.evidence["query_emb"][i])
     return texts, np.stack(seen) if seen else np.zeros((0, 1), np.float32), n_docs
 
 
 def answer_sample(seed: int, schedule, obs):
     """The answered queries that are compared, with which documents the
     index held at each one's commit."""
-    done = ~np.isnan(obs.query_ack[:-1])
+    done = ~np.isnan(obs.queries.ack[:-1])
     sample = draw_sample(seed, done, schedule.queries.tokens, SAMPLE_QUERIES)
-    docs = np.flatnonzero(~np.isnan(obs.doc_ack))  # the primer too: it is in the index
-    live = obs.doc_commit[docs][None, :] <= obs.query_commit[sample][:, None]
+    docs = np.flatnonzero(~np.isnan(obs.documents.ack))  # the primer too: it is in the index
+    live = obs.documents.commit[docs][None, :] <= obs.queries.commit[sample][:, None]
     return sample, docs, live
 
 
 def answer_vectors(obs, sample, docs):
     """The sampled queries' and the indexed documents' vectors, as the sinks saw them."""
-    queries = np.stack([obs.query_emb[i] for i in sample])
+    queries = np.stack([obs.evidence["query_emb"][i] for i in sample])
     if not len(docs):
         return queries, np.zeros((0, queries.shape[1]), np.float32)
-    return queries, np.stack([obs.doc_emb[i] for i in docs])
+    return queries, np.stack([obs.evidence["doc_emb"][i] for i in docs])
 
 
 def knn_gap(seed: int, obs, sample, docs, live, prefilled: int, k: int, moments, best, answers=None):
@@ -138,11 +140,11 @@ def knn_gap(seed: int, obs, sample, docs, live, prefilled: int, k: int, moments,
     stands in for the sink's answers when the control is judged."""
     queries, doc_vectors = answer_vectors(obs, sample, docs)
     if answers is None:
-        doc_of_key = {obs.doc_key[i]: n for n, i in enumerate(docs)}
+        doc_of_key = {obs.evidence["doc_key"][i]: n for n, i in enumerate(docs)}
         answers = []
         for i in sample:
             ids = []
-            for key in obs.query_ids[i]:
+            for key in obs.evidence["query_ids"][i]:
                 slot = int(key) - reference.PREFILL_KEY_BASE
                 if key in doc_of_key:
                     ids.append(-1 - doc_of_key[key])
@@ -150,7 +152,7 @@ def knn_gap(seed: int, obs, sample, docs, live, prefilled: int, k: int, moments,
                     ids.append(slot)
                 else:
                     ids.append(None)
-            answers.append((ids, obs.query_scores[i]))
+            answers.append((ids, obs.evidence["query_scores"][i]))
     wanted = sorted({i for ids, _ in answers for i in ids if i is not None and i >= 0})
     pre_rows = reference.prefill_rows(seed, wanted, prefilled, moments) if wanted else {}
     gap, bad = 0.0, 0
@@ -169,18 +171,18 @@ def knn_gap(seed: int, obs, sample, docs, live, prefilled: int, k: int, moments,
 def stale_answers(obs) -> int:
     """Queries sent after a document was acknowledged at the sink, yet
     answered at a commit before that document's."""
-    acked = np.flatnonzero(~np.isnan(obs.doc_ack))
-    answered = np.flatnonzero(~np.isnan(obs.query_ack))
+    acked = np.flatnonzero(~np.isnan(obs.documents.ack))
+    answered = np.flatnonzero(~np.isnan(obs.queries.ack))
     if not len(acked) or not len(answered):
         return 0
-    order = acked[np.argsort(obs.doc_ack[acked])]
-    newest = np.maximum.accumulate(obs.doc_commit[order])
-    before = np.searchsorted(obs.doc_ack[order], obs.query_sent[answered])
+    order = acked[np.argsort(obs.documents.ack[acked])]
+    newest = np.maximum.accumulate(obs.documents.commit[order])
+    before = np.searchsorted(obs.documents.ack[order], obs.queries.sent[answered])
     must = np.where(before > 0, newest[np.maximum(before, 1) - 1], -1)
-    return int(np.count_nonzero(must > obs.query_commit[answered]))
+    return int(np.count_nonzero(must > obs.queries.commit[answered]))
 
 
-def compare(cell, seed: int, *, schedule, obs, facts: dict, params, stand_in=None, memo=None) -> list[dict]:
+def compare(cell, seed: int, *, schedule, obs, facts: dict, stand_in=None, memo=None) -> list[dict]:
     """Every number compared, with its limit and whether it holds.
 
     ``stand_in`` puts a control in the program's place for the numbers it
@@ -189,7 +191,7 @@ def compare(cell, seed: int, *, schedule, obs, facts: dict, params, stand_in=Non
     the sampled answers (as :func:`knn_gap` takes them). ``memo``, a dict
     the caller keeps, saves the reference's work between such calls."""
     config = cell.config
-    enc, limits = config["encoder"], cell.limits
+    enc, limits, params = config["encoder"], cell.limits, facts["params"]
     stand_in = stand_in or {}
     memo = {} if memo is None else memo
     numbers: list[dict] = []
@@ -202,18 +204,18 @@ def compare(cell, seed: int, *, schedule, obs, facts: dict, params, stand_in=Non
         ok = limit is not None and bool(np.isfinite(value)) and value <= limit
         numbers.append({"name": name, "value": float(value), "limit": limit, "ok": ok})
 
-    exact("docs_lost", obs.sent_docs() - obs.docs_acked)
-    exact("docs_repeated", obs.doc_repeats)
+    exact("docs_lost", obs.documents.n_sent() - obs.documents.acked)
+    exact("docs_repeated", obs.documents.repeats)
     exact("error_log", len(obs.errors))
     exact("device_errors", facts["device_errors"])
     exact("compiles_in_window", facts["compiles_in_window"])
     exact("pool_exhausted", obs.pool_exhausted)
     exact("index_grown", facts["index_capacity"] != config["index"]["capacity"])
-    exact("index_count_off", facts["index_len"] - facts["prefilled"] - obs.docs_acked)
+    exact("index_count_off", facts["index_len"] - facts["prefilled"] - obs.documents.acked)
     exact(
         "index_rows_off",
         sum(
-            row is None or not np.array_equal(row, obs.doc_emb[i])
+            row is None or not np.array_equal(row, obs.evidence["doc_emb"][i])
             for i, row in zip(facts["doc_sample"], facts["index_rows"])
         ),
     )
@@ -236,8 +238,8 @@ def compare(cell, seed: int, *, schedule, obs, facts: dict, params, stand_in=Non
         file=sys.stderr,
     )
     if schedule.queries is not None:
-        exact("queries_lost", obs.sent_queries() - obs.queries_acked)
-        exact("queries_repeated", obs.query_repeats)
+        exact("queries_lost", obs.queries.n_sent() - obs.queries.acked)
+        exact("queries_repeated", obs.queries.repeats)
         sample, docs, live = answer_sample(seed, schedule, obs)
         k, prefilled, moments = config["index"]["k"], facts["prefilled"], facts["prefill_moments"]
         gap, bad, from_prefill = float("inf"), 0, 0

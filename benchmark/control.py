@@ -1,5 +1,6 @@
 #!/usr/bin/env python3
-"""Readings for the limits of ``correct``, on the chip, at a cell's own size:
+"""Readings for the limits of ``correct`` in a live-index cell (the controls
+of ``pipelines/live_index.py``'s comparison), on the chip, at a cell's own size:
 
     python3 benchmark/control.py --workload <cell> --seeds 1,2,3 --seconds 4
 
@@ -43,7 +44,8 @@ def readings(cell, seed: int, seconds: float, devices) -> dict:
     import reference
 
     _, evidence = harness.measure(cell, seed, seconds, False, devices, time.time())
-    schedule, obs, facts, params = (evidence[k] for k in ("schedule", "obs", "facts", "params"))
+    schedule, obs, facts = (evidence[k] for k in ("schedule", "obs", "facts"))
+    params = facts["params"]
     memo: dict = {}
     out = {"seed": seed, "program": _verdict(check.compare(cell, seed, **evidence, memo=memo))}
     enc, max_len = cell.config["encoder"], cell.config["embedder"]["max_len"]

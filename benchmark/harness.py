@@ -1,12 +1,13 @@
-"""One run of one cell: set-up, the measured window under ``pw.run()``, the
-end-to-end and per-layer metrics, and the hand-over to ``check.py``.
+"""One run of one cell: what every pipeline shares. The cell's files, the
+feeds and their clock, what the feeds and the sinks saw, the traced part of
+the window, ``pw.run()`` and the drain, the end-to-end and per-layer
+metrics, the result line.
 
-The graph is the one ``chip_smoke.py`` proved on the chip, built through
-the public API:
-
-    pw.io.python.read(documents) -> TpuEncoderEmbedder -> DataIndex(TpuKnnFactory)
-      -> index.query_as_of_now(queries, k) <- pw.io.python.read(queries)
-      -> pw.io.subscribe
+What belongs to one graph — weights, set-up and warm-up, the graph and its
+sinks, the work a step counts, the comparison with the reference — is the
+cell's pipeline: ``benchmark/pipelines/<name>.py``, named by the
+configuration's ``"pipeline"`` key (``pipelines/live_index.py`` where it has
+none; its docstring has the interface).
 
 ``run.py`` looks for the chip and calls :func:`run_cell`; the tests call it
 without the look, at a toy size.
@@ -16,7 +17,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 import gc
 import json
 import os
@@ -24,13 +24,12 @@ import sys
 import tempfile
 import threading
 import time
+from typing import Any
 
 import numpy as np
 
 import check
-import costs
 import readers
-import reference
 import trace as trace_mod
 import traffic
 
@@ -40,14 +39,11 @@ GRACE_S = 60.0
 #: the traced part of a ``--trace 1`` window: starts this long after the
 #: window opens (or a quarter into a shorter one) and lasts this long
 TRACE_START_S, TRACE_LENGTH_S = 2.0, 4.0
-
-
-def _buckets_up_to(limit: int) -> list[int]:
-    out, b = [], 8
-    while b <= limit:
-        out.append(b)
-        b *= 2
-    return out
+PIPELINES = os.path.join(HERE, "pipelines")
+#: the pipeline of a configuration that names none
+DEFAULT_PIPELINE = "live_index"
+#: what a pipeline's module defines (``pipelines/live_index.py`` says what each is)
+PIPELINE_INTERFACE = ("weights", "set_up", "build", "restore", "work_flops", "facts", "compare")
 
 
 # -- what a cell is -----------------------------------------------------------
@@ -62,6 +58,22 @@ class Cell:
     limits: dict  # of the numbers ``correct`` compares (limits/<cell>.json)
     end_to_end: list[dict]  # this cell's end-to-end metrics (BENCHMARK.json)
     per_layer: list[dict]  # this cell's per-layer metrics, each with its file
+    pipeline: Any  # the module the configuration names (find_pipeline)
+
+
+def find_pipeline(name: str, directory: str = PIPELINES):
+    """The module ``<directory>/<name>.py``, as ``readers.find`` finds a
+    reader; a name with no file, or a file without the interface, ends the
+    run here, before any device call."""
+    path = os.path.join(directory, name + ".py")
+    if not os.path.exists(path):
+        known = sorted(f[:-3] for f in os.listdir(directory) if f.endswith(".py"))
+        raise SystemExit(f"unknown pipeline {name!r}: no {path}; known: {known}")
+    module = readers.load_module("pipeline_" + name, path)
+    missing = [part for part in PIPELINE_INTERFACE if not callable(getattr(module, part, None))]
+    if missing:
+        raise SystemExit(f"pipeline {name!r} ({path}) does not define {missing}")
+    return module
 
 
 def load_cell(root: str, workload: str) -> Cell:
@@ -90,162 +102,89 @@ def load_cell(root: str, workload: str) -> Cell:
         if reports(metric) if "workloads" in metric else metric["moves"] in reported:
             with open(os.path.join(HERE, "layer_metrics", metric["name"] + ".json")) as fh:
                 per_layer.append({**metric, **json.load(fh)})
-    return Cell(workload, entry["chips"], config, mix, limits, end_to_end, per_layer)
+    pipeline = find_pipeline(config.get("pipeline", DEFAULT_PIPELINE))
+    return Cell(workload, entry["chips"], config, mix, limits, end_to_end, per_layer, pipeline)
 
 
-# -- what the sinks and the wrappers saw --------------------------------------
+# -- what the feeds, the sinks and the wrappers saw ---------------------------
+
+
+class Seen:
+    """One stream's events as its feed and its sink saw them. The last slot
+    of every array is the feed's primer."""
+
+    def __init__(self, stream: traffic.Stream | None, field: str) -> None:
+        n = len(stream.texts) + 1 if stream is not None else 0
+        self.field = field  # the column that carries an event's number
+        self.sent = np.full(n, np.nan)
+        self.ack = np.full(n, np.nan)
+        self.commit = np.full(n, -1, np.int64)
+        self.commits: set = set()  # distinct commit times at the sink
+        self.repeats = 0
+        self.acked = 0
+
+    def n_sent(self) -> int:
+        return int(np.count_nonzero(~np.isnan(self.sent)))
 
 
 class Observed:
-    """Filled by the feeds, the sinks and the wrappers while the window
-    runs; read once it has closed. One writer a field: the engine's thread
-    for the sinks and wrappers, each feed for its own send times.
+    """Filled by the feeds, the sinks and the pipeline's wrappers while the
+    window runs; read once it has closed. One writer a field: the engine's
+    thread for the sinks and wrappers, each feed for its own send times.
 
     Before the window opens each feed sends one primer (its first text
     again, under the id one past its last) and waits for it at the sink, so
-    that the whole path has run once; a primer is in the index and may be
-    in an answer, and is in no metric."""
+    that the whole path has run once; a primer went through the graph like
+    any event (the live index holds it and may answer with it), and is in no
+    metric."""
 
     def __init__(self, schedule: traffic.Schedule) -> None:
-        # the last slot of every per-event list is the feed's primer
-        n_docs = len(schedule.documents.texts) + 1
-        n_queries = len(schedule.queries.texts) + 1 if schedule.queries else 0
+        self.documents = Seen(schedule.documents, "doc_id")
+        self.queries = Seen(schedule.queries, "query_id")
         self.primers = 2 if schedule.queries else 1
         self.compiles_at_open = 0
         self.opened_at = 0.0  # time.time() at the window's start, for setup_s
         self.t0 = 0.0  # the same instant by time.perf_counter(), for everything else
         self.t_end = 0.0
-        self.doc_sent = np.full(n_docs, np.nan)
-        self.doc_ack = np.full(n_docs, np.nan)
-        self.doc_commit = np.full(n_docs, -1, np.int64)
-        self.doc_key: list = [None] * n_docs
-        self.doc_emb: list = [None] * n_docs
-        self.doc_repeats = 0
-        self.docs_acked = 0
-        self.query_sent = np.full(n_queries, np.nan)
-        self.query_ack = np.full(n_queries, np.nan)
-        self.query_commit = np.full(n_queries, -1, np.int64)
-        self.query_ids: list = [None] * n_queries
-        self.query_scores: list = [None] * n_queries
-        self.query_emb: list = [None] * n_queries
-        self.query_repeats = 0
-        self.queries_acked = 0
         self.errors: list[str] = []
         self.pool_exhausted = False
-        self.counters = {
-            "embed_calls_doc": 0, "embed_rows_doc": 0,
-            "embed_calls_query": 0, "embed_rows_query": 0,
-            "search_calls": 0, "search_queries": 0,
-        }
-        #: (time, "embed", batch, seq) and (time, "search", queries, 0), for
-        #: the traced part of the window
+        #: the pipeline's counts (zeroed when the window opens) and, after the
+        #: run, ``doc_commits``
+        self.counters: dict[str, int] = {}
+        #: (time, kind, *shape) of the pipeline's device calls, for the traced
+        #: part of the window
         self.device_calls: list[tuple] = []
+        #: what the pipeline's sinks keep for its comparison; the pipeline's own
+        self.evidence: dict = {}
 
-    def sent_docs(self) -> int:
-        return int(np.count_nonzero(~np.isnan(self.doc_sent)))
+    def streams(self) -> tuple[Seen, Seen]:
+        return self.documents, self.queries
 
-    def sent_queries(self) -> int:
-        return int(np.count_nonzero(~np.isnan(self.query_sent)))
-
-
-# -- set-up -------------------------------------------------------------------
-
-
-def make_embedder(config: dict, params):
-    """The program's embedder with the benchmark's weights; refuses a
-    program whose preset is not the configuration's file."""
-    from pathway_tpu.xpacks.llm.embedders import TpuEncoderEmbedder
-
-    enc = config["encoder"]
-    embedder = TpuEncoderEmbedder(
-        model=enc["model"],
-        max_len=config["embedder"]["max_len"],
-        max_batch_size=config["embedder"]["max_batch_size"],
-        seq_bucket_min=config["embedder"]["seq_bucket_min"],
-        params=params,
-    )
-    have = embedder.config
-    want = (
-        enc["hidden_size"], enc["num_hidden_layers"], enc["num_attention_heads"],
-        enc["intermediate_size"], enc["vocab_size"], enc["pooling"],
-    )
-    got = (have.hidden, have.layers, have.heads, have.intermediate, have.vocab_size, have.pooling)
-    if got != want or np.dtype(have.dtype).name != enc["compute_dtype"]:
-        raise RuntimeError(f"the program's {enc['model']} is {got}, the configuration says {want}")
-    return embedder
-
-
-def prefilled_index(config: dict, seed: int, moments, mesh):
-    """A ``DeviceKnnIndex`` at the configuration's capacity holding its
-    prefilled rows, through ``restore_op_state`` — the path a restarted
-    deployment takes — with device arrays made from the seed round
-    ``moments`` (``reference.prefill_moments``)."""
-    from pathway_tpu.engine.external_index import DeviceKnnIndex
-    from pathway_tpu.engine.value import Pointer
-
-    spec = config["index"]
-    dim = config["encoder"]["hidden_size"]
-    vectors, valid, norms = reference.make_prefill(
-        seed, spec["capacity"], spec["prefilled"], moments, mesh
-    )
-    # int.__new__ skips Pointer's masking to 128 bits, which these need not
-    keys = map(
-        functools.partial(int.__new__, Pointer),
-        range(reference.PREFILL_KEY_BASE, reference.PREFILL_KEY_BASE + spec["prefilled"]),
-    )
-    index = DeviceKnnIndex(dim=dim, metric=spec["metric"], capacity=8, mesh=mesh)
-    index.restore_op_state(
-        {
-            "vectors": vectors,
-            "valid": valid,
-            "norms": norms,
-            "key_to_slot": dict(zip(keys, range(spec["prefilled"]))),
-            "free": range(spec["capacity"] - 1, spec["prefilled"] - 1, -1),
-            "capacity": spec["capacity"],
-        }
-    )
-    return index
-
-
-def warm_up(embedder, index, cell: Cell) -> None:
-    """Run every shape this cell's traffic can produce, on the index the
-    window will use: the encoder at each batch bucket by each sequence
-    bucket of the mix's lengths, the gather and update at each batch
-    bucket, the search at each batch bucket. The rows it adds are removed
-    again."""
-    from pathway_tpu.engine.value import Pointer
-
-    mix, k = cell.mix, cell.config["index"]["k"]
-    batches = _buckets_up_to(cell.config["embedder"]["max_batch_size"])
-    least = cell.config["embedder"]["seq_bucket_min"]
-    doc_seqs = traffic.seq_buckets(mix["documents"], least)
-    query_seqs = traffic.seq_buckets(mix["queries"], least) if mix.get("queries") else []
-    added = []
-    for batch in batches:
-        for seq in sorted(set(doc_seqs) | set(query_seqs)):
-            text = " ".join(["w0"] * (seq - traffic.SPECIAL_TOKENS))
-            rows = embedder._fn([text] * batch)
-        if query_seqs:
-            index.search(rows, k)
-        keys = [Pointer(reference.PREFILL_KEY_BASE - 1 - len(added) - i) for i in range(batch)]
-        index.add(keys, rows)
-        added += keys
-    index.remove(added)
-    np.asarray(index.state.valid[:1])  # wait for the device to finish
+    def waits_ms(self, stream: str, plan: traffic.Stream) -> np.ndarray:
+        """How long every event of an open loop waited, ms: from the due time
+        its schedule gave it to the sink's callback; an event never
+        acknowledged waited the whole grace."""
+        ack = getattr(self, stream).ack[:-1]  # without the primer
+        ack = np.where(np.isnan(ack), self.t_end + GRACE_S, ack)
+        return (ack - (self.t0 + plan.due_s)) * 1e3
 
 
 # -- the window ---------------------------------------------------------------
 
 
-class _Clock:
-    """Opens the window when every feed has started."""
+class Clock:
+    """Opens the window when every feed has started, and acknowledges what
+    the sinks see."""
 
-    def __init__(self, feeds: int, seconds: float, obs: Observed, compiles) -> None:
+    def __init__(self, schedule: traffic.Schedule, seconds: float, obs: Observed, compiles, span) -> None:
+        feeds = sum(s is not None for s in (schedule.documents, schedule.queries))
         self._barrier = threading.Barrier(feeds, action=self._open)
+        self.schedule = schedule
         self.seconds = seconds
         self.obs = obs
         self.compiles = compiles
-        self.primed = {"doc_id": threading.Event(), "query_id": threading.Event()}
+        self.span = span
+        self.primed = {seen.field: threading.Event() for seen in obs.streams()}
         self.opened = threading.Event()
         self.drained = threading.Event()
         self.acked = threading.Condition()
@@ -272,18 +211,46 @@ class _Clock:
             self.sending -= 1
         deadline = self.obs.t_end + GRACE_S
         while not self.drained.is_set() and time.perf_counter() < deadline:
-            obs = self.obs
-            if (
-                self.sending == 0
-                and obs.docs_acked >= obs.sent_docs()
-                and obs.queries_acked >= obs.sent_queries()
-            ):
+            if self.sending == 0 and all(s.acked >= s.n_sent() for s in self.obs.streams()):
                 self.drained.set()
             self.drained.wait(0.02)
 
+    def sink(self, stream: str, take):
+        """The ``on_change`` of the sink of ``"documents"`` or ``"queries"``:
+        ``take(i, key, row)`` keeps the pipeline's evidence of event ``i``
+        (waiting for whatever the row still has on the device), then the
+        event is acknowledged. A retraction, or an event seen before, is a
+        repeat and is not taken."""
+        seen: Seen = getattr(self.obs, stream)
+        field, ack, commit, commits = seen.field, seen.ack, seen.commit, seen.commits
+        primer, primed = len(ack) - 1, self.primed[seen.field]
+        span, name, now = self.span, "sink_" + stream, time.perf_counter
 
-def _make_feed(pw, stream: traffic.Stream, field: str, sent: np.ndarray, clock: _Clock, span):
-    obs = clock.obs
+        def on_change(key, row, time, is_addition):  # the names pw.io.subscribe calls it by
+            with span(name):
+                i = row[field]
+                if not is_addition or commit[i] != -1:
+                    seen.repeats += 1
+                    return
+                take(i, key, row)
+                commit[i] = time
+                ack[i] = now()
+                seen.acked += 1
+                commits.add(time)
+                if i == primer:
+                    primed.set()
+
+        return on_change
+
+    def on_time_end(self, time_) -> None:
+        """The ``on_time_end`` of a closed loop's sink: a commit has been
+        acknowledged, the feed may have room again."""
+        with self.acked:
+            self.acked.notify_all()
+
+
+def _make_feed(pw, stream: traffic.Stream, seen: Seen, clock: Clock):
+    obs, span, field, sent = clock.obs, clock.span, seen.field, seen.sent
 
     class Feed(pw.io.python.ConnectorSubject):
         def run(self) -> None:
@@ -314,7 +281,7 @@ def _make_feed(pw, stream: traffic.Stream, field: str, sent: np.ndarray, clock: 
             texts, budget = stream.texts, stream.in_flight
             i = 0
             while time.perf_counter() < obs.t_end:
-                room = budget - (i + 1 - obs.docs_acked)  # 1: the primer
+                room = budget - (i + 1 - seen.acked)  # 1: the primer
                 if room <= 0:
                     with clock.acked:
                         clock.acked.wait(0.01)
@@ -332,151 +299,29 @@ def _make_feed(pw, stream: traffic.Stream, field: str, sent: np.ndarray, clock: 
     return Feed()
 
 
-def run_window(cell: Cell, embedder, index, schedule: traffic.Schedule, seconds: float, trace: bool, compiles):
-    """Build the graph, run it under ``pw.run()`` for ``seconds`` and until
-    what was sent is acknowledged. Returns what was observed and, for a
-    traced run, the trace's events and the traced seconds."""
+def run_window(cell: Cell, state, schedule: traffic.Schedule, seconds: float, trace: bool, compiles):
+    """Have the pipeline build its graph, run it under ``pw.run()`` for
+    ``seconds`` and until what was sent is acknowledged. Returns what was
+    observed and, for a traced run, the trace's events and the traced
+    seconds."""
     import jax
     import pathway_tpu as pw
     from pathway_tpu.internals.parse_graph import G
-    from pathway_tpu.stdlib.indexing import DataIndex, TpuKnnFactory
 
     G.clear()
     obs = Observed(schedule)
-    counters = obs.counters
-    k = cell.config["index"]["k"]
-    streams = [s for s in (schedule.documents, schedule.queries) if s is not None]
-    clock = _Clock(len(streams), seconds, obs, compiles)
 
     def span(name: str):
         if trace:
             return jax.profiler.TraceAnnotation(trace_mod.SPAN_PREFIX + name)
         return contextlib.nullcontext()
 
-    # -- wrappers: counts in every run, host spans in a traced one
-    doc_texts = set(schedule.documents.texts)
-    inner_fn = embedder._fn
-
-    def embed_fn(texts):
-        kind = "doc" if texts[0] in doc_texts else "query"
-        counters[f"embed_calls_{kind}"] += 1
-        counters[f"embed_rows_{kind}"] += len(texts)
-        with span("embed_call"):
-            return inner_fn(texts)
-
-    wrapped_jits = {}
-    for attr in ("_jit_embed_ids", "_jit_embed"):
-        inner = getattr(embedder, attr, None)
-        if inner is not None:
-            wrapped_jits[attr] = inner
-
-            def jit_call(ids, *rest, _inner=inner):
-                obs.device_calls.append((time.perf_counter(), "embed", *ids.shape))
-                return _inner(ids, *rest)
-
-            setattr(embedder, attr, jit_call)
-    if not wrapped_jits:
-        raise RuntimeError(
-            "the embedder has neither _jit_embed_ids nor _jit_embed: the benchmark cannot see "
-            "its device calls, and the encoder's roofline would have nothing to read"
-        )
-    embedder._fn = embed_fn
-
-    class Factory(TpuKnnFactory):
-        def build(self):
-            inner_add, inner_search = index.add, index.search
-
-            def add(keys, vectors):
-                with span("index_add"):
-                    return inner_add(keys, vectors)
-
-            def search(queries, k_):
-                counters["search_calls"] += 1
-                counters["search_queries"] += len(queries)
-                obs.device_calls.append((time.perf_counter(), "search", len(queries), 0))
-                with span("index_search"):
-                    return inner_search(queries, k_)
-
-            index.add, index.search = add, search
-            return index
-
-    # -- the graph
-    doc_commits: set = set()
-    docs = pw.io.python.read(
-        _make_feed(pw, schedule.documents, "doc_id", obs.doc_sent, clock, span),
-        schema=pw.schema_from_types(doc_id=int, text=str),
-        autocommit_duration_ms=cell.config["doc_autocommit_ms"],
-    )
-    docs = docs.select(doc_id=pw.this.doc_id, emb=embedder(pw.this.text))
-    data_index = DataIndex(
-        docs,
-        Factory(
-            dimensions=embedder.get_embedding_dimension(),
-            metric=cell.config["index"]["metric"],
-            capacity=cell.config["index"]["capacity"],
-            mesh=index.mesh,
-        ),
-        docs.emb,
-    )
-
-    def on_doc(key, row, time_, is_addition):
-        with span("sink_doc"):
-            i = row["doc_id"]
-            if not is_addition or obs.doc_key[i] is not None:
-                obs.doc_repeats += 1
-                return
-            obs.doc_emb[i] = np.asarray(row["emb"], np.float32)
-            obs.doc_key[i] = key
-            obs.doc_commit[i] = time_
-            obs.doc_ack[i] = time.perf_counter()
-            obs.docs_acked += 1
-            doc_commits.add(time_)
-            if i == len(obs.doc_key) - 1:
-                clock.primed["doc_id"].set()
-
-    def on_doc_commit(time_):
-        with clock.acked:
-            clock.acked.notify_all()
-
-    pw.io.subscribe(
-        docs,
-        on_change=lambda key, row, time, is_addition: on_doc(key, row, time, is_addition),
-        on_time_end=on_doc_commit,
-    )
-    if schedule.queries is not None:
-        queries = pw.io.python.read(
-            _make_feed(pw, schedule.queries, "query_id", obs.query_sent, clock, span),
-            schema=pw.schema_from_types(query_id=int, text=str),
-            autocommit_duration_ms=schedule.queries.autocommit_ms,
-        )
-        queries = queries.select(query_id=pw.this.query_id, qemb=embedder(pw.this.text))
-        answers = data_index.query_as_of_now(queries, queries.qemb, number_of_matches=k)
-
-        def on_answer(key, row, time_, is_addition):
-            with span("sink_answer"):
-                i = row["query_id"]
-                if not is_addition or obs.query_ids[i] is not None:
-                    obs.query_repeats += 1
-                    return
-                obs.query_ids[i] = tuple(row["_pw_index_reply_ids"])
-                obs.query_scores[i] = tuple(row["_pw_index_reply_scores"])
-                obs.query_emb[i] = np.asarray(row["qemb"], np.float32)
-                obs.query_commit[i] = time_
-                obs.query_ack[i] = time.perf_counter()
-                obs.queries_acked += 1
-                if i == len(obs.query_ids) - 1:
-                    clock.primed["query_id"].set()
-
-        pw.io.subscribe(
-            answers,
-            on_change=lambda key, row, time, is_addition: on_answer(key, row, time, is_addition),
-        )
-    else:
-        # no query feed: build the index operator all the same, over no queries
-        none = pw.debug.table_from_rows(pw.schema_from_types(query_id=int, text=str), [])
-        none = none.select(query_id=pw.this.query_id, qemb=embedder(pw.this.text))
-        answers = data_index.query_as_of_now(none, none.qemb, number_of_matches=k)
-        pw.io.subscribe(answers, on_change=lambda key, row, time, is_addition: None)
+    clock = Clock(schedule, seconds, obs, compiles, span)
+    feeds = {
+        name: _make_feed(pw, stream, getattr(obs, name), clock) if stream is not None else None
+        for name, stream in (("documents", schedule.documents), ("queries", schedule.queries))
+    }
+    cell.pipeline.build(pw, cell, state, feeds, clock)
     pw.io.subscribe(
         pw.global_error_log(),
         on_change=lambda key, row, time, is_addition: obs.errors.append(str(row["message"])),
@@ -495,7 +340,7 @@ def run_window(cell: Cell, embedder, index, schedule: traffic.Schedule, seconds:
             clock.opened.wait()
             time.sleep(max(0.0, obs.t0 + start_after - time.perf_counter()))
             options = jax.profiler.ProfileOptions()
-            options.python_tracer_level = 0  # the benchmark's spans are enough
+            options.python_tracer_level = 0  # the program's stages and the benchmark's spans are enough
             jax.profiler.start_trace(trace_dir.name, profiler_options=options)
             traced["start"] = time.perf_counter()
             time.sleep(length)
@@ -512,13 +357,11 @@ def run_window(cell: Cell, embedder, index, schedule: traffic.Schedule, seconds:
         run_error = repr(exc)
     finally:
         clock.drained.set()
-        embedder._fn = inner_fn
-        for attr, inner in wrapped_jits.items():
-            setattr(embedder, attr, inner)
-    G.clear()  # the graph holds the index; the reference needs its room
+        cell.pipeline.restore(state)
+    G.clear()  # the graph holds the program's state; the reference needs its room
     if run_error:
         obs.errors.append(f"pw.run raised: {run_error}")
-    counters["doc_commits"] = len(doc_commits)
+    obs.counters["doc_commits"] = len(obs.documents.commits)
     trace_info = None
     if tracer is not None:
         tracer.join(timeout=120.0)
@@ -545,41 +388,23 @@ def end_to_end_metrics(cell: Cell, schedule, obs: Observed, seconds: float, setu
     """Every end-to-end metric this cell reports, over the whole window and
     all its events; an event never acknowledged waited the whole grace."""
     out = {"setup_s": setup_s}
-    never = obs.t_end + GRACE_S
-    doc_ack = obs.doc_ack[:-1]
+    doc_ack = obs.documents.ack[:-1]
     in_window = (doc_ack >= obs.t0) & (doc_ack <= obs.t_end)
     out["docs_per_s"] = float(np.count_nonzero(in_window)) / seconds
     if schedule.documents.loop == "open":
-        due = obs.t0 + schedule.documents.due_s
-        ack = np.where(np.isnan(doc_ack), never, doc_ack)
-        out["index_lag_p95_ms"] = _percentile((ack - due) * 1e3, 95)
+        out["index_lag_p95_ms"] = _percentile(obs.waits_ms("documents", schedule.documents), 95)
     if schedule.queries is not None:
-        due = obs.t0 + schedule.queries.due_s
-        ack = np.where(np.isnan(obs.query_ack[:-1]), never, obs.query_ack[:-1])
-        wait_ms = (ack - due) * 1e3
+        wait_ms = obs.waits_ms("queries", schedule.queries)
         out["query_p50_ms"] = _percentile(wait_ms, 50)
+        # not end to end since PR 27 (per layer: query_wait_p95_ms.query); here for the window: line
         out["query_p95_ms"] = _percentile(wait_ms, 95)
         # does the backlog grow through the window?
-        half = due < obs.t0 + seconds / 2
+        half = schedule.queries.due_s < seconds / 2
         out["query_p50_ms_first_half"] = _percentile(wait_ms[half], 50)
         out["query_p50_ms_second_half"] = _percentile(wait_ms[~half], 50)
     print("window: " + json.dumps(out), file=sys.stderr)
     wanted = {m["name"] for m in cell.end_to_end}
     return {name: value for name, value in out.items() if name in wanted}
-
-
-def embedded_flops(cell: Cell, schedule, obs: Observed) -> float:
-    """Model FLOPs of the real tokens of every text embedded inside the
-    window (documents at the sink, queries answered)."""
-    enc = cell.config["encoder"]
-    total = 0.0
-    for stream, ack in ((schedule.documents, obs.doc_ack), (schedule.queries, obs.query_ack)):
-        if stream is None:
-            continue
-        done = (ack[:-1] >= obs.t0) & (ack[:-1] <= obs.t_end)
-        counts = np.bincount(stream.tokens[done])
-        total += sum(n * costs.encoder_flops(t, enc) for t, n in enumerate(counts) if n)
-    return total
 
 
 def per_layer_metrics(cell: Cell, ctx: "readers.Context") -> dict:
@@ -603,8 +428,9 @@ def per_layer_metrics(cell: Cell, ctx: "readers.Context") -> dict:
 
 def measure(cell: Cell, seed: int, seconds: float, trace: bool, devices, t_start: float):
     """Set up and run the window. Returns the result line without its
-    verdict, and the evidence the comparison needs: the schedule, what was
-    observed, what the index held, the weights."""
+    verdict, and the evidence the pipeline's comparison needs: the schedule,
+    what was observed, and the facts the pipeline read off its state."""
+    pipeline = cell.pipeline
     compiles = check.CompileCounter()
     mesh = None
     if cell.chips > 1:
@@ -621,30 +447,18 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, devices, t_start
     def phase(name: str) -> None:
         phases.append((name, time.time() - t_start - sum(s for _, s in phases)))
 
-    params = reference.make_params(seed, cell.config["encoder"])
-    embedder = make_embedder(cell.config, params)
+    state = pipeline.weights(cell, seed)
     phase("weights")
     schedule = traffic.build(cell.mix, seed, seconds)
     phase("traffic")
-    # the prefilled rows take their distribution from the reference's own
-    # embeddings of the run's first documents, so that they compete with the
-    # window's documents for a place in an answer
-    moments = reference.prefill_moments(
-        params, schedule.documents.texts[: reference.PREFILL_SAMPLE],
-        cell.config["encoder"], cell.config["embedder"]["max_len"],
-    )
-    index = prefilled_index(cell.config, seed, moments, mesh)
-    phase("prefill")
-    warm_up(embedder, index, cell)
-    phase("warm_up")
+    pipeline.set_up(cell, seed, schedule, state, mesh, phase)
     print(
         "set-up: " + ", ".join(f"{n} {s:.2f} s" for n, s in phases)
         + f"; {compiles.requests()} compile requests, {compiles.hits()} found in the cache",
         file=sys.stderr,
     )
-    prefilled = len(index)
 
-    obs, trace_info = run_window(cell, embedder, index, schedule, seconds, trace, compiles)
+    obs, trace_info = run_window(cell, state, schedule, seconds, trace, compiles)
 
     for message in obs.errors[:5]:
         print("error log: " + message[:600], file=sys.stderr)
@@ -659,12 +473,13 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, devices, t_start
         "count": len(devices),
         "memory_peak_bytes": int(peak_bytes),
     }
-    result = {"correct": False, "attempted": obs.sent_docs() + obs.sent_queries() - obs.primers}
-    result["failed"] = result["attempted"] + obs.primers - obs.docs_acked - obs.queries_acked
+    acked = sum(s.acked for s in obs.streams())
+    result = {"correct": False, "attempted": sum(s.n_sent() for s in obs.streams()) - obs.primers}
+    result["failed"] = result["attempted"] + obs.primers - acked
     if trace:
         ctx = readers.Context(
             cell=cell, obs=obs, schedule=schedule, seconds=seconds, chips=cell.chips,
-            peak=peaks.get(kind), trace=trace_info, flops=embedded_flops(cell, schedule, obs),
+            peak=peaks.get(kind), trace=trace_info, flops=pipeline.work_flops(cell, schedule, obs),
         )
         result["metrics"] = per_layer_metrics(cell, ctx)
         if trace_info is not None:
@@ -682,12 +497,11 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, devices, t_start
     result["device"] = device
     # the comparison reads what it needs of the program's state here; the
     # state itself goes before the reference runs
-    facts = check.index_facts(index, obs, prefilled, seed, schedule)
+    facts = pipeline.facts(cell, state, obs, seed, schedule)
     facts["compiles_in_window"] = in_window
-    del index, embedder
+    state.clear()
     gc.collect()
-    facts["prefill_moments"] = moments
-    return result, {"schedule": schedule, "obs": obs, "facts": facts, "params": params}
+    return result, {"schedule": schedule, "obs": obs, "facts": facts}
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices, t_start: float) -> dict:
@@ -695,7 +509,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices, t_star
     numbers compared last."""
     result, evidence = measure(cell, seed, seconds, trace, devices, t_start)
     began = time.time()
-    numbers = check.compare(cell, seed, **evidence)
+    numbers = cell.pipeline.compare(cell, seed, **evidence)
     print(f"comparison with the reference: {time.time() - began:.2f} s", file=sys.stderr)
     result["correct"] = all(n["ok"] for n in numbers)
     result["compared"] = {n["name"]: [n["value"], n["limit"]] for n in numbers}

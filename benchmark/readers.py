@@ -26,7 +26,7 @@ class Context:
     chips: int
     peak: dict | None  # this device kind's row of peaks.json
     trace: dict | None  # events, start, stop, window_s of the traced part
-    flops: float  # model FLOPs of the real tokens embedded in the window
+    flops: float  # model FLOPs of the work finished in the window (the pipeline's work_flops)
 
 
 def _bucket(n: int, minimum: int = 8) -> int:
@@ -47,7 +47,7 @@ def counter_ratio(ctx: Context, numerator: list[str], denominator: list[str]):
 def docs_per_commit(ctx: Context):
     """Documents at the sink over the distinct commit times it saw."""
     commits = ctx.obs.counters.get("doc_commits", 0)
-    return ctx.obs.docs_acked / commits if commits else None
+    return ctx.obs.documents.acked / commits if commits else None
 
 
 def generator_late_p95(ctx: Context, stream: str):
@@ -55,10 +55,19 @@ def generator_late_p95(ctx: Context, stream: str):
     plan = getattr(ctx.schedule, stream)
     if plan is None or plan.due_s is None:
         return None
-    sent = ctx.obs.doc_sent if stream == "documents" else ctx.obs.query_sent
-    sent = sent[:-1]  # without the primer
+    sent = getattr(ctx.obs, stream).sent[:-1]  # without the primer
     late = (sent - ctx.obs.t0 - plan.due_s)[~np.isnan(sent)]
     return float(np.percentile(late, 95) * 1e3) if len(late) else None
+
+
+def wait_percentile(ctx: Context, stream: str, q: float):
+    """A percentile of (the sink's callback - due time) over all the events
+    of an open-loop feed, ms: the end-to-end latencies' arithmetic
+    (``Observed.waits_ms``), for a tail too unsteady to carry a bound."""
+    plan = getattr(ctx.schedule, stream)
+    if plan is None or plan.due_s is None or not len(plan.due_s):
+        return None
+    return float(np.percentile(ctx.obs.waits_ms(stream, plan), q))
 
 
 def step_mfu(ctx: Context):
@@ -123,6 +132,14 @@ def device_idle_share(ctx: Context):
     return 100.0 * (1.0 - max(busy.values()) / ctx.trace["window_s"])
 
 
+def load_module(name: str, path: str):
+    """The file at ``path`` as a module of its own, outside ``sys.modules``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 def find(name: str, directory: str):
     """The reader a metric's file names: one of this module's, or — so that a
     later PR adds a reader without editing this file — the function ``read``
@@ -132,16 +149,14 @@ def find(name: str, directory: str):
     path = os.path.join(directory, name + ".py")
     if not os.path.exists(path):
         raise KeyError(f"no reader {name!r}: neither in readers.py nor at {path}")
-    spec = importlib.util.spec_from_file_location("layer_metric_reader_" + name, path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return load_module("layer_metric_reader_" + name, path).read
 
 
 READERS = {
     "counter_ratio": counter_ratio,
     "docs_per_commit": docs_per_commit,
     "generator_late_p95": generator_late_p95,
+    "wait_percentile": wait_percentile,
     "step_mfu": step_mfu,
     "encoder_program_roofline": encoder_program_roofline,
     "knn_search_roofline": knn_search_roofline,

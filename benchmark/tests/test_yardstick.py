@@ -8,6 +8,7 @@ import os
 import re
 
 import numpy as np
+import pytest
 
 import costs
 import readers
@@ -82,6 +83,48 @@ def test_trace_reduction_on_a_recorded_trace():
     assert summary["busy_s"] == busy and summary["window_s"] == span
 
 
+E = trace_mod.Event
+RUN, FEED = ("/host:CPU", "python3#4"), ("/host:CPU", "python3#9")
+#: three operations on the chip leave two gaps, 15..55 and 65..130
+OPS = [E("/device:TPU:0", "XLA Ops", f"%fusion.{n} = f32[8]", start, dur) for n, (start, dur) in enumerate([(0, 15), (55, 10), (130, 5)])]
+NESTED = [E(*RUN, "pw:commit", 0, 100), E(*RUN, "pw:udf.batch", 10, 50), E(*RUN, "pw:embed.tokenize", 20, 30),
+          E(*RUN, "pw:commit", 120, 30)]
+
+
+@pytest.mark.parametrize("host, want", [
+    # the innermost stage open on the run thread takes each idle instant; 100..120 has none
+    (NESTED, {"pw:embed.tokenize": 30, "pw:udf.batch": 10, "pw:commit": 45, trace_mod.OTHER: 20}),
+    # a stage that covers everything on another thread (the device pipeline's, a feed's) takes nothing
+    (NESTED + [E(*FEED, "pw:pipeline.complete", 0, 200), E(*FEED, "pw:commit", 0, 1)],
+     {"pw:embed.tokenize": 30, "pw:udf.batch": 10, "pw:commit": 45, trace_mod.OTHER: 20}),
+    # nor does a stage that covers most of a gap on the run thread take the whole of it
+    ([E(*RUN, "pw:commit", 0, 200), E(*RUN, "pw:pump.sleep", 70, 50)], {"pw:commit": 55, "pw:pump.sleep": 50}),
+    # under no stage, the benchmark's span open then (on any thread), and under neither, engine: other
+    (NESTED + [E(*FEED, "bench:generator_send", 95, 15), E(*RUN, "bench:embed_call", 10, 50)],
+     {"pw:embed.tokenize": 30, "pw:udf.batch": 10, "pw:commit": 45, "generator_send": 10, trace_mod.OTHER: 10}),
+    # a trace of a program without stages: the benchmark's spans alone, innermost first
+    ([E(*RUN, "bench:embed_call", 10, 50), E(*RUN, "bench:sink_documents", 60, 60), E(*FEED, "bench:generator_send", 100, 5)],
+     {"embed_call": 40, "sink_documents": 50, "generator_send": 5, trace_mod.OTHER: 10}),
+    ([], {trace_mod.OTHER: 105}),
+])
+def test_idle_time_goes_to_the_innermost_stage_open_on_the_run_thread(host, want):
+    gaps = dict(trace_mod.idle_gaps(OPS + host))
+    assert gaps == pytest.approx({name: ns / 1e9 for name, ns in want.items()})
+    assert sum(gaps.values()) == pytest.approx(105e-9)  # every idle instant, once
+    split = trace_mod.idle_split(OPS + host)
+    assert split["thread"] == (RUN if any(e.name == "pw:commit" for e in host) else None)
+    assert trace_mod.idle_gaps(OPS + host, limit=1) == [list(max(gaps.items(), key=lambda kv: kv[1]))]
+
+
+def test_innermost_segments_of_nested_and_of_lapping_spans():
+    assert trace_mod.innermost_segments((e.start_ns, e.start_ns + e.dur_ns, e.name) for e in NESTED) == [
+        (0, 10, "pw:commit"), (10, 20, "pw:udf.batch"), (20, 50, "pw:embed.tokenize"), (50, 60, "pw:udf.batch"),
+        (60, 100, "pw:commit"), (120, 150, "pw:commit"),
+    ]
+    # two threads' spans lap without nesting: no instant is counted twice
+    assert trace_mod.innermost_segments([(0, 10, "a"), (5, 15, "b")]) == [(0, 5, "a"), (5, 15, "b")]
+
+
 def test_op_family_drops_hlo_text_and_serial():
     assert trace_mod.op_family("%convert_reduce_fusion.9 = (f32[256,128]{1,0}) fusion(...)") == "convert_reduce_fusion"
     assert trace_mod.op_family("%copy-start = (f32[2,3]) copy-start(...)") == "copy-start"
@@ -137,3 +180,25 @@ def test_a_reader_is_found_in_readers_py_or_in_a_file_of_its_own(tmp_path):
         assert "nowhere" in str(exc)
     else:
         raise AssertionError("a reader that is nowhere was found")
+
+
+def test_a_tail_read_per_layer_is_the_end_to_end_arithmetic():
+    """``wait_percentile`` reads what ``end_to_end_metrics`` reads: due time to
+    the sink's callback over all events, one never acknowledged at the grace."""
+    import harness
+
+    mix = {"vocabulary_words": 20, "documents": {"loop": "closed", "in_flight": 4, "pool_per_s": 10, "tokens": {"dist": "uniform", "min": 4, "max": 8}},
+           "queries": {"loop": "open", "arrivals": "poisson", "rate_per_s": 10, "tokens": {"dist": "uniform", "min": 4, "max": 8}}}
+    schedule = traffic.build(mix, 2**31 + 11, 2.0)
+    obs = harness.Observed(schedule)
+    obs.t0, obs.t_end = 100.0, 102.0
+    obs.queries.ack[:-1] = obs.t0 + schedule.queries.due_s + np.linspace(0.010, 0.200, 20)
+    obs.queries.ack[7] = np.nan  # never acknowledged: it waited the whole grace
+    ctx = readers.Context(cell=None, obs=obs, schedule=schedule, seconds=2.0, chips=1, peak=None, trace=None, flops=0.0)
+    waits = obs.waits_ms("queries", schedule.queries)
+    assert waits.max() == pytest.approx((102.0 + harness.GRACE_S - 100.0 - schedule.queries.due_s[7]) * 1e3)
+    cell = harness.Cell("t", 1, {}, mix, {}, [{"name": "query_p50_ms"}], [], None)
+    e2e = harness.end_to_end_metrics(cell, schedule, obs, 2.0, 1.0)
+    assert readers.wait_percentile(ctx, "queries", 50) == pytest.approx(e2e["query_p50_ms"])
+    assert readers.wait_percentile(ctx, "queries", 95) == pytest.approx(float(np.percentile(waits, 95)))
+    assert readers.wait_percentile(ctx, "documents", 95) is None  # a closed loop has no due times

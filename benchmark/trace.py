@@ -12,8 +12,10 @@ import glob
 import os
 from typing import Iterable, NamedTuple
 
-#: the benchmark's own host spans carry this prefix (harness.annotate)
+#: the benchmark's own host spans carry this prefix (harness.run_window's ``span``)
 SPAN_PREFIX = "bench:"
+#: the program's stages (``pathway_tpu.internals.tracing``) carry this one
+STAGE_PREFIX = "pw:"
 OTHER = "engine: other"
 
 
@@ -26,8 +28,10 @@ class Event(NamedTuple):
 
 
 def load_events(trace_dir: str) -> list[Event]:
-    """Device events and the benchmark's host spans of the newest trace
-    under ``trace_dir``."""
+    """Device events, the program's stages and the benchmark's host spans of
+    the newest trace under ``trace_dir``. A host thread is a line, and every
+    Python thread's line has the same name: a host event's ``line`` is
+    ``<name>#<place among the plane's lines>``, which tells them apart."""
     from jax.profiler import ProfileData
 
     paths = sorted(glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
@@ -37,12 +41,11 @@ def load_events(trace_dir: str) -> list[Event]:
     events = []
     for plane in data.planes:
         device = plane.name.startswith("/device:")
-        for line in plane.lines:
+        for i, line in enumerate(plane.lines):
+            name = line.name if device else f"{line.name}#{i}"
             for ev in line.events:
-                if device or ev.name.startswith(SPAN_PREFIX):
-                    events.append(
-                        Event(plane.name, line.name, ev.name, ev.start_ns, ev.duration_ns)
-                    )
+                if device or ev.name.startswith((STAGE_PREFIX, SPAN_PREFIX)):
+                    events.append(Event(plane.name, name, ev.name, ev.start_ns, ev.duration_ns))
     return events
 
 
@@ -115,38 +118,110 @@ def top_device_ops(events: list[Event], limit: int = 10) -> list[list]:
     return [[name, seconds] for name, seconds in ranked]
 
 
-def idle_gaps(events: list[Event], limit: int = 10) -> list[list]:
-    """Idle seconds of the busiest chip by what the host was doing: each gap
-    between device operations is given to the benchmark's host span that
-    covers most of it, or to ``engine: other``."""
+def run_thread(events: Iterable[Event]) -> tuple[str, str] | None:
+    """The (plane, line) of the thread that commits: the one that holds most
+    ``pw:commit`` stages."""
+    counts: dict[tuple[str, str], int] = {}
+    for e in events:
+        if e.name == STAGE_PREFIX + "commit":
+            counts[(e.plane, e.line)] = counts.get((e.plane, e.line), 0) + 1
+    return max(counts, key=counts.get) if counts else None
+
+
+def innermost_segments(spans: Iterable[tuple[float, float, str]]) -> list[tuple[float, float, str]]:
+    """``(start, end, name)`` spans, nested as one thread's are (or lapping,
+    as two threads' may), as segments that do not overlap, each under the
+    name of the span opened last in it, in time order."""
+    out: list[tuple[float, float, str]] = []
+    stack: list[tuple[float, str]] = []  # (end, name) of the open spans
+    cursor = 0.0
+
+    def emit(until: float) -> None:
+        nonlocal cursor
+        if stack and until > cursor:
+            out.append((cursor, until, stack[-1][1]))
+        cursor = max(cursor, until)
+
+    for start, neg_end, name in sorted((start, -end, name) for start, end, name in spans):
+        while stack and stack[-1][0] <= start:
+            emit(stack[-1][0])
+            stack.pop()
+        emit(start)
+        cursor = max(cursor, start)
+        stack.append((-neg_end, name))
+    while stack:
+        emit(stack[-1][0])
+        stack.pop()
+    return out
+
+
+def _lay(intervals: list[tuple[float, float]], segments: list[tuple[float, float, str]], totals: dict):
+    """Give every instant of ``intervals`` (sorted, apart) to the segment
+    that holds it, adding nanoseconds to ``totals`` by segment name; returns
+    the parts no segment holds."""
+    bare: list[tuple[float, float]] = []
+    first = 0
+    for lo, hi in intervals:
+        while first < len(segments) and segments[first][1] <= lo:
+            first += 1
+        cursor, i = lo, first
+        while i < len(segments) and segments[i][0] < hi:
+            start, end, name = segments[i]
+            if start > cursor:
+                bare.append((cursor, start))
+            lap = min(hi, end) - max(cursor, start)
+            if lap > 0:
+                totals[name] = totals.get(name, 0.0) + lap
+            cursor = max(cursor, min(hi, end))
+            i += 1
+        if hi > cursor:
+            bare.append((cursor, hi))
+    return bare
+
+
+def idle_split(events: list[Event]) -> dict:
+    """The idle nanoseconds of the busiest chip — the gaps between its
+    operations — by what the host was doing. Each idle instant goes to the
+    innermost ``pw:`` stage open then on the thread that commits (never to
+    whatever covers most of a gap: ``pw:run`` covers everything); an instant
+    under no stage to the benchmark's ``bench:`` span open then, on any
+    thread; what is left to ``engine: other``. ``by_name`` holds stages as
+    ``pw:<stage>`` and spans bare."""
     busy = busy_seconds(events)
     if not busy:
-        return []
+        return {}
     plane = max(busy, key=busy.get)
-    spans = _union([(e.start_ns, e.start_ns + e.dur_ns) for e in _ops(events, plane)])
-    host = sorted(
+    ops = _union([(e.start_ns, e.start_ns + e.dur_ns) for e in _ops(events, plane)])
+    gaps = [(a_end, b_start) for (_, a_end), (b_start, _) in zip(ops, ops[1:])]
+    thread = run_thread(events)
+    stages = innermost_segments(
+        (e.start_ns, e.start_ns + e.dur_ns, e.name)
+        for e in events
+        if e.name.startswith(STAGE_PREFIX) and (e.plane, e.line) == thread
+    )
+    spans = innermost_segments(
         (e.start_ns, e.start_ns + e.dur_ns, e.name[len(SPAN_PREFIX):])
         for e in events
         if e.name.startswith(SPAN_PREFIX)
     )
-    totals: dict[str, float] = {}
-    first = 0
-    for (_, gap_start), (gap_end, _) in zip(spans, spans[1:]):
-        while first < len(host) and host[first][1] <= gap_start:
-            first += 1
-        cover: dict[str, float] = {}
-        i = first
-        while i < len(host) and host[i][0] < gap_end:
-            lap = min(gap_end, host[i][1]) - max(gap_start, host[i][0])
-            if lap > 0:
-                cover[host[i][2]] = cover.get(host[i][2], 0.0) + lap
-            i += 1
-        name = max(cover, key=cover.get) if cover else OTHER
-        if cover and cover[name] < 0.5 * (gap_end - gap_start):
-            name = OTHER
-        totals[name] = totals.get(name, 0.0) + (gap_end - gap_start) / 1e9
+    by_name: dict[str, float] = {}
+    no_stage = _lay(gaps, stages, by_name)
+    no_span = _lay(no_stage, spans, by_name)
+    if no_span:
+        by_name[OTHER] = sum(hi - lo for lo, hi in no_span)
+    return {
+        "plane": plane, "busy_s": busy[plane], "thread": thread, "ops": ops, "stages": stages, "by_name": by_name,
+        "idle_ns": sum(hi - lo for lo, hi in gaps),
+        "no_stage_ns": sum(hi - lo for lo, hi in no_stage),
+    }
+
+
+def idle_gaps(events: list[Event], limit: int = 10) -> list[list]:
+    """Idle seconds of the busiest chip by what the host was doing
+    (:func:`idle_split`), the ``limit`` largest."""
+    totals = idle_split(events).get("by_name", {})
     ranked = sorted(totals.items(), key=lambda kv: -kv[1])[:limit]
-    return [[name, seconds] for name, seconds in ranked]
+    return [[name, ns / 1e9] for name, ns in ranked]
 
 
 def summarize(events: list[Event], window_s: float, chips: int) -> dict:
