@@ -632,6 +632,7 @@ class GraphRunner:
                 source_node,
                 key_col,
                 optional=spec.params.get("optional", False),
+                strict=not spec.params.get("allow_misses", False),
             )
 
         if kind == "deduplicate":

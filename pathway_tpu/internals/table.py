@@ -592,8 +592,17 @@ class Table:
     # -- pointer lookup -----------------------------------------------------
 
     def ix(
-        self, expression: Any, *, optional: bool = False, context: Any = None
+        self,
+        expression: Any,
+        *,
+        optional: bool = False,
+        context: Any = None,
+        allow_misses: bool = False,
     ) -> "Table":
+        """``optional`` lets a key be ``None``; ``allow_misses`` lets it
+        name a row this table does not hold (reference Table.ix): either
+        gives a row of ``None``. A key that is missed and not allowed to be
+        is an error of its row."""
         expression = wrap_expression(expression)
         if context is not None:
             keys_table = context
@@ -607,7 +616,11 @@ class Table:
             keys_table = deps[0].table
         keys = keys_table.select(_pw_ix_key=expression)
         return self._derived(
-            TableSpec("ix", [keys, self], {"optional": optional}),
+            TableSpec(
+                "ix",
+                [keys, self],
+                {"optional": optional, "allow_misses": allow_misses},
+            ),
             {n: self._dtypes[n] for n in self._column_names},
             universe=keys_table._universe,
         )
@@ -657,7 +670,10 @@ class Table:
         )
         pointer = PointerExpression(resolved, instance=inst)
         return self.ix(
-            pointer, optional=optional or allow_misses, context=keys_table
+            pointer,
+            optional=optional or allow_misses,
+            allow_misses=allow_misses,
+            context=keys_table,
         )
 
     # -- misc ops -----------------------------------------------------------

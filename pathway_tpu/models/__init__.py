@@ -2,7 +2,8 @@
 
 - transformer.py — BERT-family encoder (MiniLM/BGE configs): embeddings,
   cross-encoder reranking head.
-- decoder.py — causal LM (Mistral-style RoPE/GQA/SwiGLU) for local chat.
+- decoder.py — causal LM over a layer pattern (grouped-query or latent
+  attention; dense or routed-and-shared-expert feed-forward) for local chat.
 - train.py — contrastive (InfoNCE) train step over the mesh (dp/tp/sp).
 
 All models are param-pytree + functional-forward with PartitionSpec rules for

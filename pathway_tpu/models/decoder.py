@@ -1,11 +1,29 @@
-"""Causal decoder LM in pure JAX: RoPE + RMSNorm + SwiGLU + GQA.
+"""Causal decoder LM in pure JAX, one module for a pattern of layers.
 
 The chat path of the LLM xpack. The reference's local chat wraps a HF
 ``pipeline`` on CPU/GPU torch (reference: python/pathway/xpacks/llm/llms.py:441
-HFPipelineChat); here decode is native JAX on TPU: Mistral-style architecture,
-static-shape KV cache for generation, and tensor-parallel weight specs over
-the ``model`` mesh axis. (Attention is dense; wiring prefill to the ring
-kernel in parallel/ring_attention.py is future work.)
+HFPipelineChat); here decode is native JAX on TPU. A ``DecoderConfig`` states
+the attention every layer uses and, layer by layer, the feed-forward kind
+(``layer_pattern``):
+
+- attention ``"gqa"``: grouped-query heads over RoPE (the Mistral-style
+  layer); the layer's cache holds keys and values, ``[b, max_len, kv_heads,
+  head_dim]`` each.
+- attention ``"mla"``: latent attention. Keys and values are expanded from
+  one compressed row a token, and **the layer's cache holds that row and the
+  rotated shared key only** (``kv_lora_rank + qk_rope_head_dim`` values a
+  token). A chunk of several tokens attends in the expanded form; a single
+  decode token attends in the absorbed form, which reads the latent cache
+  once and never expands it. RoPE may carry YaRN scaling.
+- feed-forward ``"dense"``: a gated SiLU MLP; ``"experts"``: a float32
+  router over all routed experts, the ``experts_per_token`` largest, a
+  grouped product over the experts that were chosen (``ops/moe.py``; no
+  capacity, no token dropped) plus the shared experts as one gated MLP.
+
+Each layer is handed its own cache state; ``init_cache`` makes the list.
+``prefill`` and ``decode_step`` compute the head at one position a row (the
+last), ``decoder_forward`` at every position. Tensor-parallel weight specs
+go over the ``model`` mesh axis, the experts' over ``expert``.
 """
 
 from __future__ import annotations
@@ -16,12 +34,35 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from pathway_tpu.parallel.mesh import MODEL_AXIS
+from pathway_tpu.ops.moe import expert_sizes, route_top_k, routed_experts
+from pathway_tpu.parallel.mesh import EXPERT_AXIS, MODEL_AXIS
 
 Params = dict
+
+#: prefill walks a batch in groups of rows of about this many tokens, so that
+#: attention scores and the experts' sorted rows of one group, not of the
+#: batch, are what the device holds at once
+PREFILL_BLOCK_TOKENS = 4096
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN's RoPE scaling, by the published keys of ``rope_scaling``."""
+
+    factor: float
+    original_max_len: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -36,10 +77,104 @@ class DecoderConfig:
     rope_theta: float = 10000.0
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    attention: str = "gqa"  # "gqa" | "mla"
+    # -- latent attention
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: YarnScaling | None = None
+    # -- routed and shared experts (none: every layer is dense)
+    n_routed_experts: int = 0
+    experts_per_token: int = 0
+    n_shared_experts: int = 0
+    moe_intermediate: int = 0
+    first_dense_layers: int = 0
+    norm_topk_prob: bool = False
+    routed_scaling_factor: float = 1.0
 
     @property
     def head_dim(self) -> int:
         return self.hidden // self.heads
+
+    @property
+    def layer_pattern(self) -> tuple[str, ...]:
+        """The feed-forward kind of each layer, ``"dense"`` or ``"experts"``."""
+        if not self.n_routed_experts:
+            return ("dense",) * self.layers
+        dense = min(self.first_dense_layers, self.layers)
+        return ("dense",) * dense + ("experts",) * (self.layers - dense)
+
+    @property
+    def cache_width(self) -> int:
+        """Values a token a layer keeps in an ``"mla"`` layer's cache."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        if self.attention == "gqa":
+            return 1.0 / math.sqrt(self.head_dim)
+        scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        yarn = self.rope_scaling
+        if yarn is not None and yarn.mscale_all_dim:
+            scale *= yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+        return scale
+
+    @classmethod
+    def from_hf(cls, hf: dict, **overrides: Any) -> "DecoderConfig":
+        """From the keys of a published ``config.json`` (``model_type``
+        ``deepseek_v2``: latent attention without a query projection rank,
+        greedy softmax routing in one group; ``mistral`` / ``llama``)."""
+        kind = hf.get("model_type", "mistral")
+        common = dict(
+            vocab_size=hf["vocab_size"],
+            hidden=hf["hidden_size"],
+            layers=hf["num_hidden_layers"],
+            heads=hf["num_attention_heads"],
+            kv_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
+            intermediate=hf["intermediate_size"],
+            max_len=hf.get("max_position_embeddings", 8192),
+            rope_theta=float(hf.get("rope_theta", 10000.0)),
+            rms_eps=hf.get("rms_norm_eps", 1e-5),
+        )
+        if kind == "deepseek_v2":
+            required = {
+                "q_lora_rank": None, "hidden_act": "silu", "scoring_func": "softmax",
+                "topk_method": "greedy", "n_group": 1, "moe_layer_freq": 1,
+                "attention_bias": False, "tie_word_embeddings": False,
+            }
+            for key, only in required.items():
+                if hf.get(key, only) != only:
+                    raise ValueError(f"deepseek_v2 with {key}={hf[key]!r}: only {only!r} is implemented")
+            scaling = hf.get("rope_scaling")
+            if scaling is not None and scaling.get("type") != "yarn":
+                raise ValueError(f"rope_scaling of type {scaling.get('type')!r}: only 'yarn' is implemented")
+            common.update(
+                attention="mla",
+                kv_lora_rank=hf["kv_lora_rank"],
+                qk_nope_head_dim=hf["qk_nope_head_dim"],
+                qk_rope_head_dim=hf["qk_rope_head_dim"],
+                v_head_dim=hf["v_head_dim"],
+                rope_scaling=None if scaling is None else YarnScaling(
+                    factor=scaling["factor"],
+                    original_max_len=scaling["original_max_position_embeddings"],
+                    beta_fast=scaling.get("beta_fast", 32),
+                    beta_slow=scaling.get("beta_slow", 1),
+                    mscale=scaling.get("mscale", 1.0),
+                    mscale_all_dim=scaling.get("mscale_all_dim", 0.0),
+                ),
+                n_routed_experts=hf.get("n_routed_experts") or 0,
+                experts_per_token=hf.get("num_experts_per_tok") or 0,
+                n_shared_experts=hf.get("n_shared_experts") or 0,
+                moe_intermediate=hf.get("moe_intermediate_size") or 0,
+                first_dense_layers=hf.get("first_k_dense_replace", 0),
+                norm_topk_prob=hf.get("norm_topk_prob", False),
+                routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            )
+        elif kind not in ("mistral", "llama"):
+            raise ValueError(f"no decoder layer for model_type {kind!r}")
+        common.update(overrides)
+        return cls(**common)
 
 
 def mistral_7b() -> DecoderConfig:
@@ -59,21 +194,81 @@ def tiny_decoder(vocab_size: int = 512) -> DecoderConfig:
     )
 
 
+def tiny_latent_moe_decoder(vocab_size: int = 512) -> DecoderConfig:
+    """The latent-attention, routed-experts layer pattern at a size for
+    tests: one dense layer, then two expert layers."""
+    return DecoderConfig(
+        vocab_size=vocab_size,
+        hidden=64,
+        layers=3,
+        heads=4,
+        kv_heads=4,
+        intermediate=160,
+        max_len=4096,
+        rms_eps=1e-6,
+        attention="mla",
+        kv_lora_rank=16,
+        qk_nope_head_dim=8,
+        qk_rope_head_dim=4,
+        v_head_dim=8,
+        rope_scaling=YarnScaling(40.0, 128, 32.0, 1.0, 0.707, 0.707),
+        n_routed_experts=8,
+        experts_per_token=2,
+        n_shared_experts=1,
+        moe_intermediate=32,
+        first_dense_layers=1,
+    )
+
+
+# -- parameters ---------------------------------------------------------------
+
+
+def _layer_shapes(cfg: DecoderConfig, kind: str) -> dict[str, tuple]:
+    """Matrix shapes of one layer, by parameter name (norms apart)."""
+    h = cfg.hidden
+    if cfg.attention == "mla":
+        shapes = {
+            "q_w": (h, cfg.heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)),
+            "kva_w": (h, cfg.cache_width),
+            "kvb_w": (cfg.kv_lora_rank, cfg.heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            "o_w": (cfg.heads * cfg.v_head_dim, h),
+        }
+    else:
+        shapes = {
+            "q_w": (h, cfg.heads * cfg.head_dim),
+            "kv_w": (h, 2 * cfg.kv_heads * cfg.head_dim),
+            "o_w": (cfg.heads * cfg.head_dim, h),
+        }
+    if kind == "dense":
+        shapes.update(gate_w=(h, 2 * cfg.intermediate), down_w=(cfg.intermediate, h))
+    else:
+        e, w = cfg.n_routed_experts, cfg.moe_intermediate
+        shared = cfg.n_shared_experts * w
+        shapes.update(
+            router_w=(h, e),
+            experts_gate_w=(e, h, 2 * w),
+            experts_down_w=(e, w, h),
+            shared_gate_w=(h, 2 * shared),
+            shared_down_w=(shared, h),
+        )
+    return shapes
+
+
 def init_decoder_params(
     rng: jax.Array, cfg: DecoderConfig, dtype: Any = jnp.float32
 ) -> Params:
     """``dtype=jnp.bfloat16`` stores weights half-size (7B fits a single
     16 GB chip); each tensor is drawn in f32 and cast immediately, so the
-    f32 peak is one tensor, not the model."""
+    f32 peak is one tensor, not the model. A matrix is scaled by the width
+    it contracts over (its last but one axis)."""
 
     def dense(key, shape):
-        scale = 1.0 / math.sqrt(shape[0])
-        return (scale * jax.random.normal(key, shape, jnp.float32)).astype(
-            dtype
-        )
+        scale = 1.0 / math.sqrt(shape[-2])
+        return (scale * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
 
-    keys = iter(jax.random.split(rng, 3 + 7 * cfg.layers))
-    hd, kvd = cfg.heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    pattern = cfg.layer_pattern
+    n_keys = 2 + sum(len(_layer_shapes(cfg, kind)) for kind in pattern)
+    keys = iter(jax.random.split(rng, n_keys))
     p: Params = {
         "tok_emb": (
             0.02
@@ -85,32 +280,32 @@ def init_decoder_params(
         "lm_head": dense(next(keys), (cfg.hidden, cfg.vocab_size)),
         "layers": [],
     }
-    for _ in range(cfg.layers):
-        p["layers"].append(
-            {
-                "q_w": dense(next(keys), (cfg.hidden, hd)),
-                "kv_w": dense(next(keys), (cfg.hidden, 2 * kvd)),
-                "o_w": dense(next(keys), (hd, cfg.hidden)),
-                "attn_norm": jnp.ones((cfg.hidden,), jnp.float32),
-                "gate_w": dense(next(keys), (cfg.hidden, 2 * cfg.intermediate)),
-                "down_w": dense(next(keys), (cfg.intermediate, cfg.hidden)),
-                "mlp_norm": jnp.ones((cfg.hidden,), jnp.float32),
-            }
-        )
+    for kind in pattern:
+        lp = {name: dense(next(keys), shape) for name, shape in _layer_shapes(cfg, kind).items()}
+        lp["attn_norm"] = jnp.ones((cfg.hidden,), jnp.float32)
+        lp["mlp_norm"] = jnp.ones((cfg.hidden,), jnp.float32)
+        if cfg.attention == "mla":
+            lp["kv_norm"] = jnp.ones((cfg.kv_lora_rank,), jnp.float32)
+        p["layers"].append(lp)
     return p
 
 
 def decoder_param_spec(path: tuple, leaf: Any) -> P:
     name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-    if name in ("q_w", "kv_w", "gate_w"):
+    if name in ("q_w", "kv_w", "kvb_w", "gate_w", "shared_gate_w"):
         return P(None, MODEL_AXIS)
-    if name in ("o_w", "down_w"):
+    if name in ("o_w", "down_w", "shared_down_w"):
         return P(MODEL_AXIS, None)
+    if name in ("experts_gate_w", "experts_down_w"):
+        return P(EXPERT_AXIS, None, None)
     if name in ("tok_emb",):
         return P(MODEL_AXIS, None)
     if name in ("lm_head",):
         return P(None, MODEL_AXIS)
     return P()
+
+
+# -- pieces -------------------------------------------------------------------
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -119,68 +314,267 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return (out * scale).astype(x.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding: x ``[b, t, h, d]``, positions ``[b, t]``."""
-    d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+def rope_frequencies(dim: int, theta: float, yarn: YarnScaling | None = None) -> np.ndarray:
+    """The ``dim / 2`` rotary frequencies. With YaRN each is a blend of the
+    plain frequency and that frequency over ``factor``, by a linear ramp
+    between the two correction dimensions (the dimensions that turn
+    ``beta_fast`` and ``beta_slow`` times over the original length)."""
+    plain = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if yarn is None:
+        return plain.astype(np.float32)
+
+    def correction_dim(rotations: float) -> float:
+        return dim * math.log(yarn.original_max_len / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
+    return (plain / yarn.factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
+
+
+def _rope_table_scale(cfg: DecoderConfig) -> float:
+    yarn = cfg.rope_scaling
+    if yarn is None:
+        return 1.0
+    return yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+
+
+def rope(
+    x: jax.Array, positions: jax.Array, theta: float,
+    yarn: YarnScaling | None = None, table_scale: float = 1.0,
+) -> jax.Array:
+    """Rotary embedding in the half-split layout (``x1 | x2``): x
+    ``[b, t, h, d]``, positions ``[b, t]``."""
+    freqs = jnp.asarray(rope_frequencies(x.shape[-1], theta, yarn))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [b, t, d/2]
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
+    cos = (jnp.cos(angles) * table_scale)[:, :, None, :]
+    sin = (jnp.sin(angles) * table_scale)[:, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
 
 
-class KVCache(NamedTuple):
-    """Static-shape per-layer cache ``[b, max_len, kv_heads, head_dim]``.
+def _gated_mlp(h: jax.Array, gate_w: jax.Array, down_w: jax.Array) -> jax.Array:
+    gate, up = jnp.split(h @ gate_w.astype(h.dtype), 2, axis=-1)
+    return (jax.nn.silu(gate) * up) @ down_w.astype(h.dtype)
+
+
+def _experts_layer(h: jax.Array, lp: Params, cfg: DecoderConfig, counted: jax.Array):
+    """Routed plus shared experts over ``h`` ``[b, t, hidden]``; also how
+    many of the ``counted`` tokens' choices each expert took and how many
+    experts had any row at all."""
+    b, t, hidden = h.shape
+    flat = h.reshape(b * t, hidden)
+    weights, experts = route_top_k(
+        flat, lp["router_w"], cfg.experts_per_token,
+        renormalize=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
+    )
+    y, sizes = routed_experts(flat, weights, experts, lp["experts_gate_w"], lp["experts_down_w"])
+    y = y + _gated_mlp(flat, lp["shared_gate_w"], lp["shared_down_w"])
+    load = expert_sizes(experts, cfg.n_routed_experts, counted.reshape(-1))
+    return y.reshape(b, t, hidden), load, jnp.count_nonzero(sizes).astype(jnp.int32)
+
+
+# -- cache --------------------------------------------------------------------
+
+
+class Cache(NamedTuple):
+    """Static-shape cache, one state a layer: ``{"k", "v"}`` ``[b, max_len,
+    kv_heads, head_dim]`` for a ``"gqa"`` layer, ``{"latent"}`` ``[b,
+    max_len, kv_lora_rank + qk_rope_head_dim]`` for an ``"mla"`` layer.
 
     ``valid`` marks usable slots: left-pad positions of shorter prompts in a
     batch stay False forever, so generated tokens never attend to pads.
     """
 
-    k: list
-    v: list
+    layers: list
     length: jax.Array  # [] int32 — filled prefix
     valid: jax.Array  # [b, max_len] bool — non-pad filled slots
 
 
-def init_cache(cfg: DecoderConfig, batch: int, max_len: int) -> KVCache:
-    shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
-    return KVCache(
-        k=[jnp.zeros(shape, cfg.dtype) for _ in range(cfg.layers)],
-        v=[jnp.zeros(shape, cfg.dtype) for _ in range(cfg.layers)],
+def init_cache(cfg: DecoderConfig, batch: int, max_len: int) -> Cache:
+    def state() -> dict:
+        if cfg.attention == "mla":
+            return {"latent": jnp.zeros((batch, max_len, cfg.cache_width), cfg.dtype)}
+        shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
+        return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+
+    return Cache(
+        layers=[state() for _ in range(cfg.layers)],
         length=jnp.zeros((), jnp.int32),
         valid=jnp.zeros((batch, max_len), bool),
     )
 
 
-def _attend(q, k, v, q_pos, k_valid, cfg: DecoderConfig):
-    """GQA attention; q ``[b,t,h,d]``, k/v ``[b,s,kvh,d]``; causal by
-    absolute position with ``k_valid`` masking unfilled cache slots."""
+def _write(buffer: jax.Array, chunk: jax.Array, start: jax.Array) -> jax.Array:
+    """``chunk`` ``[b, t, ...]`` into ``buffer`` ``[b, max_len, ...]`` at slots
+    ``[start, start + t)``."""
+    at = (jnp.zeros((), jnp.int32), start) + (jnp.zeros((), jnp.int32),) * (buffer.ndim - 2)
+    return lax.dynamic_update_slice(buffer, chunk.astype(buffer.dtype), at)
+
+
+# -- attention ----------------------------------------------------------------
+
+
+def _mask(q_slot: jax.Array, k_valid: jax.Array) -> jax.Array:
+    """``[b, t, s]``: causal by slot, and only slots that hold a real token."""
+    k_slot = jnp.arange(k_valid.shape[1])
+    return (q_slot[:, :, None] >= k_slot[None, None, :]) & k_valid[:, None, :]
+
+
+def _softmax(scores: jax.Array, mask: jax.Array, dtype: Any) -> jax.Array:
+    """Masked softmax in float32; ``scores`` ``[b, ..., t, s]`` with the heads
+    between, ``mask`` ``[b, t, s]``."""
+    mask = mask.reshape(mask.shape[:1] + (1,) * (scores.ndim - 3) + mask.shape[1:])
+    return jax.nn.softmax(jnp.where(mask, scores.astype(jnp.float32), -1e30), axis=-1).astype(dtype)
+
+
+def _gqa_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only):
+    b, t, _ = h.shape
+    q = (h @ lp["q_w"].astype(cfg.dtype)).reshape(b, t, cfg.heads, cfg.head_dim)
+    k, v = jnp.split(h @ lp["kv_w"].astype(cfg.dtype), 2, axis=-1)
+    k = k.reshape(b, t, cfg.kv_heads, cfg.head_dim)
+    v = v.reshape(b, t, cfg.kv_heads, cfg.head_dim)
+    q = rope(q, q_pos, cfg.rope_theta)
+    k = rope(k, q_pos, cfg.rope_theta)
+    if state is not None:
+        state = {"k": _write(state["k"], k, start), "v": _write(state["v"], v, start)}
+        if not chunk_only:
+            k, v = state["k"], state["v"]
     g = cfg.heads // cfg.kv_heads
-    b, t, h, d = q.shape
-    s = k.shape[1]
-    qg = q.reshape(b, t, cfg.kv_heads, g, d)
-    scores = jnp.einsum("btkgd,bskd->bkgts", qg, k).astype(jnp.float32)
-    scores = scores / math.sqrt(d)
-    k_pos = jnp.arange(s)
-    causal = q_pos[:, :, None] >= k_pos[None, None, :]  # [b, t, s]
-    mask = causal & k_valid[:, None, :]
-    scores = jnp.where(mask[:, None, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    qg = q.reshape(b, t, cfg.kv_heads, g, cfg.head_dim)
+    scores = jnp.einsum("btkgd,bskd->bkgts", qg, k) * cfg.softmax_scale
+    probs = _softmax(scores, _mask(q_slot, k_valid), v.dtype)
     out = jnp.einsum("bkgts,bskd->btkgd", probs, v)
-    return out.reshape(b, t, h * d)
+    return out.reshape(b, t, cfg.heads * cfg.head_dim), state
+
+
+def _mla_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only):
+    """Latent attention over ``h`` ``[b, t, hidden]``. The layer's state is
+    the latent cache; with ``chunk_only`` (a prompt into an empty cache) the
+    keys are the chunk's own rows."""
+    b, t, _ = h.shape
+    nope, rot, vd, rank = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    yarn, table = cfg.rope_scaling, _rope_table_scale(cfg)
+    q = (h @ lp["q_w"].astype(cfg.dtype)).reshape(b, t, cfg.heads, nope + rot)
+    q_nope, q_rope = q[..., :nope], rope(q[..., nope:], q_pos, cfg.rope_theta, yarn, table)
+    kva = h @ lp["kva_w"].astype(cfg.dtype)
+    c = rms_norm(kva[..., :rank], lp["kv_norm"], cfg.rms_eps)
+    k_rope = rope(kva[..., None, rank:], q_pos, cfg.rope_theta, yarn, table)[:, :, 0]
+    latent = jnp.concatenate([c, k_rope], axis=-1)  # [b, t, rank + rot]: what the cache keeps
+    if state is not None:
+        state = {"latent": _write(state["latent"], latent, start)}
+        if not chunk_only:
+            latent = state["latent"]
+    kvb = lp["kvb_w"].astype(cfg.dtype).reshape(rank, cfg.heads, nope + vd)
+    mask = _mask(q_slot, k_valid)
+    if t == 1 and state is not None:
+        # absorbed: the query goes into the latent space, the cache is read as it lies
+        q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, kvb[..., :nope])
+        q_cat = jnp.concatenate([q_lat, q_rope], axis=-1)  # [b, 1, heads, rank + rot]
+        scores = jnp.einsum("bthc,bsc->bhts", q_cat, latent) * cfg.softmax_scale
+        probs = _softmax(scores, mask, latent.dtype)
+        out_lat = jnp.einsum("bhts,bsr->bthr", probs, latent[..., :rank])
+        out = jnp.einsum("bthr,rhv->bthv", out_lat, kvb[..., nope:])
+    else:
+        kv = jnp.einsum("bsr,rhd->bshd", latent[..., :rank], kvb)
+        k_nope, v = kv[..., :nope], kv[..., nope:]
+        scores = jnp.einsum("bthn,bshn->bhts", q_nope, k_nope)
+        scores = scores + jnp.einsum("bthr,bsr->bhts", q_rope, latent[..., rank:])
+        probs = _softmax(scores * cfg.softmax_scale, mask, v.dtype)
+        out = jnp.einsum("bhts,bshv->bthv", probs, v)
+    return out.reshape(b, t, cfg.heads * vd), state
+
+
+# -- the layer stack ----------------------------------------------------------
+
+
+class ExpertStats(NamedTuple):
+    """What the expert layers of one forward pass took: ``load`` ``[expert
+    layers, experts]`` int32, the choices of the counted (real) tokens each
+    expert got; ``touched`` ``[]`` int32, over the expert layers the experts
+    that had any row, a padding row's included (their weights were read)."""
+
+    load: jax.Array
+    touched: jax.Array
+
+    @staticmethod
+    def none(cfg: DecoderConfig) -> "ExpertStats":
+        n = sum(kind == "experts" for kind in cfg.layer_pattern)
+        return ExpertStats(jnp.zeros((n, max(cfg.n_routed_experts, 1)), jnp.int32), jnp.zeros((), jnp.int32))
+
+    def __add__(self, other: "ExpertStats") -> "ExpertStats":  # type: ignore[override]
+        return ExpertStats(self.load + other.load, self.touched + other.touched)
+
+
+def _stack(
+    params: Params,
+    token_ids: jax.Array,  # [b, t]
+    cfg: DecoderConfig,
+    cache: Cache | None,
+    attn_mask: jax.Array | None,
+    pos_offset: jax.Array | None,
+    chunk_only: bool = False,
+) -> tuple[jax.Array, Cache | None, ExpertStats]:
+    """The layers over a chunk: hidden states ``[b, t, hidden]`` before the
+    final norm, the cache with the chunk appended, the experts' counts."""
+    b, t = token_ids.shape
+    x = params["tok_emb"][token_ids].astype(cfg.dtype)
+    start = cache.length if cache is not None else jnp.zeros((), jnp.int32)
+    # slot index (causal order) vs rotary position (logical, pad-corrected)
+    q_slot = jnp.broadcast_to(start + jnp.arange(t, dtype=jnp.int32)[None, :], (b, t))
+    if pos_offset is not None:
+        q_pos = jnp.maximum(q_slot - pos_offset[:, None].astype(jnp.int32), 0)
+    else:
+        q_pos = q_slot
+    real = attn_mask if attn_mask is not None else jnp.ones((b, t), bool)
+    if cache is None:
+        k_valid, valid_full = real, None
+    else:
+        valid_full = _write(cache.valid, real, start)
+        k_valid = real if chunk_only else valid_full
+        if chunk_only:
+            q_slot = q_slot - start  # slots within the chunk
+    states, loads, touched = [], [], jnp.zeros((), jnp.int32)
+    for i, (lp, kind) in enumerate(zip(params["layers"], cfg.layer_pattern)):
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        state = cache.layers[i] if cache is not None else None
+        if cfg.attention == "mla":
+            a, state = _mla_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only)
+        else:
+            a, state = _gqa_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only)
+        states.append(state)
+        x = x + (a @ lp["o_w"].astype(cfg.dtype))
+        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+        if kind == "experts":
+            y, load, n_touched = _experts_layer(h, lp, cfg, real)
+            loads.append(load)
+            touched = touched + n_touched
+        else:
+            y = _gated_mlp(h, lp["gate_w"], lp["down_w"])
+        x = x + y
+    stats = ExpertStats(jnp.stack(loads), touched) if loads else ExpertStats.none(cfg)
+    if cache is not None:
+        cache = Cache(layers=states, length=start + t, valid=valid_full)
+    return x, cache, stats
+
+
+def _head(params: Params, x: jax.Array, cfg: DecoderConfig) -> jax.Array:
+    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    return (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
 
 
 def decoder_forward(
     params: Params,
     token_ids: jax.Array,  # [b, t]
     cfg: DecoderConfig,
-    cache: KVCache | None = None,
+    cache: Cache | None = None,
     *,
     attn_mask: jax.Array | None = None,  # [b, t] True = real (non-pad) token
     pos_offset: jax.Array | None = None,  # [b] per-row left-pad count
-) -> tuple[jax.Array, KVCache | None]:
+) -> tuple[jax.Array, Cache | None]:
     """Logits ``[b, t, vocab]``; appends to ``cache`` when given.
 
     Without a cache this is plain causal training/scoring forward. With a
@@ -190,59 +584,63 @@ def decoder_forward(
     per row, subtracted from RoPE positions so token 0 of every prompt sits
     at rotary position 0).
     """
-    b, t = token_ids.shape
-    x = params["tok_emb"][token_ids].astype(cfg.dtype)
-    start = cache.length if cache is not None else jnp.zeros((), jnp.int32)
-    # slot index (causal order) vs rotary position (logical, pad-corrected)
-    q_slot = start + jnp.arange(t)[None, :].astype(jnp.int32)
-    q_slot = jnp.broadcast_to(q_slot, (b, t))
-    if pos_offset is not None:
-        q_pos = jnp.maximum(q_slot - pos_offset[:, None].astype(jnp.int32), 0)
-    else:
-        q_pos = q_slot
-    new_k, new_v = [], []
-    valid_full = None
-    if cache is not None:
-        chunk_valid = (
-            attn_mask if attn_mask is not None else jnp.ones((b, t), bool)
+    x, cache, _ = _stack(params, token_ids, cfg, cache, attn_mask, pos_offset)
+    return _head(params, x, cfg), cache
+
+
+def prefill(
+    params: Params,
+    prompt_ids: jax.Array,  # [b, t] left-padded
+    prompt_mask: jax.Array | None,  # [b, t] True = real token
+    cfg: DecoderConfig,
+    max_len: int,
+) -> tuple[jax.Array, Cache, jax.Array, ExpertStats]:
+    """A batch of prompts into an empty cache of ``max_len`` slots: the
+    logits of each row's last position ``[b, vocab]`` (the head runs there
+    and nowhere else), the cache, each row's pad count ``[b]`` and the
+    experts' counts. The batch is walked in groups of rows of about
+    ``PREFILL_BLOCK_TOKENS`` tokens."""
+    b, t = prompt_ids.shape
+    if prompt_mask is None:
+        prompt_mask = jnp.ones((b, t), bool)
+    pos_offset = t - prompt_mask.sum(axis=1).astype(jnp.int32)
+    rows = max(1, min(b, PREFILL_BLOCK_TOKENS // t))
+    while b % rows:
+        rows -= 1
+
+    def group(args):
+        ids, mask, offset = args
+        x, cache, stats = _stack(
+            params, ids, cfg, init_cache(cfg, rows, max_len), mask, offset, chunk_only=True
         )
-        valid_full = cache.valid.at[:, start + jnp.arange(t)].set(chunk_valid)
-    for i, lp in enumerate(params["layers"]):
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q = (h @ lp["q_w"].astype(cfg.dtype)).reshape(
-            b, t, cfg.heads, cfg.head_dim
-        )
-        kv = h @ lp["kv_w"].astype(cfg.dtype)
-        k, v = jnp.split(kv, 2, axis=-1)
-        k = k.reshape(b, t, cfg.kv_heads, cfg.head_dim)
-        v = v.reshape(b, t, cfg.kv_heads, cfg.head_dim)
-        q = rope(q, q_pos, cfg.rope_theta)
-        k = rope(k, q_pos, cfg.rope_theta)
-        if cache is not None:
-            # scatter the chunk at positions [start, start+t)
-            idx = start + jnp.arange(t)
-            k_full = cache.k[i].at[:, idx].set(k)
-            v_full = cache.v[i].at[:, idx].set(v)
-            new_k.append(k_full)
-            new_v.append(v_full)
-            a = _attend(q, k_full, v_full, q_slot, valid_full, cfg)
-        else:
-            k_valid = (
-                attn_mask
-                if attn_mask is not None
-                else jnp.ones((b, t), bool)
-            )
-            a = _attend(q, k, v, q_slot, k_valid, cfg)
-        x = x + (a @ lp["o_w"].astype(cfg.dtype))
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        gate_up = h @ lp["gate_w"].astype(cfg.dtype)
-        gate, up = jnp.split(gate_up, 2, axis=-1)
-        x = x + (jax.nn.silu(gate) * up) @ lp["down_w"].astype(cfg.dtype)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
-    if cache is not None:
-        cache = KVCache(k=new_k, v=new_v, length=start + t, valid=valid_full)
-    return logits, cache
+        return _head(params, x[:, -1], cfg), cache.layers, cache.valid, stats
+
+    split = lambda a: a.reshape((b // rows, rows) + a.shape[1:])  # noqa: E731
+    logits, layers, valid, stats = lax.map(group, (split(prompt_ids), split(prompt_mask), split(pos_offset)))
+    join = lambda a: a.reshape((b,) + a.shape[2:])  # noqa: E731
+    cache = Cache(jax.tree.map(join, layers), jnp.asarray(t, jnp.int32), join(valid))
+    return join(logits), cache, pos_offset, ExpertStats(stats.load.sum(0), stats.touched.sum(0))
+
+
+def decode_step(
+    params: Params,
+    tokens: jax.Array,  # [b] the tokens chosen last
+    cache: Cache,
+    pos_offset: jax.Array,  # [b]
+    cfg: DecoderConfig,
+    real: jax.Array | None = None,  # [b] False: a row of padding, never valid nor counted
+) -> tuple[jax.Array, Cache, ExpertStats]:
+    """One token a row through the cache: the next logits ``[b, vocab]``."""
+    mask = None if real is None else real[:, None]
+    x, cache, stats = _stack(params, tokens[:, None], cfg, cache, mask, pos_offset)
+    return _head(params, x[:, 0], cfg), cache, stats
+
+
+class Generation(NamedTuple):
+    tokens: jax.Array  # [b, new] int32
+    logits: jax.Array  # [b, new] float32: the logit of each token chosen
+    prefill_stats: ExpertStats
+    decode_stats: ExpertStats
 
 
 def _generate_loop(
@@ -253,7 +651,7 @@ def _generate_loop(
     eos_id: int | None,
     prompt_mask: jax.Array | None,
     choose,
-) -> jax.Array:
+) -> Generation:
     """Shared decode scaffold: prompt prefill, per-step cache decode,
     EOS padding. ``choose(logits [b, vocab], step_no) -> [b] int32`` picks
     each next token (argmax for greedy, filtered categorical for
@@ -264,40 +662,61 @@ def _generate_loop(
     prompt starts at rotary position 0 (ADVICE r1). Tokens after EOS are
     padded with ``eos_id``.
     """
-    b, t_prompt = prompt_ids.shape
-    max_len = t_prompt + max_new_tokens
-    cache = init_cache(cfg, b, max_len)
-    if prompt_mask is not None:
-        # left-padding: pad count = leading False run = t_prompt - true count
-        pos_offset = t_prompt - prompt_mask.sum(axis=1).astype(jnp.int32)
-    else:
-        pos_offset = jnp.zeros((b,), jnp.int32)
-    logits, cache = decoder_forward(
-        params,
-        prompt_ids,
-        cfg,
-        cache,
-        attn_mask=prompt_mask,
-        pos_offset=pos_offset,
+    t_prompt = prompt_ids.shape[1]
+    logits, cache, pos_offset, prefill_stats = prefill(
+        params, prompt_ids, prompt_mask, cfg, t_prompt + max_new_tokens
     )
-    next_tok = choose(logits[:, -1], 0)
-    done = jnp.zeros((b,), bool)
+    first = choose(logits, 0)
+    first_logit = logit_of(logits, first)
+    tokens, chosen, decode_stats = decode_loop(
+        params, cache, first, pos_offset, cfg, max_new_tokens - 1, choose, eos_id
+    )
+    return Generation(
+        jnp.concatenate([first[:, None], tokens], axis=1),
+        jnp.concatenate([first_logit[:, None], chosen], axis=1),
+        prefill_stats,
+        decode_stats,
+    )
+
+
+def logit_of(logits: jax.Array, tokens: jax.Array) -> jax.Array:
+    """``logits[row, tokens[row]]``: the logit of the token each row chose."""
+    return jnp.take_along_axis(logits, tokens[:, None], axis=1)[:, 0]
+
+
+def decode_loop(
+    params: Params,
+    cache: Cache,
+    first: jax.Array,  # [b] the token prefill chose
+    pos_offset: jax.Array,
+    cfg: DecoderConfig,
+    steps: int,
+    choose,
+    eos_id: int | None = None,
+    real: jax.Array | None = None,  # [b] as decode_step takes it
+) -> tuple[jax.Array, jax.Array, ExpertStats]:
+    """``steps`` further tokens a row after ``first``: tokens and their
+    logits, ``[b, steps]`` each, and the experts' counts over the steps."""
 
     def step(carry, step_no):
-        cache, tok, done = carry
-        logits, cache = decoder_forward(
-            params, tok[:, None], cfg, cache, pos_offset=pos_offset
-        )
-        new_tok = choose(logits[:, -1], step_no + 1)
+        cache, tok, done, stats = carry
+        logits, cache, step_stats = decode_step(params, tok, cache, pos_offset, cfg, real)
+        new_tok = choose(logits, step_no + 1)
+        new_logit = logit_of(logits, new_tok)
         if eos_id is not None:
             done = done | (tok == eos_id)
             new_tok = jnp.where(done, eos_id, new_tok)
-        return (cache, new_tok, done), tok
+        return (cache, new_tok, done, stats + step_stats), (new_tok, new_logit)
 
-    (_, _, _), toks = lax.scan(
-        step, (cache, next_tok, done), jnp.arange(max_new_tokens)
+    done = jnp.zeros(first.shape, bool)
+    (_, _, _, stats), (tokens, chosen) = lax.scan(
+        step, (cache, first, done, ExpertStats.none(cfg)), jnp.arange(steps)
     )
-    return toks.transpose(1, 0)  # [b, max_new]
+    return tokens.transpose(1, 0), chosen.transpose(1, 0), stats
+
+
+def greedy(logits: jax.Array, _step: Any) -> jax.Array:
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def greedy_generate(
@@ -309,13 +728,9 @@ def greedy_generate(
     prompt_mask: jax.Array | None = None,  # [b, t_prompt] True = real token
 ) -> jax.Array:
     """Greedy decode with a static-shape cache; returns ``[b, max_new]``."""
-
-    def choose(logits: jax.Array, _step: Any) -> jax.Array:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
     return _generate_loop(
-        params, prompt_ids, cfg, max_new_tokens, eos_id, prompt_mask, choose
-    )
+        params, prompt_ids, cfg, max_new_tokens, eos_id, prompt_mask, greedy
+    ).tokens
 
 
 def _filter_logits(
@@ -377,4 +792,4 @@ def sample_generate(
 
     return _generate_loop(
         params, prompt_ids, cfg, max_new_tokens, eos_id, prompt_mask, choose
-    )
+    ).tokens

@@ -129,9 +129,14 @@ class DataIndex:
         query_column: ColumnReference,
         doc_columns: list[str],
         number_of_matches: int | ColumnExpression = 3,
+        with_ids: bool = False,
     ) -> Table:
         """Collapse-with-documents: query columns + per-doc-column tuples
-        ordered by rank + a scores tuple (the shape RAG pipelines consume)."""
+        ordered by rank + a scores tuple (the shape RAG pipelines consume);
+        ``with_ids`` adds ``_pw_index_reply_ids`` in the same order (a hit on
+        a row the index holds with no row of the data table behind it — a
+        restored index — has ``None`` in every document column, and only its
+        id says which it was)."""
         flat = self.query_as_of_now(
             query_table,
             query_column,
@@ -139,7 +144,7 @@ class DataIndex:
             collapse_rows=False,
         )
         return fetch_docs_for_hits(
-            self.data_table, query_table, flat, doc_columns
+            self.data_table, query_table, flat, doc_columns, with_ids
         )
 
 
@@ -148,17 +153,22 @@ def fetch_docs_for_hits(
     query_table: Table,
     flat_hits: Table,
     doc_columns: list[str],
+    with_ids: bool = False,
 ) -> Table:
     """Shared collapse tail: one-row-per-hit table (``_pw_query_id`` /
     ``_pw_index_reply_rank`` / ``_pw_index_reply_id`` / ``_pw_index_reply_score``)
     -> per-query doc-column tuples ordered by rank + scores tuple."""
-    # optional=True: zero-hit sentinel rows carry a None doc id
-    docs_at = data_table.ix(flat_hits["_pw_index_reply_id"], optional=True)
+    # optional: zero-hit sentinel rows carry a None doc id; allow_misses: a
+    # restored index holds rows the data table never had
+    docs_at = data_table.ix(
+        flat_hits["_pw_index_reply_id"], optional=True, allow_misses=True
+    )
     fetched = flat_hits.select(
         _pw_query_id=flat_hits["_pw_query_id"],
         _pw_index_reply_rank=flat_hits["_pw_index_reply_rank"],
         _pw_index_reply_score=flat_hits["_pw_index_reply_score"],
         **{name: docs_at[name] for name in doc_columns},
+        **({"_pw_index_reply_ids": flat_hits["_pw_index_reply_id"]} if with_ids else {}),
     )
 
     def strip_ranks(pairs: tuple) -> tuple:
@@ -173,7 +183,7 @@ def fetch_docs_for_hits(
                 make_tuple(fetched["_pw_index_reply_rank"], fetched[name])
             ),
         )
-        for name in doc_columns
+        for name in doc_columns + (["_pw_index_reply_ids"] if with_ids else [])
     }
     agg["_pw_index_reply_scores"] = pw_apply(
         strip_ranks,

@@ -2,7 +2,8 @@
 
 The local chat — reference HFPipelineChat (:441, torch `pipeline`) — is the
 TPU-native causal decoder (models/decoder.py): greedy decode with a static
-KV cache, microbatched by the engine. Remote chats (OpenAIChat :84,
+cache, microbatched by the engine, two named programs (prefill, decode loop)
+over prompt-length buckets. Remote chats (OpenAIChat :84,
 LiteLLMChat :313, CohereChat :544) are async UDFs over an injected client
 (zero-egress environment).
 """
@@ -73,17 +74,44 @@ def _checkpoint_digest(params: Any, tokenizer: Any) -> str:
     return h.hexdigest()
 
 
+#: the decoder presets a name picks; anything else is a ``DecoderConfig``
+#: the caller built (``DecoderConfig.from_hf`` reads a published config)
+_DECODER_PRESETS = {"mistral-7b": "mistral_7b", "tiny": "tiny_decoder"}
+
+
+def _prompt_buckets(max_prompt_len: int, least: int = 16) -> tuple[int, ...]:
+    out, b = [], least
+    while b < max_prompt_len:
+        out.append(b)
+        b *= 2
+    return tuple(out) + (max_prompt_len,)
+
+
 class TpuPipelineChat(UDF):
     """Local decode on TPU.
 
-    ``model`` picks a DecoderConfig preset ('mistral-7b' or 'tiny'); weights
-    random unless ``params`` is passed (import a checkpoint for real text).
-    A custom tokenizer with ``encode``/``decode`` may be supplied.
+    ``model`` is a ``DecoderConfig`` (``DecoderConfig.from_hf`` builds one
+    from a published ``config.json``'s keys) or the name of a preset
+    ('mistral-7b', 'tiny'); weights are random, stored in bfloat16, unless
+    ``params`` is passed (import a checkpoint for real text). A custom
+    tokenizer with ``encode``/``decode`` may be supplied.
+
+    A call pads its prompts on the left to the least of ``prompt_buckets``
+    that holds the longest (powers of two up to ``max_prompt_len`` where none
+    are given) and its rows to ``max_batch_size``, always, so the compiled
+    programs are one prefill a bucket and one decode loop: ``chat_prefill``
+    (the prompts into a cache of ``max_prompt_len + max_new_tokens`` slots,
+    the head at each row's last position) and ``chat_decode`` (the remaining
+    tokens through the cache). A prompt over ``max_prompt_len`` tokens keeps
+    its first ``max_prompt_len - keep_tail`` and its last ``keep_tail``: in a
+    RAG prompt that is the tail of the context lost, the question and the
+    cue kept. ``last_generation`` holds what the last call produced: the
+    tokens, each token's logit (float32), the expert layers' counts.
     """
 
     def __init__(
         self,
-        model: str = "tiny",
+        model: Any = "tiny",
         *,
         max_new_tokens: int = 32,
         max_prompt_len: int = 128,
@@ -96,79 +124,146 @@ class TpuPipelineChat(UDF):
         temperature: float = 1.0,
         top_k: int | None = None,
         top_p: float | None = None,
+        prompt_buckets: Any = None,
+        keep_tail: int = 64,
+        eos_id: int | None = 2,
     ) -> None:
+        import functools
         import zlib
 
         import jax
         import jax.numpy as jnp
         import numpy as np
 
-        from pathway_tpu.models import (
-            greedy_generate,
-            init_decoder_params,
-            mistral_7b,
-            sample_generate,
-            tiny_decoder,
-        )
+        from pathway_tpu.internals import tracing as _tracing
+        from pathway_tpu.models import decoder as _decoder
 
-        cfg_fn = {"mistral-7b": mistral_7b, "tiny": tiny_decoder}.get(model)
-        if cfg_fn is None:
-            raise ValueError(f"unknown decoder preset {model!r}")
-        self.config = cfg_fn()
+        if isinstance(model, str):
+            preset = _DECODER_PRESETS.get(model)
+            if preset is None:
+                raise ValueError(f"unknown decoder preset {model!r}")
+            self.config = getattr(_decoder, preset)()
+            name = model
+        else:
+            self.config = model
+            name = (
+                f"{model.attention}-{model.hidden}x{model.layers}"
+                f"-e{model.n_routed_experts}-v{model.vocab_size}"
+            )
         self.max_new_tokens = max_new_tokens
         self.max_prompt_len = max_prompt_len
+        self.max_batch_size = max_batch_size
+        self.keep_tail = min(keep_tail, max_prompt_len // 2)
+        buckets = prompt_buckets or _prompt_buckets(max_prompt_len)
+        self.prompt_buckets = tuple(
+            sorted({b for b in buckets if b < max_prompt_len} | {max_prompt_len})
+        )
         self.tokenizer = tokenizer or HashTokenizer(self.config.vocab_size)
         custom_weights = params is not None or tokenizer is not None
         if params is None:
-            params = init_decoder_params(jax.random.key(seed), self.config)
+            params = _decoder.init_decoder_params(
+                jax.random.key(seed), self.config, jnp.bfloat16
+            )
+        self._params = params
+        self.last_generation: dict | None = None
         cfg = self.config
-        mnt = max_new_tokens
+        cache_len = max_prompt_len + max_new_tokens
+
+        # params ride as a runtime argument, not a closure (a closed-over
+        # array is inlined into every bucket's module as a constant), and
+        # the functions carry names: a trace shows jit_chat_prefill and
+        # jit_chat_decode, not a lambda
+        def chat_prefill(p, ids, mask):
+            logits, cache, offset, stats = _decoder.prefill(p, ids, mask, cfg, cache_len)
+            token = _decoder.greedy(logits, 0)
+            return cache, offset, token, _decoder.logit_of(logits, token), stats
+
+        def chat_decode(p, cache, offset, first, real):
+            return _decoder.decode_loop(
+                p, cache, first, offset, cfg, max_new_tokens - 1, _decoder.greedy, eos_id, real
+            )
+
+        def chat_sample(p, ids, mask, row_seeds):
+            return _decoder.sample_generate(
+                p, ids, cfg, max_new_tokens=max_new_tokens, row_seeds=row_seeds,
+                temperature=temperature, top_k=top_k, top_p=top_p, eos_id=eos_id, prompt_mask=mask,
+            )
+
+        self._prefill = functools.partial(jax.jit(chat_prefill), params)
+        self._decode = functools.partial(jax.jit(chat_decode), params)
+        self._sample = functools.partial(jax.jit(chat_sample), params)
+
+        def encode(text: str) -> tuple[list, bool]:
+            ids = self.tokenizer.encode(text, 1 << 30)
+            if len(ids) <= max_prompt_len:
+                return ids, False
+            return ids[: max_prompt_len - self.keep_tail] + ids[-self.keep_tail :], True
 
         def generate_batch(prompts: list) -> list:
-            texts = [_coerce_prompt(p) for p in prompts]
-            encoded = [
-                self.tokenizer.encode(t, self.max_prompt_len) for t in texts
-            ]
-            t_max = max(len(e) for e in encoded)
-            ids = np.zeros((len(texts), t_max), np.int32)
-            mask = np.zeros((len(texts), t_max), bool)
-            for i, e in enumerate(encoded):
-                ids[i, t_max - len(e) :] = e  # left-pad: generation is at end
-                mask[i, t_max - len(e) :] = True
-            if do_sample:
-                # per-row seed from (seed, prompt text): sampling stays a
-                # deterministic function of the row, independent of batch
-                # composition (retraction consistency)
-                row_seeds = np.asarray(
-                    [
-                        (zlib.crc32(t.encode()) ^ seed) & 0xFFFFFFFF
-                        for t in texts
-                    ],
-                    np.uint32,
+            with _tracing.stage("chat.batch", rows=len(prompts)) as batch:
+                with _tracing.detail("chat.tokenize"):
+                    texts = [_coerce_prompt(p) for p in prompts]
+                    encoded = [encode(t) for t in texts]
+                with _tracing.detail("chat.pad"):
+                    longest = max(len(e) for e, _ in encoded)
+                    width = next(b for b in self.prompt_buckets if b >= longest)
+                    ids = np.zeros((max_batch_size, width), np.int32)
+                    mask = np.zeros((max_batch_size, width), bool)
+                    for i, (e, _) in enumerate(encoded):
+                        ids[i, width - len(e) :] = e  # left-pad: generation is at end
+                        mask[i, width - len(e) :] = True
+                    real = np.arange(max_batch_size) < len(prompts)
+                real_tokens = sum(len(e) for e, _ in encoded)
+                batch.add(
+                    prompt_tokens=real_tokens,
+                    padded_prompt_tokens=ids.size,
+                    new_tokens=len(prompts) * max_new_tokens,
+                    truncated=sum(cut for _, cut in encoded),
                 )
-                toks = sample_generate(
-                    params,
-                    jnp.asarray(ids),
-                    cfg,
-                    max_new_tokens=mnt,
-                    row_seeds=jnp.asarray(row_seeds),
-                    temperature=temperature,
-                    top_k=top_k,
-                    top_p=top_p,
-                    eos_id=2,
-                    prompt_mask=jnp.asarray(mask),
-                )
-            else:
-                toks = greedy_generate(
-                    params,
-                    jnp.asarray(ids),
-                    cfg,
-                    max_new_tokens=mnt,
-                    eos_id=2,
-                    prompt_mask=jnp.asarray(mask),
-                )
-            toks = np.asarray(toks)
-            return [self.tokenizer.decode(list(row)) for row in toks]
+                if do_sample:
+                    # per-row seed from (seed, prompt text): sampling stays a
+                    # deterministic function of the row, independent of batch
+                    # composition (retraction consistency)
+                    row_seeds = np.zeros(max_batch_size, np.uint32)
+                    row_seeds[: len(texts)] = [
+                        (zlib.crc32(t.encode()) ^ seed) & 0xFFFFFFFF for t in texts
+                    ]
+                    with _tracing.stage("chat.dispatch", h2d_bytes=ids.nbytes + mask.nbytes):
+                        toks_dev = self._sample(
+                            jnp.asarray(ids), jnp.asarray(mask), jnp.asarray(row_seeds)
+                        )
+                    with _tracing.stage("chat.fetch", wait=True) as st:
+                        toks = np.asarray(toks_dev)
+                        st.add(d2h_bytes=toks.nbytes)
+                    self.last_generation = {"rows": len(prompts), "bucket": width, "tokens": toks}
+                else:
+                    with _tracing.stage("chat.dispatch", h2d_bytes=ids.nbytes + mask.nbytes + real.nbytes):
+                        # both programs are enqueued before anything is read back
+                        cache, offset, first, first_logit, pre = self._prefill(
+                            jnp.asarray(ids), jnp.asarray(mask)
+                        )
+                        rest, rest_logits, dec = self._decode(cache, offset, first, jnp.asarray(real))
+                    with _tracing.stage("chat.fetch", wait=True) as st:
+                        fetched = jax.device_get(
+                            (first, first_logit, rest, rest_logits, pre, dec)
+                        )
+                        first, first_logit, rest, rest_logits, pre, dec = fetched
+                        toks = np.concatenate([first[:, None], rest], axis=1)
+                        logits = np.concatenate([first_logit[:, None], rest_logits], axis=1)
+                        load = pre.load + dec.load  # [expert layers, experts]
+                        st.add(
+                            d2h_bytes=sum(a.nbytes for a in jax.tree.leaves(fetched)),
+                            expert_tokens_max=int(load.max(-1).sum()),
+                            expert_tokens_mean=int(round(float(load.mean(-1).sum()))),
+                        )
+                    self.last_generation = {
+                        "rows": len(prompts), "bucket": width, "tokens": toks, "logits": logits,
+                        "prompt_tokens": [len(e) for e, _ in encoded],
+                        "expert_load": load, "prefill_touched": int(pre.touched),
+                        "decode_touched": int(dec.touched),
+                    }
+                with _tracing.detail("chat.detokenize"):
+                    return [self.tokenizer.decode(list(row)) for row in toks[: len(prompts)]]
 
         super().__init__(
             generate_batch,
@@ -181,7 +276,7 @@ class TpuPipelineChat(UDF):
             # restarts) so two checkpoints can never serve each other's
             # cached rows.
             cache_name=(
-                f"TpuPipelineChat:{model}:{max_new_tokens}:{max_prompt_len}"
+                f"TpuPipelineChat:{name}:{max_new_tokens}:{max_prompt_len}"
                 f":seed{seed}"
                 + (
                     f":tag{cache_tag}"

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import Any, Sequence
 
+from pathway_tpu.internals import tracing as _tracing
 from pathway_tpu.internals.expression import apply as pw_apply
 from pathway_tpu.internals.table import Table
 from pathway_tpu.xpacks.llm import prompts
@@ -21,18 +22,37 @@ NOT_FOUND = "No information found."
 
 
 class BaseRAGQuestionAnswerer:
+    """``chunk_store(key) -> str`` reads the text of a hit that has no row of
+    the documents table behind it — a row restored into the index, whose
+    text a restarted deployment keeps in its chunk store; with none such a
+    hit is left out of the context."""
+
     def __init__(
         self,
         llm: Any,
-        indexer: DocumentStore,
+        indexer: DocumentStore | None,
         *,
         search_topk: int = 6,
         prompt_template: Any = prompts.prompt_qa,
+        chunk_store: Any = None,
     ) -> None:
         self.llm = llm
         self.indexer = indexer
         self.search_topk = search_topk
         self.prompt_template = prompt_template
+        self.chunk_store = chunk_store
+
+    def _full_prompt(self, question: str, texts: Sequence, keys: Sequence) -> str:
+        """The template over the hits' texts in rank order."""
+        with _tracing.detail("qa.prompt", docs=len(texts)):
+            store = self.chunk_store
+            context = []
+            for text, key in zip(texts, keys):
+                if text is None and store is not None and key is not None:
+                    text = store(key)
+                if text is not None:
+                    context.append(text)
+            return self.prompt_template(question, context)
 
     def answer_query(self, query_table: Table) -> Table:
         """``query_table(prompt: str)`` -> ``(result: str, context_docs)``."""
@@ -42,14 +62,58 @@ class BaseRAGQuestionAnswerer:
             k=pw_apply(lambda _q: topk, query_table.prompt),
         )
         hits = self.indexer.retrieve_query(prepped)
-        template = self.prompt_template
         with_prompt = query_table.restrict(hits).select(
             prompt=query_table.prompt,
             docs=hits.result,
             full_prompt=pw_apply(
-                lambda q, docs: template(q, [d["text"] for d in docs]),
+                lambda q, docs: self._full_prompt(
+                    q, [d["text"] for d in docs], [None] * len(docs)
+                ),
                 query_table.prompt,
                 hits.result,
+            ),
+        )
+        return with_prompt.select(
+            result=self.llm(with_prompt.full_prompt),
+            context_docs=with_prompt.docs,
+        )
+
+    def answer_index_reply(
+        self,
+        query_table: Table,
+        index: Any,
+        query_column: Any,
+        *,
+        text_column: str = "text",
+    ) -> Table:
+        """The same answerer over a ``DataIndex``'s reply, for a caller that
+        holds the index and the queries' vectors and no ``DocumentStore``:
+        ``query_table(prompt: str, ...)`` -> ``(result: str, context_docs)``
+        keyed as the queries are, ``context_docs`` a tuple of ``{"text",
+        "id", "score"}`` in rank order, as of the query's commit."""
+        hits = index.query_docs_as_of_now(
+            query_table,
+            query_column,
+            doc_columns=[text_column],
+            number_of_matches=self.search_topk,
+            with_ids=True,
+        )
+        asked = query_table.restrict(hits)
+        with_prompt = hits.select(
+            docs=pw_apply(
+                lambda texts, ids, scores: tuple(
+                    {"text": t, "id": i, "score": s}
+                    for t, i, s in zip(texts, ids, scores)
+                ),
+                hits[text_column],
+                hits["_pw_index_reply_ids"],
+                hits["_pw_index_reply_scores"],
+            ),
+            full_prompt=pw_apply(
+                self._full_prompt,
+                asked.prompt,
+                hits[text_column],
+                hits["_pw_index_reply_ids"],
             ),
         )
         return with_prompt.select(
@@ -130,7 +194,7 @@ class AdaptiveRAGQuestionAnswerer(BaseRAGQuestionAnswerer):
 
             return answer_with_geometric_rag_strategy(
                 question,
-                [d["text"] for d in docs],
+                [d["text"] for d in docs if d["text"] is not None],
                 llm_call,
                 n_starting_documents=n0,
                 factor=factor,
