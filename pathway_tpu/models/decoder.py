@@ -38,7 +38,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from pathway_tpu.ops.moe import expert_sizes, route_top_k, routed_experts
+from pathway_tpu.ops.moe import route_top_k, routed_experts
 from pathway_tpu.parallel.mesh import EXPERT_AXIS, MODEL_AXIS
 
 Params = dict
@@ -361,20 +361,23 @@ def _gated_mlp(h: jax.Array, gate_w: jax.Array, down_w: jax.Array) -> jax.Array:
     return (jax.nn.silu(gate) * up) @ down_w.astype(h.dtype)
 
 
-def _experts_layer(h: jax.Array, lp: Params, cfg: DecoderConfig, counted: jax.Array):
+def _experts_layer(h: jax.Array, lp: Params, cfg: DecoderConfig, counted: jax.Array | None):
     """Routed plus shared experts over ``h`` ``[b, t, hidden]``; also how
-    many of the ``counted`` tokens' choices each expert took and how many
-    experts had any row at all."""
+    many of the ``counted`` ``[b, t]`` tokens' choices each expert took
+    (``None``: every token's) and how many experts took any. The other
+    tokens are padding and go through the shared experts alone."""
     b, t, hidden = h.shape
     flat = h.reshape(b * t, hidden)
     weights, experts = route_top_k(
         flat, lp["router_w"], cfg.experts_per_token,
         renormalize=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
     )
-    y, sizes = routed_experts(flat, weights, experts, lp["experts_gate_w"], lp["experts_down_w"])
+    y, load = routed_experts(
+        flat, weights, experts, lp["experts_gate_w"], lp["experts_down_w"],
+        None if counted is None else counted.reshape(-1),
+    )
     y = y + _gated_mlp(flat, lp["shared_gate_w"], lp["shared_down_w"])
-    load = expert_sizes(experts, cfg.n_routed_experts, counted.reshape(-1))
-    return y.reshape(b, t, hidden), load, jnp.count_nonzero(sizes).astype(jnp.int32)
+    return y.reshape(b, t, hidden), load, jnp.count_nonzero(load).astype(jnp.int32)
 
 
 # -- cache --------------------------------------------------------------------
@@ -493,9 +496,10 @@ def _mla_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only)
 
 class ExpertStats(NamedTuple):
     """What the expert layers of one forward pass took: ``load`` ``[expert
-    layers, experts]`` int32, the choices of the counted (real) tokens each
-    expert got; ``touched`` ``[]`` int32, over the expert layers the experts
-    that had any row, a padding row's included (their weights were read)."""
+    layers, experts]`` int32, the choices of the real tokens each expert
+    got; ``touched`` ``[]`` int32, over the expert layers the experts a real
+    token chose: those whose weights the pass had to read (padding takes no
+    routed expert)."""
 
     load: jax.Array
     touched: jax.Array
@@ -549,7 +553,7 @@ def _stack(
         x = x + (a @ lp["o_w"].astype(cfg.dtype))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         if kind == "experts":
-            y, load, n_touched = _experts_layer(h, lp, cfg, real)
+            y, load, n_touched = _experts_layer(h, lp, cfg, attn_mask)
             loads.append(load)
             touched = touched + n_touched
         else:

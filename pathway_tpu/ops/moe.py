@@ -12,8 +12,12 @@ and runs each expert's gated MLP over its own rows with
 ``jax.lax.ragged_dot``: the work is that of the rows chosen, not of every
 expert over every token, and an expert's weights are read only where it has
 rows. There is no capacity: every pair is computed whatever the skew, all
-tokens on one expert included. The per-expert row counts come back for the
-callers' counters (``expert_sizes``).
+tokens on one expert included. Padding takes no expert: given the mask of
+real tokens, a padding token's pairs are sorted behind the last expert's
+rows and belong to no group, so they are not computed, an expert that only
+padding chose has no row and its weights are not read, and a padding
+token's result is zero. The per-expert row counts of the real tokens come
+back for the callers' counters (``expert_sizes``).
 """
 
 from __future__ import annotations
@@ -62,16 +66,20 @@ def routed_experts(
     experts: jax.Array,  # [n, k] int32
     gate_up_w: jax.Array,  # [experts, hidden, 2 * width]: gate | up
     down_w: jax.Array,  # [experts, width, hidden]
+    counted: jax.Array | None = None,  # [n] bool: False = a padding token
 ) -> tuple[jax.Array, jax.Array]:
     """``sum_i weights[:, i] * E_{experts[:, i]}(h)`` with ``E`` a gated SiLU
     MLP, ``[n, hidden]`` in ``h``'s dtype (float32 accumulation), and how
-    many (token, choice) pairs each expert took, ``[experts]`` int32."""
+    many (token, choice) pairs each expert took, ``[experts]`` int32. Of the
+    ``counted`` tokens alone where given: the others' pairs are in no
+    expert's group and their rows of the result are zero."""
     n, k = experts.shape
     n_experts = gate_up_w.shape[0]
-    flat = experts.reshape(-1)
-    order = jnp.argsort(flat)  # stable: an expert's rows stay in token order
+    # a padding token's pairs sort behind the last expert's rows, into no group
+    keys = experts if counted is None else jnp.where(counted[:, None], experts, n_experts)
+    order = jnp.argsort(keys.reshape(-1))  # stable: an expert's rows stay in token order
     token = order // k
-    sizes = expert_sizes(experts, n_experts)
+    sizes = expert_sizes(experts, n_experts, counted)
     x = h[token]
     gate_up = lax.ragged_dot(x, gate_up_w.astype(h.dtype), sizes, preferred_element_type=jnp.float32)
     gate, up = jnp.split(gate_up, 2, axis=-1)
@@ -81,4 +89,8 @@ def routed_experts(
     # a scatter-add over the sorted rows costs the chip far more
     out = out[jnp.argsort(order)].reshape(n, k, -1)
     y = (out * weights[..., None]).sum(1)
+    if counted is not None:
+        # the rows past the groups hold whatever the device left there: the
+        # mask decides a padding token's result, not those rows
+        y = jnp.where(counted[:, None], y, 0.0)
     return y.astype(h.dtype), sizes

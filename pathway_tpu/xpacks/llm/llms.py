@@ -99,7 +99,9 @@ class TpuPipelineChat(UDF):
     A call pads its prompts on the left to the least of ``prompt_buckets``
     that holds the longest (powers of two up to ``max_prompt_len`` where none
     are given) and its rows to ``max_batch_size``, always, so the compiled
-    programs are one prefill a bucket and one decode loop: ``chat_prefill``
+    programs are one prefill a bucket and one decode loop (the padding takes
+    no routed expert: the programs get the mask, and ``chat.fetch`` counts
+    the pairs left out): ``chat_prefill``
     (the prompts into a cache of ``max_prompt_len + max_new_tokens`` slots,
     the head at each row's last position) and ``chat_decode`` (the remaining
     tokens through the cache). A prompt over ``max_prompt_len`` tokens keeps
@@ -251,10 +253,20 @@ class TpuPipelineChat(UDF):
                         toks = np.concatenate([first[:, None], rest], axis=1)
                         logits = np.concatenate([first_logit[:, None], rest_logits], axis=1)
                         load = pre.load + dec.load  # [expert layers, experts]
+                        # the (token, choice) pairs of the call's shapes, and
+                        # those of them that were padding and took no expert
+                        steps = max_new_tokens - 1
+                        pad_rows = max_batch_size - len(prompts)
+                        expert_layers = load.shape[0]
+                        pairs_per_token = expert_layers * cfg.experts_per_token
                         st.add(
                             d2h_bytes=sum(a.nbytes for a in jax.tree.leaves(fetched)),
                             expert_tokens_max=int(load.max(-1).sum()),
                             expert_tokens_mean=int(round(float(load.mean(-1).sum()))),
+                            expert_pairs=pairs_per_token * (ids.size + max_batch_size * steps),
+                            expert_pairs_skipped=pairs_per_token * (ids.size - real_tokens + pad_rows * steps),
+                            decode_touched=int(dec.touched),
+                            decode_layer_steps=expert_layers * steps,
                         )
                     self.last_generation = {
                         "rows": len(prompts), "bucket": width, "tokens": toks, "logits": logits,

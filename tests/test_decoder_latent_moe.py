@@ -235,17 +235,125 @@ def test_every_token_on_one_expert_through_the_layer_still_gives_the_references_
     assert [list(row) for row in load] == [[12, 12, 0, 0, 0, 0, 0, 0]] * 2
 
 
-def test_expert_counts_take_real_tokens_only_and_touched_counts_every_row(model):
+def _grouped_product_inputs(n=12, hidden=16, width=8, n_experts=8, k=2, seed=21):
+    rng = np.random.default_rng(seed)
+    h = rng.normal(size=(n, hidden)).astype(np.float32)
+    gate_up = jnp.asarray(rng.normal(size=(n_experts, hidden, 2 * width)) / 4, jnp.float32)
+    down = jnp.asarray(rng.normal(size=(n_experts, width, hidden)) / 3, jnp.float32)
+    experts = np.stack([rng.permutation(n_experts)[:k] for _ in range(n)]).astype(np.int32)
+    weights = rng.uniform(0.05, 0.3, (n, k)).astype(np.float32)
+    return h, weights, experts, gate_up, down
+
+
+@pytest.mark.parametrize("n_padding", [0, 1, 5, 11])
+def test_a_real_tokens_row_is_the_same_to_the_bit_whatever_the_padding_tokens_hold(n_padding):
+    h, weights, experts, gate_up, down = _grouped_product_inputs()
+    real = np.ones(12, bool)
+    real[np.random.default_rng(n_padding).permutation(12)[:n_padding]] = False
+    alone, alone_sizes = moe.routed_experts(
+        jnp.asarray(h[real]), jnp.asarray(weights[real]), jnp.asarray(experts[real]), gate_up, down
+    )
+    for filler in (0.0, 3e38, np.inf, -np.inf):
+        padded = h.copy()
+        padded[~real] = filler
+        y, sizes = moe.routed_experts(
+            jnp.asarray(padded), jnp.asarray(weights), jnp.asarray(experts), gate_up, down, jnp.asarray(real)
+        )
+        np.testing.assert_array_equal(np.asarray(y)[real], np.asarray(alone))
+        np.testing.assert_array_equal(np.asarray(y)[~real], 0.0)
+        np.testing.assert_array_equal(np.asarray(sizes), np.asarray(alone_sizes))
+    assert int(alone_sizes.sum()) == (12 - n_padding) * 2
+
+
+def test_a_batch_of_padding_alone_takes_no_expert_and_gives_zeros():
+    h, weights, experts, gate_up, down = _grouped_product_inputs()
+    h[::2] = np.inf
+    y, sizes = moe.routed_experts(
+        jnp.asarray(h), jnp.asarray(weights), jnp.asarray(experts), gate_up, down, jnp.zeros(12, bool)
+    )
+    np.testing.assert_array_equal(np.asarray(sizes), 0)
+    np.testing.assert_array_equal(np.asarray(y), 0.0)  # no NaN either
+
+
+def test_without_a_mask_the_grouped_product_is_what_it_was_to_the_bit():
+    """Against the product as it stood before it took a mask, and against a
+    mask that counts every token."""
+    h, weights, experts, gate_up, down = (jnp.asarray(a) for a in _grouped_product_inputs(n=40))
+    n, k = experts.shape
+    order = jnp.argsort(experts.reshape(-1))
+    sizes = moe.expert_sizes(experts, 8)
+    gate, up = jnp.split(
+        jax.lax.ragged_dot(h[order // k], gate_up, sizes, preferred_element_type=jnp.float32), 2, axis=-1
+    )
+    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, down, sizes, preferred_element_type=jnp.float32)
+    was = (out[jnp.argsort(order)].reshape(n, k, -1) * weights[..., None]).sum(1)
+    for counted in (None, jnp.ones(n, bool)):
+        y, got_sizes = moe.routed_experts(h, weights, experts, gate_up, down, counted)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(was))
+        np.testing.assert_array_equal(np.asarray(got_sizes), np.asarray(sizes))
+
+
+def test_expert_counts_and_touched_take_real_tokens_only(model):
     cfg, params = model
-    ids = np.zeros((2, 6), np.int32)
-    mask = np.zeros((2, 6), bool)
-    ids[0, 2:], mask[0, 2:] = [9, 8, 7, 6], True  # row 1 is all padding
+    rng = np.random.default_rng(13)
+    ids = np.zeros((8, 6), np.int32)
+    mask = np.zeros((8, 6), bool)
+    for r, n in enumerate([4, 6, 3]):  # rows 3-7 are all padding
+        ids[r, 6 - n :], mask[r, 6 - n :] = rng.integers(4, 512, n), True
     _, cache, offset, stats = dec_mod.prefill(params, jnp.asarray(ids), jnp.asarray(mask), cfg, 8)
-    assert np.asarray(stats.load).sum() == 2 * 4 * 2  # two expert layers, four real tokens, two choices
-    assert 2 <= int(stats.touched) <= 2 * 8
-    real = jnp.asarray([True, False])
-    _, _, step = dec_mod.decode_step(params, jnp.asarray([5, 0], jnp.int32), cache, offset, cfg, real)
-    assert np.asarray(step.load).sum() == 2 * 1 * 2
+    load = np.asarray(stats.load)
+    assert load.sum() == 2 * 13 * 2  # two expert layers, 13 real tokens, two choices
+    assert int(stats.touched) == np.count_nonzero(load)  # the experts a real token chose, no other
+    _, _, _, alone = dec_mod.prefill(params, jnp.asarray(ids[:3]), jnp.asarray(mask[:3]), cfg, 8)
+    np.testing.assert_array_equal(load, np.asarray(alone.load))
+    assert int(stats.touched) == int(alone.touched)
+    real = jnp.arange(8) < 3
+    tok = jnp.asarray([5, 6, 7, 0, 0, 0, 0, 0], jnp.int32)
+    _, _, step = dec_mod.decode_step(params, tok, cache, offset, cfg, real)
+    assert np.asarray(step.load).sum() == 2 * 3 * 2
+    assert int(step.touched) == np.count_nonzero(np.asarray(step.load)) <= 2 * 3 * 2
+    # 8 rows of 2 choices would touch up to all 8 experts of a layer: 3 real rows touch what they chose
+    _, _, every = dec_mod.decode_step(params, tok, cache, offset, cfg)
+    assert int(step.touched) <= int(every.touched)
+
+
+def test_rows_of_padding_beside_a_row_change_neither_its_tokens_nor_its_logits(model):
+    """Rows 0-2 of a call of 8 (what ``TpuPipelineChat`` sends: rows to the
+    cap, ``real`` for the decode loop) through prefill and the decode loop,
+    against the same prompts with real rows beside them, to the bit, and in a
+    call of three rows of their own (whose products have another shape and
+    round in another order: the tokens, and the logits to a few ulps)."""
+    cfg, params = model
+    rng = np.random.default_rng(14)
+    lengths, width, new = [5, 11, 8, 12, 7, 12, 9, 4], 12, 6
+    ids = np.zeros((8, width), np.int32)
+    mask = np.zeros((8, width), bool)
+    for r, n in enumerate(lengths):
+        ids[r, width - n :], mask[r, width - n :] = rng.integers(4, 512, n), True
+
+    def generate(ids, mask, real):
+        logits, cache, offset, pre = dec_mod.prefill(params, jnp.asarray(ids), jnp.asarray(mask), cfg, width + new)
+        first = dec_mod.greedy(logits, 0)
+        rest, rest_logits, dec = dec_mod.decode_loop(
+            params, cache, first, offset, cfg, new - 1, dec_mod.greedy, None, real
+        )
+        tokens = np.concatenate([np.asarray(first)[:, None], np.asarray(rest)], axis=1)
+        chosen = np.concatenate([np.asarray(dec_mod.logit_of(logits, first))[:, None], np.asarray(rest_logits)], axis=1)
+        return tokens, chosen, pre, dec
+
+    full_tokens, full_chosen, _, _ = generate(ids, mask, None)  # every row real
+    padded_ids, padded_mask = ids.copy(), mask.copy()
+    padded_ids[3:], padded_mask[3:] = 0, False
+    tokens, chosen, pre, dec = generate(padded_ids, padded_mask, jnp.arange(8) < 3)
+    assert chosen.dtype == np.float32
+    np.testing.assert_array_equal(tokens[:3], full_tokens[:3])
+    np.testing.assert_array_equal(chosen[:3], full_chosen[:3])
+    own_tokens, own_chosen, own_pre, own_dec = generate(ids[:3], mask[:3], None)
+    np.testing.assert_array_equal(tokens[:3], own_tokens)
+    np.testing.assert_allclose(chosen[:3], own_chosen, rtol=0, atol=1e-5)
+    for padded, own in ((pre, own_pre), (dec, own_dec)):  # the padding took no expert
+        np.testing.assert_array_equal(np.asarray(padded.load), np.asarray(own.load))
+        assert int(padded.touched) == int(own.touched)
 
 
 # -- rotary frequencies ----------------------------------------------------------

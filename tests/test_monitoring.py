@@ -173,6 +173,8 @@ class TestTimeseriesAndProfileRoutes:
         from pathway_tpu.internals import profiling
 
         profiling.PROFILER.configure(enabled=False, clear=True)
+        # a failover test that ran earlier in this process leaves the fence raised
+        profiling.PROFILER.epoch = 0
         assert profiling.PROFILER.absorb(
             1,
             {

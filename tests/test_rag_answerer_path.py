@@ -124,7 +124,22 @@ def test_the_chats_stages_carry_the_counts_the_metrics_read(chat):
     fetch = added("chat.fetch")
     assert after["chat.fetch"]["wait"] is True and fetch["d2h_bytes"] > 0
     assert fetch["expert_tokens_max"] >= fetch["expert_tokens_mean"] > 0
+    # two expert layers of two choices: 4 x 32 prefilled and 4 x 4 decoded tokens, 50 + 3 x 4 of them real
+    assert fetch["expert_pairs"] == 2 * 2 * (4 * 32 + 4 * 4)
+    assert fetch["expert_pairs_skipped"] == 2 * 2 * (4 * 32 - 50 + 1 * 4)
+    assert fetch["decode_layer_steps"] == 2 * 4
+    assert 2 * 4 <= fetch["decode_touched"] == chat.last_generation["decode_touched"] <= 2 * 4 * 3 * 2
     assert "chat.tokenize" not in after  # a detail stage: only while someone looks
+
+
+def test_a_full_batch_of_full_width_prompts_skips_no_pair(chat):
+    chat._fn([_words(2)])
+    before = _this_threads_stages()["chat.fetch"]["counts"]
+    chat._fn([_words(40, 100 * i) for i in range(4)])  # four rows, each cut to the 32 of the widest bucket
+    after = _this_threads_stages()["chat.fetch"]["counts"]
+    assert chat.last_generation["prompt_tokens"] == [32] * 4
+    assert after["expert_pairs"] - before["expert_pairs"] == 2 * 2 * (4 * 32 + 4 * 4)
+    assert after["expert_pairs_skipped"] == before["expert_pairs_skipped"]
 
 
 # -- the question answerer over a DataIndex's reply ------------------------------
