@@ -975,7 +975,7 @@ def _mesh_groupby_once(
                 sched.receive_topology()
             barrier.wait()
             t0 = time.perf_counter()
-            sched.commit_local()
+            sched.commit()
             times[pid] = time.perf_counter() - t0
             barrier.wait()  # don't tear the mesh down under the peer
         except BaseException as exc:  # noqa: BLE001 — surfaced to caller
@@ -1116,7 +1116,7 @@ else:
     sched.receive_topology()
 _tracing.TRACER.configure(enabled=True, sample=1, clear=True)
 ctx = _tracing.TRACER.begin(sched.time, origin_mono=time.monotonic())
-sched.commit_local()
+sched.commit()
 if ctx is not None:
     _tracing.TRACER.end(sched.time - 1)
 if pid == 0:

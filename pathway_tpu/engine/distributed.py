@@ -48,17 +48,15 @@ import numpy as np
 from pathway_tpu.engine.batch import (
     Columns,
     DeltaBatch,
-    apply_batch_to_state,
     columnarize_entries,
 )
 from pathway_tpu.engine.device import VECTOR_THRESHOLD
 from pathway_tpu.engine.graph import (
-    ErrorLogNode,
     InputSession,
     Node,
+    Scheduler,
     Scope,
     StaticSource,
-    SubscribeNode,
 )
 from pathway_tpu.engine.routing import (
     columnar_shards,
@@ -67,9 +65,9 @@ from pathway_tpu.engine.routing import (
 )
 from pathway_tpu.engine.sharded import (
     _VERIFY_ELISION,
+    ShardedScheduler,
     _assert_colocated,
     partition_rule,
-    partitioner,
 )
 from pathway_tpu.engine.value import Pointer
 
@@ -945,13 +943,14 @@ class MeshTransport:
                 pass
 
 
-class DistributedScheduler:
+class DistributedScheduler(ShardedScheduler):
     """The per-process commit pump of the multi-process runtime.
 
-    Mirrors engine/sharded.py ShardedScheduler over ``threads`` local scope
-    replicas, with remote workers reached through the mesh. Process 0's
-    scope 0 is the primary replica: sources flush there, sinks and
-    globally-stateful operators are pinned there."""
+    ShardedScheduler over ``threads`` local scope replicas, with remote
+    workers reached through the mesh: ``_deliver`` queues remote parts in
+    the outbox and ``propagate`` runs exchange rounds round the shared
+    sweep. Process 0's scope 0 is the primary replica: sources flush
+    there, sinks and globally-stateful operators are pinned there."""
 
     def __init__(
         self,
@@ -962,32 +961,24 @@ class DistributedScheduler:
         n_shared: int | None = None,
         probe: bool = False,
     ) -> None:
-        self.scopes = list(local_scopes)
+        # not ShardedScheduler's constructor: its signature check and its
+        # rewrite are made here per process, after the topology handshake
+        Scheduler.__init__(self, local_scopes, probe, optimize=False)
         for scope in self.scopes:
             # replica `current` holds key shards (see ShardedScheduler)
             scope.sharded = True
         self.threads = len(self.scopes)
         self.process_id = process_id
         self.n_processes = n_processes
-        self.n_workers = self.threads * n_processes
+        #: workers across the whole mesh (ShardedScheduler's ``n``)
+        self.n = self.threads * n_processes
         self.transport = transport
-        self.time = 0
-        self.probe = probe
-        #: node index -> OperatorStats aggregated across LOCAL replicas
-        #: (populated by _drain_local under probe; same read surface as
-        #: Scheduler/ShardedScheduler for the monitor + mesh snapshots)
-        self.stats: dict[int, Any] = {}
         #: peer process id -> last piggybacked metrics snapshot (leader
         #: only; followers attach theirs to round frames bound for 0)
         self.mesh_metrics: dict[int, dict] = {}
         #: peer process id -> spans piggybacked for the in-flight sampled
         #: trace (leader only; the runner assembles + clears per commit)
         self.trace_peer_spans: dict[int, list] = {}
-        if probe:
-            self._queue_gauge = _metrics.REGISTRY.gauge(
-                "pathway_queue_depth",
-                "operators with pending delta batches (backpressure)",
-            )
         #: shared graph length: nodes with index >= n_shared exist only on
         #: process 0 / scope 0 (sink-side chains attached there). The
         #: runner measures it before attaching sink drivers; guessing it
@@ -1120,14 +1111,6 @@ class DistributedScheduler:
         """worker -> (process, local scope idx)."""
         return worker // self.threads, worker % self.threads
 
-    def _partition_fn(self, consumer: Node, port: int):
-        key = (consumer.index, port)
-        fn = self._parts.get(key, False)
-        if fn is False:
-            fn = partitioner(consumer, port, self.n_workers)
-            self._parts[key] = fn
-        return fn
-
     def _push_remote(
         self,
         process: int,
@@ -1204,7 +1187,7 @@ class DistributedScheduler:
     # -- exchange ----------------------------------------------------------
 
     def _deliver(
-        self, producer: Node, out: DeltaBatch, scope_idx: int = 0
+        self, scope_idx: int, producer: Node, out: DeltaBatch
     ) -> None:
         """Split ``out`` per consumer; push each part to the consumer's
         replica on the owning worker (local) or queue it for the owning
@@ -1220,7 +1203,7 @@ class DistributedScheduler:
                     _assert_colocated(
                         consumer, port, out,
                         self.process_id * self.threads + scope_idx,
-                        self.n_workers,
+                        self.n,
                     )
                 EXCHANGE_STATS["elided"] += 1
                 EXCHANGE_STATS["repartitions"] += 1
@@ -1260,7 +1243,7 @@ class DistributedScheduler:
             and out.columns is not None
         ):
             shards = columnar_shards(
-                partition_rule(consumer, port), out.columns, self.n_workers
+                partition_rule(consumer, port), out.columns, self.n
             )
             if shards is not None and self._route_columnar(
                 cons_idx, port, out, shards, consumer=consumer
@@ -1268,9 +1251,9 @@ class DistributedScheduler:
                 return
         EXCHANGE_STATS["host_deliveries"] += 1
         EXCHANGE_STATS["repartitions"] += 1
-        parts: list[list] = [[] for _ in range(self.n_workers)]
+        parts: list[list] = [[] for _ in range(self.n)]
         shards = entry_shards(
-            partition_rule(consumer, port), out.entries, self.n_workers
+            partition_rule(consumer, port), out.entries, self.n
         )
         if shards is not None:
             # batched worker assignment (one digest kernel call), same
@@ -1328,7 +1311,7 @@ class DistributedScheduler:
                 cons_idx,
                 cols,
                 shards,
-                self.n_workers,
+                self.n,
                 consumer=consumer,
             )
             if cparts is not None:
@@ -1355,7 +1338,7 @@ class DistributedScheduler:
                 return False
         EXCHANGE_STATS["host_deliveries"] += 1
         EXCHANGE_STATS["repartitions"] += 1
-        track = not any_remote and _collective.tracking(self.n_workers)
+        track = not any_remote and _collective.tracking(self.n)
         t0 = _walltime.perf_counter_ns() if track else 0
         for worker in workers:
             idx = np.flatnonzero(shards == worker)
@@ -1426,14 +1409,6 @@ class DistributedScheduler:
                 )
         return got
 
-    def _stats_of(self, node: Node):
-        from pathway_tpu.engine.graph import OperatorStats
-
-        st = self.stats.get(node.index)
-        if st is None:
-            st = self.stats[node.index] = OperatorStats()
-        return st
-
     def _metrics_snapshot(self) -> dict:
         """This process's registry snapshot plus its per-operator series —
         the payload followers piggyback on round frames bound for the
@@ -1449,101 +1424,14 @@ class DistributedScheduler:
 
     # -- commit ------------------------------------------------------------
 
-    def _drain_local(self, time: int) -> bool:
-        """Process local pending work to quiescence (including same-time
-        error-log feedback); remote parts accumulate in the outbox.
-        Returns True if anything was processed."""
-        busy = False
-        probe = self.probe
-        trace = _tracing.current()
-        # traced runs attribute device-resident operator kernel time to
-        # the launching span (same per-node split the sharded pump emits)
-        _dops = None
-        if trace is not None:
-            from pathway_tpu.engine import device_ops as _device_ops
-
-            if _device_ops.enabled():
-                _dops = _device_ops
-        while True:
-            did = False
-            busy_nodes = 0
-            for scope_idx, scope in enumerate(self.scopes):
-                for node in scope.nodes:
-                    if not node.has_pending():
-                        continue
-                    did = True
-                    busy_nodes += 1
-                    if probe or trace is not None:
-                        t0 = _walltime.perf_counter()
-                    dns0 = _dops.total_ns() if _dops is not None else 0
-                    out = node.process(time)
-                    if out is None:
-                        out = DeltaBatch()
-                    out = out.consolidate() if out else out
-                    # defer like the sharded scheduler: an eager apply
-                    # would materialise columnar batches into rows before
-                    # the vectorized exchange ships them
-                    node._defer_state(out)
-                    if trace is not None:
-                        extra = {}
-                        if _dops is not None:
-                            dns = _dops.total_ns() - dns0
-                            if dns:
-                                extra["device_ns"] = dns
-                        trace.span(
-                            getattr(node, "name", None)
-                            or type(node).__name__,
-                            "sink"
-                            if isinstance(node, SubscribeNode)
-                            else "op",
-                            t0,
-                            _walltime.perf_counter(),
-                            node=node.index,
-                            scope=scope_idx,
-                            **extra,
-                        )
-                    if probe:
-                        st = self._stats_of(node)
-                        st.time_spent += _walltime.perf_counter() - t0
-                        st.batches += 1
-                        st.last_time = time
-                        cols = out.columns
-                        if cols is not None:
-                            if cols.diffs is None:
-                                st.insertions += cols.n
-                            else:
-                                pos = int((cols.diffs > 0).sum())
-                                st.insertions += pos
-                                st.deletions += cols.n - pos
-                        else:
-                            for _k, _r, d in out.consolidate():
-                                if d > 0:
-                                    st.insertions += 1
-                                else:
-                                    st.deletions += 1
-                    if out:
-                        self._deliver(node, out, scope_idx)
-            if probe:
-                self._queue_gauge.value = float(busy_nodes)
-            if did:
-                busy = True
-                continue
-            flushed = False
-            for scope in self.scopes:
-                for node in scope.nodes:
-                    if isinstance(node, ErrorLogNode):
-                        batch = node.flush_buffer()
-                        if batch:
-                            node.push(0, batch)
-                            flushed = True
-            if not flushed:
-                return busy
-            busy = True
-
     def _flush_sources(self) -> None:
         """Coordinator: flush static sources + input sessions of the
         primary replica; maintain the sharded source-state invariant
         (sharded.py _route_source) and route downstream parts."""
+        self._ensure_optimized()  # no-op after the topology handshake
+        self._mark_replica_sources()
+        if self.process_id != 0:
+            return
         scope0 = self.scopes[0]
         for node in scope0.nodes:
             if isinstance(node, StaticSource):
@@ -1573,16 +1461,16 @@ class DistributedScheduler:
                 if cbatch is not None:
                     batch = cbatch
             # key-shard parts maintain replica state on workers > 0
-            if self.n_workers > 1 and not self._replicate_source_columnar(
+            if self.n > 1 and not self._replicate_source_columnar(
                 node, batch
             ):
-                parts: list[list] = [[] for _ in range(self.n_workers)]
+                parts: list[list] = [[] for _ in range(self.n)]
                 key_shards = shards_of_values(
-                    [e[0] for e in batch.entries], self.n_workers
+                    [e[0] for e in batch.entries], self.n
                 )
                 for e, w in zip(batch.entries, key_shards):
                     parts[w].append(e)
-                for worker in range(1, self.n_workers):
+                for worker in range(1, self.n):
                     if not parts[worker]:
                         continue
                     process, scope_idx = self._owner(worker)
@@ -1595,7 +1483,7 @@ class DistributedScheduler:
                             process, "state", node.index, 0, worker,
                             parts[worker], batch._consolidated,
                         )
-            self._deliver(node, batch)
+            self._deliver(0, node, batch)
 
     def _replicate_source_columnar(
         self, node: Node, batch: DeltaBatch
@@ -1609,7 +1497,7 @@ class DistributedScheduler:
             and batch.columns is not None
         ):
             return False
-        shards = columnar_shards(("key",), batch.columns, self.n_workers)
+        shards = columnar_shards(("key",), batch.columns, self.n)
         if shards is None:
             return False
         cols = batch.columns
@@ -1715,7 +1603,7 @@ class DistributedScheduler:
                 # trace context from the round-0 frame, so rounds >= 1
                 # (and the drain they gate) see it active
                 ctx = _tracing.current()
-                busy = self._drain_local(time)
+                busy = self._sweep(time)
                 my_bit = busy or any(self._outbox.values())
                 # mesh stats protocol: once this process goes quiet for the
                 # round, piggyback its metrics snapshot on the frame bound
@@ -1812,52 +1700,34 @@ class DistributedScheduler:
             raise
         _metrics.FLIGHT.record("exchange", time=time, rounds=round_no)
         if notify_time_end or any_work:
-            for scope in self.scopes:
-                for node in scope.nodes:
-                    node.on_time_end(time)
+            for node in self._nodes():
+                node.on_time_end(time)
         from pathway_tpu.engine import device_pipeline
 
         device_pipeline.commit_boundary(time)
         return any_work
 
-    def commit_local(self) -> int:
+    def propagate(self, time: int) -> None:
+        self._exchange_rounds(time)
+
+    def commit(self) -> int:
         """One commit: coordinator flushes sources, then all processes run
         exchange rounds to global quiescence."""
-        self._ensure_optimized()  # no-op after the topology handshake
-        self._mark_replica_sources()
-        if self.process_id == 0:
-            self._flush_sources()
-        time = self.time
-        self._exchange_rounds(time)
-        self.time += 1
-        _metrics.FLIGHT.record(
-            "commit", time=time, process=self.process_id
-        )
+        time = super().commit()
         if self.process_id != 0:
             # adopted context ends with the commit; its spans already
             # rode the final quiescent round's frame to the leader
             _tracing.TRACER.drop()
         return time
 
-    def finish_local(self) -> None:
-        """Final commit + on_end hooks + one settling commit
-        (ShardedScheduler.finish)."""
-        self.commit_local()
-        for scope in self.scopes:
-            for node in scope.nodes:
-                node.on_end()
-        # on_end may inject final batches (buffer flush) on any process;
-        # sinks tear down in close() only after the settlement delivers them
+    def _settle(self) -> None:
+        # on_end may inject final batches (buffer flush) on any process, so
+        # every process joins the settling rounds; sinks tear down in
+        # close() only after the settlement delivers them
         self._exchange_rounds(self.time, notify_time_end=False)
         self.time += 1
         if self.process_id != 0:
             _tracing.TRACER.drop()
-        from pathway_tpu.engine import device_pipeline
-
-        device_pipeline.drain()
-        for scope in self.scopes:
-            for node in scope.nodes:
-                node.close()
 
     # -- recovery ----------------------------------------------------------
 
@@ -1971,17 +1841,3 @@ class DistributedScheduler:
             # subscribed replica as an epoch-fenced ``snap-rollback``.
             _serving.STORE.truncate(to_time)
             _serving.stream_truncate(to_time)
-
-    # -- monitoring surface parity ----------------------------------------
-
-    @property
-    def scope(self) -> Scope:
-        return self.scopes[0]
-
-    def merged_state(self, index: int) -> dict[Pointer, tuple]:
-        """Union of one operator's state across LOCAL replicas (cross-
-        process captures are not collected; outputs flow through sinks)."""
-        out: dict[Pointer, tuple] = {}
-        for scope in self.scopes:
-            out.update(scope.nodes[index].current)
-        return out

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import itertools
 import logging
+import threading
 import time as _time
 from typing import Any, Callable, Iterable, Sequence
 
@@ -3047,8 +3048,15 @@ class OperatorStats:
 class Scheduler:
     """Topological commit-batch pump (replaces timely's worker loop,
     reference: dataflow.rs:5769-5822). All deltas at one logical time are
-    processed as a unit; ``propagate`` loops until quiescent so same-time
+    processed as a unit; the sweep loops until quiescent so same-time
     feedback (error logs) settles within the commit.
+
+    This class is the single worker's pump and the base of the sharded and
+    mesh ones: it owns the sweep over ``self.scopes`` (a single worker has
+    one), the operator statistics, and ``commit()`` / ``finish()``. A
+    subclass says how an output reaches its consumers (``_deliver``), how
+    sources are flushed (``_flush_sources``) and what ``propagate`` does
+    round the sweep.
 
     ``probe=True`` collects per-operator stats into ``self.stats``
     (node index → OperatorStats), feeding the monitoring dashboard and the
@@ -3056,161 +3064,214 @@ class Scheduler:
     """
 
     def __init__(
-        self, scope: Scope, probe: bool = False, optimize: bool = True
+        self,
+        scope: "Scope | Sequence[Scope]",
+        probe: bool = False,
+        optimize: bool = True,
     ) -> None:
+        #: one scope a worker: a single worker has one
+        self.scopes = [scope] if isinstance(scope, Scope) else list(scope)
         if optimize:
             from pathway_tpu.optimize import optimize_scopes
 
             # single-worker: no exchanges to elide, but fusion/pushdown
             # still apply (skips itself under PATHWAY_TPU_OPTIMIZE=0 and
             # in analyze mode; idempotent per scope)
-            optimize_scopes([scope])
-        self.scope = scope
+            optimize_scopes(self.scopes)
         self.time = 0
         self.probe = probe
-        self.stats: dict[int, OperatorStats] = {}
+        #: the pump thread inserts per-operator entries while the live
+        #: monitoring thread snapshots the dict — serialize the inserts
+        self._stats_lock = threading.Lock()
+        #: node index -> OperatorStats, aggregated across ``self.scopes``
+        self.stats: dict[int, OperatorStats] = {}  # guarded-by: self._stats_lock
         if probe:
             self._queue_gauge = _metrics.REGISTRY.gauge(
                 "pathway_queue_depth",
                 "operators with pending delta batches (backpressure)",
             )
 
+    @property
+    def scope(self) -> Scope:
+        """Canonical scope for monitoring and analysis (with replicas,
+        worker 0's: it carries the superset, sinks attach there only)."""
+        return self.scopes[0]
+
+    def _nodes(self) -> Iterable[Node]:
+        return itertools.chain.from_iterable(s.nodes for s in self.scopes)
+
     def _stats_of(self, node: Node) -> OperatorStats:
         st = self.stats.get(node.index)
         if st is None:
-            st = self.stats[node.index] = OperatorStats()
+            with self._stats_lock:
+                st = self.stats.setdefault(node.index, OperatorStats())
         return st
 
-    def propagate(self, time: int) -> None:
-        scope = self.scope
+    def _note_stats(
+        self, node: Node, out: DeltaBatch, time: int, t0: float
+    ) -> None:
+        st = self._stats_of(node)
+        st.time_spent += _time.perf_counter() - t0
+        st.batches += 1
+        st.last_time = time
+        cols = out.columns
+        if cols is not None:
+            # count from the diff vector — don't materialise rows just
+            # for monitoring
+            if cols.diffs is None:
+                st.insertions += cols.n
+            else:
+                pos = int((cols.diffs > 0).sum())
+                st.insertions += pos
+                st.deletions += cols.n - pos
+        else:
+            # consolidate for counting: raw batches may carry net-zero
+            # churn that monitoring should not report
+            for _k, _r, d in out.consolidate():
+                if d > 0:
+                    st.insertions += 1
+                else:
+                    st.deletions += 1
+
+    def _deliver(self, worker: int, node: Node, out: DeltaBatch) -> None:
+        """How ``node``'s output on ``worker`` reaches its consumers."""
+        for consumer, port in node.consumers:
+            consumer.push(port, out)
+
+    def _sweep(self, time: int) -> bool:
+        """Run every node that has pending batches until none has,
+        same-time error-log feedback included. True if anything ran."""
         probe = self.probe
         # an operator's sweep is a stage only while a sampled commit or a
         # profiler session is there to show it: no metric reads it
         detail = _tracing.detail_on()
-        if probe:
-            import time as _walltime
+        dops = None
+        if detail:
+            # the device operator kernels' time goes to the stage that
+            # launched them (critical-path analysis needs the per-node
+            # split, not just the global kernel_ns bucket)
+            from pathway_tpu.engine import device_ops
+
+            if device_ops.enabled():
+                dops = device_ops
+        worked = False
+        t0 = 0.0
+        st = _tracing.NO_STAGE
         while True:
-            dirty = [n for n in scope.nodes if n.has_pending()]
-            if probe:
-                self._queue_gauge.value = float(len(dirty))
-            if not dirty:
-                # flush error-log buffers; may create new pending work
-                flushed = False
+            ran = 0
+            for worker, scope in enumerate(self.scopes):
                 for node in scope.nodes:
-                    if isinstance(node, ErrorLogNode):
-                        batch = node.flush_buffer()
-                        if batch:
-                            node.push(0, batch)
-                            flushed = True
-                if not flushed:
-                    break
+                    if not node.has_pending():
+                        continue
+                    ran += 1
+                    if probe:
+                        t0 = _time.perf_counter()
+                    if detail:
+                        st = _tracing.stage(
+                            "op." + type(node).__name__,
+                            cat="sink" if isinstance(node, SubscribeNode) else "op",
+                            label=getattr(node, "name", None),
+                            batches=1,
+                            node=node.index,
+                            shard=worker,
+                        )
+                        dns0 = dops.total_ns() if dops is not None else 0
+                    with st:
+                        out = node.process(time)
+                        if out is None:
+                            out = DeltaBatch()
+                        # no eager consolidation or apply: consumers
+                        # consolidate in take() (cached), lazy state drain
+                        # consolidates before applying, and a columnar
+                        # batch stays arrays for the vectorized exchange
+                        node._defer_state(out)
+                        if dops is not None:
+                            dns = dops.total_ns() - dns0
+                            if dns:
+                                st.add(device_ns=dns)
+                    if probe:
+                        self._note_stats(node, out, time, t0)
+                    if out:
+                        self._deliver(worker, node, out)
+            if probe:
+                self._queue_gauge.value = float(ran)
+            if ran:
+                worked = True
                 continue
-            for node in scope.nodes:
-                if not node.has_pending():
-                    continue
-                if probe:
-                    t0 = _walltime.perf_counter()
-                with (
-                    _tracing.stage(
-                        "op." + type(node).__name__,
-                        cat="sink" if isinstance(node, SubscribeNode) else "op",
-                        label=getattr(node, "name", None),
-                        batches=1,
-                    )
-                    if detail
-                    else _tracing.NO_STAGE
-                ):
-                    out = node.process(time)
-                    if out is None:
-                        out = DeltaBatch()
-                    # no eager consolidation: consumers consolidate in
-                    # take() (cached), lazy state drain consolidates
-                    # before applying
-                    node._defer_state(out)
-                if probe:
-                    st = self._stats_of(node)
-                    st.time_spent += _walltime.perf_counter() - t0
-                    st.batches += 1
-                    st.last_time = time
-                    cols = out.columns
-                    if cols is not None:
-                        # count from the diff vector — don't materialise
-                        # rows just for monitoring
-                        if cols.diffs is None:
-                            st.insertions += cols.n
-                        else:
-                            pos = int((cols.diffs > 0).sum())
-                            st.insertions += pos
-                            st.deletions += cols.n - pos
-                    else:
-                        # consolidate for counting: raw batches may carry
-                        # net-zero churn that monitoring should not report
-                        for _k, _r, d in out.consolidate():
-                            if d > 0:
-                                st.insertions += 1
-                            else:
-                                st.deletions += 1
-                if out:
-                    for consumer, port in node.consumers:
-                        consumer.push(port, out)
-        for node in scope.nodes:
+            # flush error-log buffers; may create new pending work
+            flushed = False
+            for node in self._nodes():
+                if isinstance(node, ErrorLogNode):
+                    batch = node.flush_buffer()
+                    if batch:
+                        node.push(0, batch)
+                        flushed = True
+            if not flushed:
+                return worked
+            worked = True
+
+    def propagate(self, time: int) -> None:
+        self._sweep(time)
+        for node in self._nodes():
             node.on_time_end(time)
         from pathway_tpu.engine import device_pipeline
 
         device_pipeline.commit_boundary(time)
 
-    def _end_nodes(self) -> None:
-        """Run on_end hooks; they may inject final batches (buffer flush) —
-        propagate those as one more commit, then tear sinks down."""
-        for node in self.scope.nodes:
-            node.on_end()
-        if any(n.has_pending() for n in self.scope.nodes):
+    def _settle(self) -> None:
+        """``on_end`` hooks may inject final batches (buffer flush):
+        propagate those as one more commit."""
+        if any(n.has_pending() for n in self._nodes()):
             self.propagate(self.time)
             self.time += 1
+
+    def _end_nodes(self) -> None:
+        """Run on_end hooks, settle what they injected, then tear sinks
+        down."""
+        for node in self._nodes():
+            node.on_end()
+        self._settle()
         from pathway_tpu.engine import device_pipeline
 
         device_pipeline.drain()
-        for node in self.scope.nodes:
+        for node in self._nodes():
             node.close()
 
     def _analysis_intercept(self) -> bool:
         """Under ``cli analyze`` (PATHWAY_TPU_ANALYZE=1) the scheduler
-        records the built graph for static analysis and skips execution."""
+        records the built graph for static analysis and skips execution
+        (replicas are identical: worker 0's scope is analyzed once)."""
         from pathway_tpu.analysis import runtime as _analysis_runtime
 
         return _analysis_runtime.intercept(self.scope)
+
+    def _flush_sources(self) -> None:
+        for node in self.scope.nodes:
+            if isinstance(node, StaticSource):
+                batch = node.initial_batch()
+            elif isinstance(node, InputSession):
+                batch = node.flush()
+            else:
+                continue
+            if batch:
+                node.push(0, batch)
 
     def run_static(self) -> None:
         """Batch mode: all static sources at time 0, one commit, then end."""
         if self._analysis_intercept():
             self.time = 1
             return
-        for node in self.scope.nodes:
-            if isinstance(node, StaticSource):
-                batch = node.initial_batch()
-                if batch:
-                    node.push(0, batch)
+        self._flush_sources()
         self.propagate(0)
         self.time = 1
         self._end_nodes()
 
     def commit(self) -> int:
         """Streaming mode: flush all input sessions as one commit."""
-        if self._analysis_intercept():
-            time = self.time
-            self.time += 1
-            return time
-        for node in self.scope.nodes:
-            if isinstance(node, StaticSource):
-                batch = node.initial_batch()
-                if batch:
-                    node.push(0, batch)
-            elif isinstance(node, InputSession):
-                batch = node.flush()
-                if batch:
-                    node.push(0, batch)
         time = self.time
-        self.propagate(time)
+        if not self._analysis_intercept():
+            self._flush_sources()
+            self.propagate(time)
         self.time += 1
         return time
 

@@ -28,7 +28,6 @@ for multi-host (SURVEY §2.10 mapping).
 from __future__ import annotations
 
 import os
-import threading
 from typing import Any, Callable, Sequence
 
 from pathway_tpu.engine.batch import (
@@ -45,6 +44,7 @@ from pathway_tpu.engine.graph import (
     IxNode,
     JoinNode,
     Node,
+    Scheduler,
     Scope,
     SortNode,
     StaticSource,
@@ -164,8 +164,9 @@ def partitioner(
     return by_key
 
 
-class ShardedScheduler:
-    """Lockstep commit pump over N identically-built scopes."""
+class ShardedScheduler(Scheduler):
+    """Lockstep commit pump over N identically-built scopes: the base's
+    sweep and commit, with the exchange as ``_deliver``."""
 
     def __init__(
         self,
@@ -173,27 +174,13 @@ class ShardedScheduler:
         probe: bool = False,
         optimize: bool = True,
     ) -> None:
-        self.scopes = list(scopes)
+        # its own rewrite below: the optimizer's elisions are its to keep
+        super().__init__(scopes, probe, optimize=False)
         self.n = len(self.scopes)
         for scope in self.scopes:
             # replica `current` holds key shards: state-peeking operators
             # (zip/ix/update/iterate) must use their own input mirrors
             scope.sharded = True
-        self.time = 0
-        self.probe = probe
-        #: the pump thread inserts per-operator entries while the live
-        #: monitoring thread snapshots the dict — serialize the inserts
-        self._stats_lock = threading.Lock()
-        #: node index -> OperatorStats aggregated ACROSS workers (the
-        #: monitoring surface reads .scope/.stats like the single Scheduler)
-        self.stats: dict[int, Any] = {}  # guarded-by: self._stats_lock
-        if probe:
-            from pathway_tpu.internals import metrics as _metrics
-
-            self._queue_gauge = _metrics.REGISTRY.gauge(
-                "pathway_queue_depth",
-                "operators with pending delta batches (backpressure)",
-            )
         sigs = [
             [type(node).__name__ for node in scope.nodes]
             for scope in self.scopes
@@ -347,147 +334,26 @@ class ShardedScheduler:
                     batch._consolidated = out._consolidated
                     self.scopes[w].nodes[consumer.index].push(port, batch)
 
-    @property
-    def scope(self) -> Scope:
-        """Canonical scope for monitoring (worker 0 carries the superset)."""
-        return self.scopes[0]
-
-    def _stats_of(self, node: Node):
-        from pathway_tpu.engine.graph import OperatorStats
-
-        st = self.stats.get(node.index)
-        if st is None:
-            with self._stats_lock:
-                st = self.stats.setdefault(node.index, OperatorStats())
-        return st
-
-    def propagate(self, time: int) -> None:
-        from pathway_tpu.internals import tracing as _tracing
-
-        probe = self.probe
-        trace = _tracing.current()
-        if probe or trace is not None:
-            import time as _walltime
-        # traced runs attribute device-resident operator kernel time to
-        # the span that launched it (critical-path analysis needs the
-        # per-node split, not just the global kernel_ns bucket)
-        _dops = None
-        if trace is not None:
-            from pathway_tpu.engine import device_ops as _device_ops
-
-            if _device_ops.enabled():
-                _dops = _device_ops
-        while True:
-            busy = False
-            busy_nodes = 0
-            for w, scope in enumerate(self.scopes):
-                for node in scope.nodes:
-                    if not node.has_pending():
-                        continue
-                    busy = True
-                    busy_nodes += 1
-                    if probe or trace is not None:
-                        t0 = _walltime.perf_counter()
-                    dns0 = _dops.total_ns() if _dops is not None else 0
-                    out = node.process(time)
-                    if out is None:
-                        out = DeltaBatch()
-                    # defer like the single scheduler: an eager apply
-                    # would materialise columnar batches before the
-                    # vectorized exchange can route them
-                    node._defer_state(out)
-                    if trace is not None:
-                        extra = {}
-                        if _dops is not None:
-                            dns = _dops.total_ns() - dns0
-                            if dns:
-                                extra["device_ns"] = dns
-                        trace.span(
-                            getattr(node, "name", None)
-                            or type(node).__name__,
-                            "sink"
-                            if isinstance(node, SubscribeNode)
-                            else "op",
-                            t0,
-                            _walltime.perf_counter(),
-                            node=node.index,
-                            shard=w,
-                            **extra,
-                        )
-                    if probe:
-                        st = self._stats_of(node)
-                        st.time_spent += _walltime.perf_counter() - t0
-                        st.batches += 1
-                        st.last_time = time
-                        cols = out.columns
-                        if cols is not None:
-                            if cols.diffs is None:
-                                st.insertions += cols.n
-                            else:
-                                pos = int((cols.diffs > 0).sum())
-                                st.insertions += pos
-                                st.deletions += cols.n - pos
-                        else:
-                            for _k, _r, d in out.consolidate():
-                                if d > 0:
-                                    st.insertions += 1
-                                else:
-                                    st.deletions += 1
-                    if out:
-                        self._deliver(w, node, out)
-            if probe:
-                self._queue_gauge.value = float(busy_nodes)
-            if busy:
-                continue
-            flushed = False
-            for scope in self.scopes:
-                for node in scope.nodes:
-                    if isinstance(node, ErrorLogNode):
-                        batch = node.flush_buffer()
-                        if batch:
-                            node.push(0, batch)
-                            flushed = True
-            if not flushed:
-                break
-        for scope in self.scopes:
-            for node in scope.nodes:
-                node.on_time_end(time)
-        from pathway_tpu.engine import device_pipeline
-
-        device_pipeline.commit_boundary(time)
-
-    def _analysis_intercept(self) -> bool:
-        """Analyze-only mode: the workers are identical replicas, so the
-        worker-0 scope (the superset — sinks attach there) is analyzed
-        once and execution is skipped."""
-        from pathway_tpu.analysis import runtime as _analysis_runtime
-
-        return _analysis_runtime.intercept(self.scopes[0])
-
-    def commit(self) -> int:
-        if self._analysis_intercept():
-            time = self.time
-            self.time += 1
-            return time
+    def _flush_sources(self) -> None:
         for w, scope in enumerate(self.scopes):
             for node in scope.nodes:
                 if isinstance(node, StaticSource):
-                    # the same static rows exist on every worker replica;
-                    # only worker 0 emits, the exchange spreads them
-                    batch = node.initial_batch() if w == 0 else None
-                    if w != 0:
+                    if w:
+                        # the same static rows exist on every worker
+                        # replica; only worker 0 emits, the exchange
+                        # spreads them
                         node._emitted = True
-                    if batch:
-                        self._route_source(node, batch)
+                        continue
+                    batch = node.initial_batch()
                 elif isinstance(node, InputSession):
                     batch = node.flush()
                     if batch:
                         # flush may return raw diffs; routing applies state
-                        self._route_source(node, batch.consolidate())
-        time = self.time
-        self.propagate(time)
-        self.time += 1
-        return time
+                        batch = batch.consolidate()
+                else:
+                    continue
+                if batch:
+                    self._route_source(node, batch)
 
     def _route_source(self, node: Node, batch: DeltaBatch) -> None:
         """Sources read whole on worker 0 and reshard at the exchange
@@ -539,25 +405,6 @@ class ShardedScheduler:
                         replica = self.scopes[w].nodes[node.index]
                         replica._defer_state(DeltaBatch(parts[w]))
         self._deliver(0, replica0, batch)
-
-    def finish(self) -> None:
-        if self._analysis_intercept():
-            return
-        self.commit()
-        for scope in self.scopes:
-            for node in scope.nodes:
-                node.on_end()
-        if any(
-            n.has_pending() for s in self.scopes for n in s.nodes
-        ):
-            self.propagate(self.time)
-            self.time += 1
-        from pathway_tpu.engine import device_pipeline
-
-        device_pipeline.drain()
-        for scope in self.scopes:
-            for node in scope.nodes:
-                node.close()
 
     # -- results --------------------------------------------------------------
 

@@ -10,6 +10,7 @@ engine expressions, and pumps the scheduler.
 from __future__ import annotations
 
 import os
+import time as _time
 from typing import TYPE_CHECKING, Any, Callable, Sequence
 
 from pathway_tpu.engine import expression as eex
@@ -104,8 +105,6 @@ def _observe_commit_latency(
     Rows without an ingest stamp (static data, replays) fall back to the
     commit start so the histogram ``_count`` always equals the rows the
     sinks produced."""
-    import time as _time
-
     rows = int(_OUT_ROWS.value - rows_before)
     if rows <= 0:
         return
@@ -118,6 +117,130 @@ def _entries_taken(drivers: list) -> int:
     return sum(
         getattr(getattr(d, "driver", d), "entries_total", 0) for d in drivers
     )
+
+
+def _adopt_scheduler(runner: Any, sched: Scheduler) -> Scheduler:
+    runner.scheduler = sched  # telemetry sampler reads stats here
+    if runner.monitor is not None:
+        runner.monitor.scheduler = sched
+    return sched
+
+
+def _probe_wanted(runner: Any) -> bool:
+    return (
+        runner.monitor is not None
+        and getattr(runner.monitor, "wants_operator_stats", True)
+    ) or getattr(runner, "probe_stats", False)
+
+
+def _replay(drivers: list) -> list:
+    """Journaled events go back into their sessions before the first
+    poll; returns the drivers that keep a journal."""
+    persistent = [d for d in drivers if hasattr(d, "replay")]
+    for d in persistent:
+        d.replay()
+    return persistent
+
+
+def _resume_and_commit(sched, scopes: list, drivers: list, snapshot_mgr) -> None:
+    """A run's first commit: operator persistence restores state directly
+    (no event replay) and the clock resumes after the snapshotted commit,
+    so sink timestamps / part names stay monotonic across restarts; then
+    the static sources (and what was replayed) are one commit. It is a
+    ``commit`` stage like the pump's: operators run nowhere else."""
+    if snapshot_mgr is not None:
+        restored_time = snapshot_mgr.restore(scopes, drivers)
+        if restored_time is not None:
+            sched.time = max(sched.time, restored_time + 1)
+    with _tracing.stage("commit"):
+        sched.commit()
+
+
+def _commit_step(
+    commit, sched, drivers: list, announce=None, peer_spans=None
+) -> tuple[int, float]:
+    """One data commit of any runner, inside the ``commit`` stage the pump
+    hands over. The four recorders every commit pays stand side by side
+    here: the stage's ``commit_wait_ns``, the sampled trace, the latency
+    histogram and the flight ring. The mesh leader passes what only it
+    has: ``announce`` runs between the trace's begin and the commit (the
+    context tuple rides the first exchange round's frames, so followers
+    adopt it at commit start) and ``peer_spans`` is where the followers'
+    spans arrive. Returns the commit's time and when it started."""
+    started = _time.monotonic()
+    stamp, sources = _take_ingest_stamp(drivers)
+    commit.add(commit_wait_ns=_commit_wait_ns(stamp, started))
+    rows_before = _OUT_ROWS.value
+    ctx = _tracing.TRACER.begin(
+        sched.time, origin_mono=stamp, sources=sources
+    )
+    if announce is not None:
+        announce()
+    time = sched.commit()
+    _observe_commit_latency(stamp, started, rows_before)
+    _metrics.FLIGHT.record("commit", time=time)
+    if ctx is not None:
+        _tracing.TRACER.end(
+            time, peer_spans=dict(peer_spans) if peer_spans else None
+        )
+        if peer_spans:
+            peer_spans.clear()
+    return time, started
+
+
+def _after_commit(
+    time: int,
+    scopes: list,
+    drivers: list,
+    started: float | None = None,
+    *,
+    w0: "GraphRunner | None" = None,
+    persistent: Sequence = (),
+    snapshot_mgr=None,
+    fault_plan=None,
+    process_id: int = 0,
+) -> None:
+    """What follows a commit, in the one order every runner keeps: the
+    journal's offsets, the operator snapshot, the read tier's view, the
+    fault plan, the monitor (``w0`` carries it and the connectors it
+    counts). A caller passes what it has."""
+    with _tracing.detail("commit.after"):
+        serving = _serving.enabled()
+        if persistent or snapshot_mgr is not None or serving:
+            # exactly-once seam: a checkpoint/offset for commit N may only
+            # be cut once N's staged device work has completed (read
+            # snapshots sit on the same seam: a published view must
+            # contain all of commit N, none of N+1)
+            _device_pipeline.drain_until(time)
+        for d in persistent:
+            d.on_commit(time)
+        if snapshot_mgr is not None:
+            snapshot_mgr.on_commit(scopes, drivers, time)
+        if serving:
+            # one snapshot spanning every local replica: reads merge the
+            # key-sharded views back into the synchronous answer (in a
+            # mesh every process publishes its own shard; rollback
+            # republication truncates stale views)
+            _serving.publish_on_commit(scopes, time)
+        if fault_plan is not None:
+            fault_plan.on_commit(process_id, time)
+        if w0 is not None and w0.monitor is not None:
+            w0._sync_monitor_connectors()
+            w0.monitor.on_commit(time, started)
+
+
+def _end_run(
+    sched, scopes: list, drivers: list, persistent: list, snapshot_mgr
+) -> None:
+    """A run's last commit (a ``commit`` stage too), then the traces, the
+    journal's last offsets and the final snapshot."""
+    with _tracing.stage("commit"):
+        sched.finish()
+    _tracing.TRACER.export()
+    for d in persistent:
+        d.on_commit(sched.time)
+    if snapshot_mgr is not None:
+        snapshot_mgr.snapshot(scopes, drivers, sched.time)
 
 
 def _pump_drivers(w0: "GraphRunner", drivers: list, on_data, on_idle=None) -> None:
@@ -135,11 +258,8 @@ def _pump_drivers(w0: "GraphRunner", drivers: list, on_data, on_idle=None) -> No
     keeps commit granularity healthy: committing on every poll turns a
     fast feed into thousands of tiny commits whose per-commit overhead
     (scheduler sweep + device dispatch + decay barrier) dwarfs the row
-    work — measured 163 vs ~8000 docs/s on the RAG ingest bench. Data
-    waits at most the window; a 0-window connector (queries) pulls the
-    commit forward immediately."""
-    import time as _time
-
+    work. Data waits at most the window; a 0-window connector (queries)
+    pulls the commit forward immediately."""
     live = list(drivers)
     idle_spins = 0
     pending = False  # rows sit in input sessions awaiting a commit
@@ -1050,118 +1170,45 @@ class GraphRunner:
 
     # -- execution ----------------------------------------------------------
 
-    def run_static(self) -> Scheduler:
-        sched = Scheduler(
-            self.scope,
-            probe=(
-                self.monitor is not None
-                and getattr(self.monitor, "wants_operator_stats", True)
-            )
-            or getattr(self, "probe_stats", False),
+    def _make_scheduler(self) -> Scheduler:
+        return _adopt_scheduler(
+            self, Scheduler(self.scope, probe=_probe_wanted(self))
         )
-        self.scheduler = sched  # telemetry sampler reads stats here
-        if self.monitor is not None:
-            self.monitor.scheduler = sched
-        import time as _time
 
+    def run_static(self) -> Scheduler:
+        sched = self._make_scheduler()
         t0 = _time.monotonic()
         with _tracing.stage("commit"):
             sched.run_static()
-        if _serving.enabled():
-            _device_pipeline.drain_until(sched.time)
-            _serving.publish_on_commit([self.scope], sched.time)
-        if self.monitor is not None:
-            self._sync_monitor_connectors()
-            self.monitor.on_commit(0, t0)
+        _after_commit(sched.time, [self.scope], self.drivers, t0, w0=self)
         return sched
 
     @_tracing.traced_run
     def run(self) -> Scheduler:
         """Run to completion: static commit if no drivers, else the streaming
         loop (poll drivers, commit, until all report done)."""
-        import time as _time
-
-        from pathway_tpu.engine.graph import StaticSource
-
         if not self.drivers:
             return self.run_static()
-        sched = Scheduler(
-            self.scope,
-            probe=(
-                self.monitor is not None
-                and getattr(self.monitor, "wants_operator_stats", True)
-            )
-            or getattr(self, "probe_stats", False),
-        )
-        self.scheduler = sched  # telemetry sampler reads stats here
-        if self.monitor is not None:
-            self.monitor.scheduler = sched
-        persistent = [d for d in self.drivers if hasattr(d, "replay")]
-        for driver in persistent:
-            driver.replay()
+        sched = self._make_scheduler()
+        scopes, drivers = [self.scope], self.drivers
+        persistent = _replay(drivers)
         if persistent:
             # flush replayed events as the first commit so downstream state
             # is rebuilt even if no new input arrives
             with _tracing.stage("commit"):
                 sched.commit()
         snapshot_mgr = self._operator_snapshot_manager()
-        if snapshot_mgr is not None:
-            # operator persistence: restore state directly, no event replay;
-            # resume the clock after the snapshotted commit so sink
-            # timestamps / part names stay monotonic across restarts
-            restored_time = snapshot_mgr.restore(self.scope, self.drivers)
-            if restored_time is not None:
-                sched.time = max(sched.time, restored_time + 1)
-        for node in self.scope.nodes:
-            if isinstance(node, StaticSource):
-                batch = node.initial_batch()
-                if batch:
-                    node.push(0, batch)
-        # the static sources' commit, and the last one in finish(), are
-        # ``commit`` stages like the pump's: operators run nowhere else
-        with _tracing.stage("commit"):
-            sched.propagate(sched.time)
-        sched.time += 1
-        def on_data(commit) -> None:
-            commit_started = _time.monotonic()
-            stamp, sources = _take_ingest_stamp(self.drivers)
-            commit.add(commit_wait_ns=_commit_wait_ns(stamp, commit_started))
-            rows_before = _OUT_ROWS.value
-            ctx = _tracing.TRACER.begin(
-                sched.time, origin_mono=stamp, sources=sources
-            )
-            time = sched.commit()
-            _observe_commit_latency(stamp, commit_started, rows_before)
-            _metrics.FLIGHT.record("commit", time=time)
-            if ctx is not None:
-                _tracing.TRACER.end(time)
-            with _tracing.detail("commit.after"):
-                serving = _serving.enabled()
-                if persistent or snapshot_mgr is not None or serving:
-                    # exactly-once seam: a checkpoint/offset for commit N
-                    # may only be cut once N's staged device work has
-                    # completed (read snapshots sit on the same seam: a
-                    # published view must contain all of commit N, none
-                    # of N+1)
-                    _device_pipeline.drain_until(time)
-                for driver in persistent:
-                    driver.on_commit(time)
-                if snapshot_mgr is not None:
-                    snapshot_mgr.on_commit(self.scope, self.drivers, time)
-                if serving:
-                    _serving.publish_on_commit([self.scope], time)
-                if self.monitor is not None:
-                    self._sync_monitor_connectors()
-                    self.monitor.on_commit(time, commit_started)
+        _resume_and_commit(sched, scopes, drivers, snapshot_mgr)
 
-        _pump_drivers(self, self.drivers, on_data)
-        with _tracing.stage("commit"):
-            sched.finish()
-        _tracing.TRACER.export()
-        for driver in persistent:
-            driver.on_commit(sched.time)
-        if snapshot_mgr is not None:
-            snapshot_mgr.snapshot(self.scope, self.drivers, sched.time)
+        def on_data(commit) -> None:
+            time, started = _commit_step(commit, sched, drivers)
+            _after_commit(
+                time, scopes, drivers, started, w0=self,
+                persistent=persistent, snapshot_mgr=snapshot_mgr,
+            )
+
+        _pump_drivers(self, drivers, on_data)
+        _end_run(sched, scopes, drivers, persistent, snapshot_mgr)
         return sched
 
     def _loopback_upstream_live(self, driver, remaining) -> bool:
@@ -1264,85 +1311,36 @@ class ShardedGraphRunner:
     def _make_scheduler(self):
         from pathway_tpu.engine.sharded import ShardedScheduler
 
-        probe = (
-            self.monitor is not None
-            and getattr(self.monitor, "wants_operator_stats", True)
-        ) or getattr(self, "probe_stats", False)
-        sched = ShardedScheduler(
-            [w.scope for w in self.workers], probe=probe
+        return _adopt_scheduler(
+            self,
+            ShardedScheduler(
+                [w.scope for w in self.workers], probe=_probe_wanted(self)
+            ),
         )
-        self.scheduler = sched  # telemetry sampler reads stats here
-        return sched
 
     @_tracing.traced_run
-    def run(self, sched=None):
-        import time as _time
-
-        sched = sched or self._make_scheduler()
+    def run(self):
+        sched = self._make_scheduler()
         w0 = self.workers[0]
+        w0.monitor = self.monitor  # worker 0 counts the connectors for it
         drivers = list(w0.drivers)  # inputs read on worker 0
-        persistent = [d for d in drivers if hasattr(d, "replay")]
-        for d in persistent:
-            d.replay()
         scopes = [w.scope for w in self.workers]
+        persistent = _replay(drivers)
         snapshot_mgr = w0._operator_snapshot_manager()
-        if snapshot_mgr is not None:
-            # per-worker operator snapshots: restore every replica's state
-            # and resume the clock after the snapshotted commit
-            restored_time = snapshot_mgr.restore(scopes, drivers)
-            if restored_time is not None:
-                sched.time = max(sched.time, restored_time + 1)
-        if self.monitor is not None:
-            # aggregated cross-worker operator stats (ShardedScheduler.stats)
-            self.monitor.scheduler = sched
-        with _tracing.stage("commit"):
-            sched.commit()
+        _resume_and_commit(sched, scopes, drivers, snapshot_mgr)
 
         def on_data(commit) -> None:
-            started = _time.monotonic()
-            stamp, sources = _take_ingest_stamp(drivers)
-            commit.add(commit_wait_ns=_commit_wait_ns(stamp, started))
-            rows_before = _OUT_ROWS.value
-            ctx = _tracing.TRACER.begin(
-                sched.time, origin_mono=stamp, sources=sources
+            time, started = _commit_step(commit, sched, drivers)
+            _after_commit(
+                time, scopes, drivers, started, w0=w0,
+                persistent=persistent, snapshot_mgr=snapshot_mgr,
             )
-            time = sched.commit()
-            _observe_commit_latency(stamp, started, rows_before)
-            _metrics.FLIGHT.record("commit", time=time)
-            if ctx is not None:
-                _tracing.TRACER.end(time)
-            with _tracing.detail("commit.after"):
-                serving = _serving.enabled()
-                if persistent or snapshot_mgr is not None or serving:
-                    # exactly-once seam: checkpoint only fully-completed
-                    # commits
-                    _device_pipeline.drain_until(time)
-                for d in persistent:
-                    d.on_commit(time)
-                if snapshot_mgr is not None:
-                    snapshot_mgr.on_commit(scopes, drivers, time)
-                if serving:
-                    # one snapshot spanning every worker replica: reads
-                    # merge the key-sharded views back into the
-                    # synchronous answer
-                    _serving.publish_on_commit(scopes, time)
-                if self.monitor is not None:
-                    w0.monitor = self.monitor
-                    w0._sync_monitor_connectors()
-                    self.monitor.on_commit(time, started)
 
         _pump_drivers(w0, drivers, on_data)
-        with _tracing.stage("commit"):
-            sched.finish()
-        if not drivers and _serving.enabled():
+        _end_run(sched, scopes, drivers, persistent, snapshot_mgr)
+        if not drivers:
             # static run: the single up-front commit bypassed on_data
-            _device_pipeline.drain_until(sched.time)
-            _serving.publish_on_commit(scopes, sched.time)
-        _tracing.TRACER.export()
-        for d in persistent:
-            d.on_commit(sched.time)
-        if snapshot_mgr is not None:
-            snapshot_mgr.snapshot(scopes, drivers, sched.time)
+            _after_commit(sched.time, scopes, drivers)
         return sched
 
     def capture(self, *tables: "Table") -> list[dict[Pointer, tuple]]:
@@ -1492,16 +1490,10 @@ class DistributedGraphRunner:
                 # followers always probe: their piggybacked mesh snapshots
                 # must carry per-operator series for the leader's /metrics
                 # even though their own monitoring level is forced NONE
-                probe=(
-                    self.monitor is not None
-                    and getattr(self.monitor, "wants_operator_stats", True)
-                )
-                or getattr(self, "probe_stats", False)
-                or self.process_id != 0,
+                probe=_probe_wanted(self) or self.process_id != 0,
             )
-            self.scheduler = sched  # telemetry sampler reads stats here
+            _adopt_scheduler(self, sched)
             if self.monitor is not None:
-                self.monitor.scheduler = sched
                 # live reference: the leader's endpoint renders follower
                 # snapshots as they arrive on round frames
                 self.monitor.mesh_snapshots = sched.mesh_metrics
@@ -1678,8 +1670,6 @@ class DistributedGraphRunner:
         """Leader-side recovery: park survivors, get the dead worker
         restarted (supervisor), re-mesh, re-handshake, roll every process
         back to the restarted worker's snapshot, and resync the links."""
-        import time as _time
-
         t0 = _time.monotonic()
         self._epoch += 1
         epoch = self._epoch
@@ -1750,18 +1740,15 @@ class DistributedGraphRunner:
     # -- the two run loops --------------------------------------------------
 
     def _coordinate(self, sched, transport) -> None:
-        import time as _time
-
         from pathway_tpu.engine.distributed import (
             RECV_TIMEOUT,
             PeerLostError,
         )
 
         w0 = self.workers[0]
+        w0.monitor = self.monitor  # worker 0 counts the connectors for it
         drivers = list(w0.drivers)
-        persistent = [d for d in drivers if hasattr(d, "replay")]
-        for d in persistent:
-            d.replay()
+        persistent = _replay(drivers)
         snapshot_mgr = self._snapshot_manager()
         recovery = self._recovery_enabled(snapshot_mgr)
         fault_plan = self._fault_plan()
@@ -1852,34 +1839,29 @@ class DistributedGraphRunner:
             # uninterrupted run's numbering, breaking sink bit-identity.
             transport.broadcast(("cmd", "commit"))
             with _tracing.stage("commit"):
-                barrier_time = sched.commit_local()
-            if snapshot_mgr is not None:
-                # followers snapshot EVERY commit (including this one);
-                # the leader must too, or a worker that dies before the
-                # first data commit forces a rollback to a boundary the
-                # leader cannot restore.  Same exactly-once seam as the
-                # data path: the barrier commit flushes static sources,
-                # which can stage device work this snapshot must contain
-                _device_pipeline.drain_until(barrier_time)
-                snapshot_mgr.on_commit(sched.scopes, drivers, barrier_time)
+                barrier_time = sched.commit()
+            # followers snapshot (and publish) EVERY commit, including this
+            # one; the leader must too, or a worker that dies before the
+            # first data commit forces a rollback to a boundary the leader
+            # cannot restore.  Same exactly-once seam as the data path: the
+            # barrier commit flushes static sources, which can stage device
+            # work this snapshot must contain
+            _after_commit(
+                barrier_time, sched.scopes, drivers, snapshot_mgr=snapshot_mgr
+            )
         last_sign_of_life = _time.monotonic()
+
+        def announce() -> None:
+            transport.broadcast(("cmd", "commit"))
 
         def on_data(commit) -> None:
             nonlocal last_sign_of_life
-            started = _time.monotonic()
             try:
                 transport.raise_if_peer_dead()
-                stamp, sources = _take_ingest_stamp(drivers)
-                commit.add(commit_wait_ns=_commit_wait_ns(stamp, started))
-                rows_before = _OUT_ROWS.value
-                # begin BEFORE the broadcast: the context tuple rides the
-                # first exchange round's frames so followers adopt it at
-                # commit start
-                ctx = _tracing.TRACER.begin(
-                    sched.time, origin_mono=stamp, sources=sources
+                time, started = _commit_step(
+                    commit, sched, drivers,
+                    announce=announce, peer_spans=sched.trace_peer_spans,
                 )
-                transport.broadcast(("cmd", "commit"))
-                time = sched.commit_local()
             except PeerLostError as exc:
                 if not recovery or exc.peer is None or exc.peer == 0:
                     raise
@@ -1887,33 +1869,11 @@ class DistributedGraphRunner:
                     sched, transport, snapshot_mgr, exc.peer, drivers
                 )
                 return  # the rolled-back commit re-drives on the next poll
-            if ctx is not None:
-                _tracing.TRACER.end(
-                    time, peer_spans=dict(sched.trace_peer_spans)
-                )
-                sched.trace_peer_spans.clear()
-            _observe_commit_latency(stamp, started, rows_before)
-            with _tracing.detail("commit.after"):
-                serving = _serving.enabled()
-                if persistent or snapshot_mgr is not None or serving:
-                    # exactly-once seam: checkpoint only fully-completed
-                    # commits
-                    _device_pipeline.drain_until(time)
-                for d in persistent:
-                    d.on_commit(time)
-                if snapshot_mgr is not None:
-                    snapshot_mgr.on_commit(sched.scopes, drivers, time)
-                if serving:
-                    # leader publishes its own shard; followers publish
-                    # theirs in _follow — rollback republication truncates
-                    # stale views
-                    _serving.publish_on_commit(sched.scopes, time)
-                if fault_plan is not None:
-                    fault_plan.on_commit(self.process_id, time)
-                if self.monitor is not None:
-                    w0.monitor = self.monitor
-                    w0._sync_monitor_connectors()
-                    self.monitor.on_commit(time, started)
+            _after_commit(
+                time, sched.scopes, drivers, started, w0=w0,
+                persistent=persistent, snapshot_mgr=snapshot_mgr,
+                fault_plan=fault_plan, process_id=self.process_id,
+            )
             last_sign_of_life = started
             maybe_quiesce(time)
 
@@ -1943,14 +1903,9 @@ class DistributedGraphRunner:
                 last_sign_of_life = _time.monotonic()
 
         _pump_drivers(w0, drivers, on_data, on_idle)
-        with _tracing.stage("commit"):
-            transport.broadcast(("cmd", "finish"))
-            sched.finish_local()
-        _tracing.TRACER.export()  # leader holds the assembled mesh traces
-        for d in persistent:
-            d.on_commit(sched.time)
-        if snapshot_mgr is not None:
-            snapshot_mgr.snapshot(sched.scopes, drivers, sched.time)
+        transport.broadcast(("cmd", "finish"))
+        # the leader holds the assembled mesh traces: the export is its
+        _end_run(sched, sched.scopes, drivers, persistent, snapshot_mgr)
 
     def _follow(self, sched, transport) -> None:
         from pathway_tpu.engine.distributed import PeerLostError
@@ -1989,7 +1944,7 @@ class DistributedGraphRunner:
                 continue
             if cmd == "commit":
                 try:
-                    time = sched.commit_local()
+                    time = sched.commit()
                 except PeerLostError as exc:
                     if exc.peer == 0 or 0 in transport.dead_peers:
                         self._leader_failover(
@@ -2010,17 +1965,13 @@ class DistributedGraphRunner:
                         else:
                             raise
                     continue
-                serving = _serving.enabled()
-                if snapshot_mgr is not None or serving:
-                    # exactly-once seam (follower): a per-worker snapshot
-                    # for commit N waits for N's staged device work
-                    _device_pipeline.drain_until(time)
-                    if snapshot_mgr is not None:
-                        snapshot_mgr.on_commit(sched.scopes, [], time)
-                    if serving:
-                        _serving.publish_on_commit(sched.scopes, time)
-                if fault_plan is not None:
-                    fault_plan.on_commit(self.process_id, time)
+                _metrics.FLIGHT.record(
+                    "commit", time=time, process=self.process_id
+                )
+                _after_commit(
+                    time, sched.scopes, [], snapshot_mgr=snapshot_mgr,
+                    fault_plan=fault_plan, process_id=self.process_id,
+                )
             elif cmd == "recover":
                 # a peer died; this follower survived without noticing
                 # (or already parked — _park_for_recovery consumed the
@@ -2062,7 +2013,7 @@ class DistributedGraphRunner:
                 _metrics.FLIGHT.dump("quiesced for rescale")
                 raise SystemExit(EXIT_QUIESCED)
             elif cmd == "finish":
-                sched.finish_local()
+                sched.finish()
                 if snapshot_mgr is not None:
                     snapshot_mgr.snapshot(sched.scopes, [], sched.time)
                 return
@@ -2091,8 +2042,6 @@ class DistributedGraphRunner:
         rejected by the epoch fence (and its replaced socket).  A
         cascading survivor death during the window fail-stops on the
         election deadline."""
-        import time as _time
-
         from pathway_tpu.engine.distributed import (
             PeerLostError,
             elect_leader,
@@ -2245,7 +2194,6 @@ class DistributedGraphRunner:
         toward the restarted worker.  The subsequent rollback command is
         handled by the normal follow loop."""
         import random as _random
-        import time as _time
 
         from pathway_tpu.engine.distributed import PeerLostError
 

@@ -565,10 +565,10 @@ def test_distributed_single_process_collective(monkeypatch):
             sched.announce_topology()
             for i in range(500):
                 sessions[0].insert(ref_scalar(i), (i % 13, float(i)))
-            sched.commit_local()
+            sched.commit()
             for i in range(50, 80):
                 sessions[0].remove(ref_scalar(i), (i % 13, float(i)))
-            sched.commit_local()
+            sched.commit()
         finally:
             transport.close()
         merged = {}

@@ -291,3 +291,171 @@ def test_gradual_broadcast_threshold_moves_after_rows_sharded():
     high_uppers = sum(1 for r in merged.values() if r[-1] == 1.0)
     assert len(merged) == 20  # no rows lost on re-emit
     assert high_uppers > low_uppers
+
+
+# -- the runners agree: one sweep, one commit step ---------------------------
+
+RUNNER_WORKERS = [1, 2, 4]  # GraphRunner, ShardedGraphRunner(2), (4)
+
+
+def _streaming_wordcount(seen: list, persistent_id=None):
+    """A small groupby-and-subscribe graph over a python connector that
+    feeds two batches, the second once the sink has seen the first."""
+    import threading
+
+    from pathway_tpu.internals.parse_graph import G
+
+    G.clear()
+    first_seen = threading.Event()
+
+    class Words(pw.io.python.ConnectorSubject):
+        def run(self) -> None:
+            for w in ("a", "b", "a"):
+                self.next(word=w)
+            first_seen.wait(10)
+            for w in ("b", "c"):
+                self.next(word=w)
+
+    words = pw.io.python.read(
+        Words(), schema=pw.schema_from_types(word=str),
+        autocommit_duration_ms=5, persistent_id=persistent_id,
+    )
+    counts = words.groupby(words.word).reduce(
+        word=words.word, n=pw.reducers.count()
+    )
+
+    def on_change(key, row, time, is_addition) -> None:
+        seen.append((row["word"], row["n"], is_addition))
+        first_seen.set()
+
+    pw.io.subscribe(counts, on_change=on_change)
+
+
+def _runner_with_sinks(n_workers: int, persistence_config=None):
+    """What ``pw.run`` builds for ``threads=n_workers``."""
+    from pathway_tpu.internals.parse_graph import G
+
+    if n_workers > 1:
+        runner = ShardedGraphRunner(
+            n_workers, persistence_config=persistence_config
+        )
+        runner.attach_sinks()
+        return runner
+    runner = GraphRunner(persistence_config=persistence_config)
+    for sink in G.sinks:
+        driver = sink.attach(runner.scope, runner.build(sink.table))
+        if driver is not None:
+            runner.drivers.append(driver)
+    return runner
+
+
+@pytest.mark.parametrize("n_workers", RUNNER_WORKERS)
+def test_every_runner_records_operator_stages_in_a_sampled_commit(n_workers):
+    from pathway_tpu.internals import tracing
+    from pathway_tpu.internals.parse_graph import G
+
+    seen: list = []
+    _streaming_wordcount(seen)
+    tracing.TRACER.configure(enabled=True, sample=1, clear=True)
+    try:
+        _runner_with_sinks(n_workers).run()
+        traces = tracing.TRACER.traces()
+    finally:
+        tracing.TRACER.drop()
+        tracing.TRACER.configure(enabled=False, clear=True)
+        G.clear()
+    assert ("a", 2, True) in seen and ("c", 1, True) in seen
+    stages = tracing.stage_totals()["stages"]
+    assert {"op.GroupbyNode", "op.SubscribeNode"} <= set(stages)
+    assert stages["op.GroupbyNode"]["counts"]["batches"] >= 1
+    spans = [
+        s for t in traces for s in t["spans"] if s["name"].startswith("op.")
+    ]
+    cats = {s["name"]: s["cat"] for s in spans}
+    assert cats["op.GroupbyNode"] == "op"
+    assert cats["op.SubscribeNode"] == "sink"
+    # what the sharded and mesh sweeps' hand-written spans carried rides
+    # the stage: the node, its replica, its name
+    assert {s["args"]["shard"] for s in spans} <= set(range(n_workers))
+    assert all("node" in s["args"] and "label" in s["args"] for s in spans)
+    if n_workers > 1:
+        groupby_shards = {
+            s["args"]["shard"] for s in spans if s["name"] == "op.GroupbyNode"
+        }
+        assert len(groupby_shards) > 1
+
+
+@pytest.mark.parametrize("n_workers", RUNNER_WORKERS)
+def test_every_runner_calls_the_commit_hooks_in_one_order(
+    n_workers, monkeypatch, tmp_path
+):
+    """drain_until -> the journal's on_commit -> the snapshot manager's
+    -> the monitor's, each commit, all with that commit's time."""
+    import types
+
+    from pathway_tpu.engine import device_pipeline
+    from pathway_tpu.engine.persistence import PersistentDriver
+    from pathway_tpu.internals.parse_graph import G
+    from pathway_tpu.persistence import Backend, Config
+
+    calls: list = []
+    real_drain = device_pipeline.drain_until
+    real_journal = PersistentDriver.on_commit
+
+    def drain_until(time):
+        calls.append(("drain_until", time))
+        return real_drain(time)
+
+    def journal_on_commit(self, time):
+        calls.append(("journal", time))
+        return real_journal(self, time)
+
+    class Snapshots:
+        def restore(self, scopes, drivers):
+            return None
+
+        def on_commit(self, scopes, drivers, time):
+            calls.append(("snapshot", time))
+
+        def snapshot(self, scopes, drivers, time):
+            calls.append(("final_snapshot", time))
+
+    class Monitor:
+        scheduler = None
+
+        def connector(self, name):
+            return types.SimpleNamespace()
+
+        def on_commit(self, time, started):
+            calls.append(("monitor", time))
+
+    monkeypatch.setattr(device_pipeline, "drain_until", drain_until)
+    monkeypatch.setattr(PersistentDriver, "on_commit", journal_on_commit)
+    monkeypatch.setattr(
+        GraphRunner, "_operator_snapshot_manager", lambda self: Snapshots()
+    )
+    seen: list = []
+    _streaming_wordcount(seen, persistent_id="words")
+    try:
+        runner = _runner_with_sinks(
+            n_workers, Config(Backend.filesystem(tmp_path / "journal"))
+        )
+        runner.monitor = Monitor()
+        sched = runner.run()
+    finally:
+        G.clear()
+    assert ("c", 1, True) in seen
+    # the run's last offsets and its final snapshot come after the hooks
+    assert calls[-2:] == [
+        ("journal", sched.time), ("final_snapshot", sched.time)
+    ]
+    hooks = calls[:-2]
+    order = ["drain_until", "journal", "snapshot", "monitor"]
+    assert len(hooks) >= 2 * len(order) and len(hooks) % len(order) == 0
+    times = []
+    for i in range(0, len(hooks), len(order)):
+        group = hooks[i:i + len(order)]
+        assert [name for name, _ in group] == order, hooks
+        assert len({time for _, time in group}) == 1, group
+        times.append(group[0][1])
+    assert times == sorted(set(times)) and times[-1] < sched.time
