@@ -168,6 +168,13 @@ class Reader:
     #: one (file re-read) — the driver then retracts the old rows first.
     replaces_sources = False
 
+    #: ``time.monotonic`` arrival of the oldest entry the last ``poll()``
+    #: returned, for a reader that sees its entries arrive (QueueReader:
+    #: the feed's thread stamps each push). None — files, object stores,
+    #: the wire clients — means "unknown": the driver then counts a row's
+    #: autocommit window from the poll that found it.
+    polled_arrival: float | None = None
+
     def poll(self) -> tuple[list[tuple[Any, str, dict]], bool]:
         """Returns (entries, done)."""
         raise NotImplementedError
@@ -262,18 +269,22 @@ class QueueReader(Reader):
         self.closed = False
 
     def push(self, payload: Any, source_id: str = "q", metadata: dict | None = None) -> None:
-        self.queue.put((payload, source_id, metadata or {}))
+        # stamped on the feed's thread: the pump may be inside a commit
+        self.queue.put((_time.monotonic(), (payload, source_id, metadata or {})))
 
     def close(self) -> None:
         self.closed = True
 
     def poll(self) -> tuple[list[tuple[Any, str, dict]], bool]:
-        entries = []
+        stamped = []
         while True:
             try:
-                entries.append(self.queue.get_nowait())
+                stamped.append(self.queue.get_nowait())
             except queue.Empty:
                 break
+        # FIFO: the first is the oldest
+        self.polled_arrival = stamped[0][0] if stamped else None
+        entries = [entry for _arrived, entry in stamped]
         return entries, self.closed and self.queue.empty()
 
 
@@ -300,8 +311,9 @@ class InputDriver:
         self.parser = parser
         self.pk = list(primary_key_indices) if primary_key_indices else None
         self.source_name = source_name
-        #: max seconds this connector's rows may wait before a commit
-        #: (the pump loop batches accordingly); 0 commits on every poll
+        #: max seconds this connector's rows may wait, from their arrival,
+        #: before a commit (the pump loop batches accordingly); 0 commits
+        #: on every poll
         self.autocommit_s = (autocommit_duration_ms or 0) / 1000.0
         self.append_metadata = append_metadata
         self._per_source_rows: dict[str, list[tuple[Pointer, tuple]]] = {}
@@ -311,10 +323,16 @@ class InputDriver:
         self.entries_total = 0
         self.batches_total = 0
         self.last_entry_wall: float | None = None
-        #: wall stamp of the oldest row fed to the session and not yet
-        #: committed; the runner pops it per commit to observe the
+        #: arrival (``time.monotonic``) of the oldest row fed to the session
+        #: and not yet committed: when its reader saw it arrive, or the poll
+        #: that found it where the reader cannot say. The pump counts the
+        #: autocommit window from it; the runner pops it per commit for the
+        #: stage's ``commit_wait_ns``, the ingest-wait span and the
         #: ingest->sink latency histogram
         self.first_pending_wall: float | None = None
+        #: when the poll that set ``first_pending_wall`` ran: the two differ
+        #: by the time the row queued while the pump was away
+        self.first_pending_polled: float | None = None
         self._m_entries = _metrics.REGISTRY.counter(
             "pathway_connector_entries_total",
             "entries ingested per connector",
@@ -448,6 +466,7 @@ class InputDriver:
         if self.done:
             return "done"
         produced = False
+        took = False  # an entry of this poll reached the session
         if self._sync_backlog:
             produced = self._drain_backlog()
         if self._done_pending:
@@ -470,7 +489,7 @@ class InputDriver:
             if old_rows:
                 for key, row in old_rows:
                     self.session.remove(key, row)
-                produced = True
+                produced = took = True
             if replaces and self._sync_backlog:
                 # held-back events of the replaced source version must not
                 # surface later: they were superseded before emission
@@ -501,7 +520,7 @@ class InputDriver:
                 track = new_rows if (event.kind == INSERT and replaces) else None
                 if self._sync_admit(values):
                     self._feed(event.kind, key, values, track)
-                    produced = True
+                    produced = took = True
                 else:
                     self._sync_backlog.append(
                         (event.kind, key, values, track, source_id)
@@ -511,7 +530,14 @@ class InputDriver:
                 self._per_source_rows[source_id] = new_rows
         self._note_pending()
         if produced and self.first_pending_wall is None:
-            self.first_pending_wall = _time.monotonic()
+            # events a synchronization group held back count from the poll
+            # that released them, like the entries of a reader with no stamp
+            polled = _time.monotonic()
+            arrived = (
+                getattr(self.reader, "polled_arrival", None) if took else None
+            )
+            self.first_pending_wall = polled if arrived is None else arrived
+            self.first_pending_polled = polled
         if done:
             if self._sync_backlog:
                 # the group still holds events back; report idle until the

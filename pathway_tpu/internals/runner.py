@@ -69,13 +69,15 @@ _OUT_ROWS = _metrics.REGISTRY.counter("pathway_output_rows_total")
 
 def _take_ingest_stamp(
     drivers: list,
-) -> tuple[float | None, list[str]]:
-    """Pop the oldest pending-row wall stamp across connector drivers
-    (InputDriver.poll sets it when rows enter a session); the commit that
-    follows delivers those rows, closing the latency window.  Also
-    returns the source names whose stamps were popped — the tracing
-    ingest-wait span labels itself with them."""
-    best = None
+) -> tuple[float | None, float | None, list[str]]:
+    """Pop the oldest pending-row arrival stamp across connector drivers
+    (InputDriver.poll sets it when rows enter a session: the row's arrival
+    at its reader, or that poll's time where the reader cannot say); the
+    commit that follows delivers those rows, closing the latency window.
+    Also returns when the poll that took that oldest row ran, and the
+    source names whose stamps were popped — the tracing ingest-wait span
+    labels itself with them."""
+    best = polled = None
     sources: list[str] = []
     for d in drivers:
         inner = getattr(d, "driver", d)
@@ -87,15 +89,16 @@ def _take_ingest_stamp(
                 sources.append(str(name))
             if best is None or stamp < best:
                 best = stamp
-    return best, sources
+                polled = getattr(inner, "first_pending_polled", None)
+    return best, polled, sources
 
 
-def _commit_wait_ns(stamp: float | None, commit_started: float) -> int:
-    """How long the oldest row of this commit sat in its session before
-    the commit began (both ``time.monotonic`` stamps); 0 with no row."""
-    if stamp is None:
+def _elapsed_ns(since: float | None, until: float | None) -> int:
+    """Nanoseconds between two ``time.monotonic`` stamps; 0 where either
+    is missing (a commit with no row) or they run backwards."""
+    if since is None or until is None:
         return 0
-    return max(0, int((commit_started - stamp) * 1e9))
+    return max(0, int((until - since) * 1e9))
 
 
 def _observe_commit_latency(
@@ -161,15 +164,22 @@ def _commit_step(
 ) -> tuple[int, float]:
     """One data commit of any runner, inside the ``commit`` stage the pump
     hands over. The four recorders every commit pays stand side by side
-    here: the stage's ``commit_wait_ns``, the sampled trace, the latency
-    histogram and the flight ring. The mesh leader passes what only it
+    here: the stage's counts, the sampled trace, the latency histogram
+    and the flight ring. The counts are of the commit's oldest row, from
+    its arrival: ``commit_wait_ns`` until the commit began, and
+    ``arrival_to_poll_ns`` until the poll that took it, which is the part
+    of its autocommit window the pump did not wait again (0 for a row
+    polled as it arrived). The mesh leader passes what only it
     has: ``announce`` runs between the trace's begin and the commit (the
     context tuple rides the first exchange round's frames, so followers
     adopt it at commit start) and ``peer_spans`` is where the followers'
     spans arrive. Returns the commit's time and when it started."""
     started = _time.monotonic()
-    stamp, sources = _take_ingest_stamp(drivers)
-    commit.add(commit_wait_ns=_commit_wait_ns(stamp, started))
+    stamp, polled, sources = _take_ingest_stamp(drivers)
+    commit.add(
+        commit_wait_ns=_elapsed_ns(stamp, started),
+        arrival_to_poll_ns=_elapsed_ns(stamp, polled),
+    )
     rows_before = _OUT_ROWS.value
     ctx = _tracing.TRACER.begin(
         sched.time, origin_mono=stamp, sources=sources
@@ -258,8 +268,15 @@ def _pump_drivers(w0: "GraphRunner", drivers: list, on_data, on_idle=None) -> No
     keeps commit granularity healthy: committing on every poll turns a
     fast feed into thousands of tiny commits whose per-commit overhead
     (scheduler sweep + device dispatch + decay barrier) dwarfs the row
-    work. Data waits at most the window; a 0-window connector (queries)
-    pulls the commit forward immediately."""
+    work. Data waits at most the window **from its arrival**: a
+    connector's deadline is ``first_pending_wall`` (the arrival of its
+    oldest uncommitted row, as its reader saw it; the poll's own time
+    where the reader cannot say) plus its window, so the time a row
+    queued while this loop was inside a commit counts against its window
+    and is not waited a second time. A deadline already past at the poll
+    starts the commit after that same sweep, which has drained the feed:
+    never before the rows are in their sessions. A 0-window connector
+    (queries) pulls the commit forward immediately."""
     live = list(drivers)
     idle_spins = 0
     pending = False  # rows sit in input sessions awaiting a commit
@@ -294,9 +311,14 @@ def _pump_drivers(w0: "GraphRunner", drivers: list, on_data, on_idle=None) -> No
             elif status == "data":
                 produced = True
                 eff = getattr(d, "effective_autocommit_s", None)
-                ac_deadline = _time.monotonic() + (
-                    eff() if eff is not None else getattr(d, "autocommit_s", 0.0)
+                # the window runs from the arrival of the driver's oldest
+                # uncommitted row; a driver that keeps no stamp: from now
+                arrived = getattr(
+                    getattr(d, "driver", d), "first_pending_wall", None
                 )
+                ac_deadline = (
+                    _time.monotonic() if arrived is None else arrived
+                ) + (eff() if eff is not None else getattr(d, "autocommit_s", 0.0))
                 deadline = min(deadline, ac_deadline) if pending else ac_deadline
                 pending = True
         if pending and (flush_now or _time.monotonic() >= deadline):

@@ -498,7 +498,8 @@ class TraceRecorder:
         """Leader/local-side sampling decision at commit start.
 
         ``origin_mono`` is the connector ingest stamp
-        (``InputDriver.first_pending_wall``, a ``time.monotonic`` value)
+        (``InputDriver.first_pending_wall``, a ``time.monotonic`` value:
+        the arrival of the commit's oldest row at its reader)
         popped by the runner — the trace's time zero.  Returns the
         active context when this commit is sampled, else ``None``."""
         if not self.enabled:

@@ -93,6 +93,16 @@ def read(
     persistent_id: str | None = None,
     **kwargs: Any,
 ) -> Table:
+    """A table fed by ``subject`` (reference: pw.io.python.read).
+
+    ``autocommit_duration_ms`` is the maximum time between two commits, as
+    upstream documents it, counted **from a row's arrival**: ``next()``
+    stamps the row on the subject's thread, and the run loop starts the
+    commit at that stamp plus the window, or as soon after as it is free
+    (while the loop is inside an earlier commit the row's window keeps
+    running; the poll that finds the row does not start it over). Rows
+    that arrive inside one window share a commit. ``None`` or 0 commits
+    at the poll that finds a row."""
     dtypes = schema.dtypes()
 
     started = False

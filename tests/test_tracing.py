@@ -720,7 +720,7 @@ class TestTraceSurvivesRecovery:
 RAG_STAGES = {
     "pump.poll": ("rows",),
     "pump.sleep": (),
-    "commit": ("commit_wait_ns",),
+    "commit": ("commit_wait_ns", "arrival_to_poll_ns"),
     "op.BatchApplyNode": ("batches",),
     "op.ExternalIndexNode": ("batches",),
     "op.SubscribeNode": ("batches",),
