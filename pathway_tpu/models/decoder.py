@@ -7,8 +7,12 @@ the attention every layer uses and, layer by layer, the feed-forward kind
 (``layer_pattern``):
 
 - attention ``"gqa"``: grouped-query heads over RoPE (the Mistral-style
-  layer); the layer's cache holds keys and values, ``[b, max_len, kv_heads,
-  head_dim]`` each.
+  layer); the layer's cache holds keys and values, ``[b, slots, kv_heads,
+  head_dim]`` each. With ``layer_types`` each layer is one of two kinds of
+  it: ``"sliding_attention"`` (RoPE; key ``j`` is seen by query ``i`` iff
+  ``0 <= i - j < sliding_window``; the cache is a ring of ``min(window,
+  max_len)`` slots, position ``p`` in slot ``p mod slots``) and
+  ``"full_attention"`` (no positions at all, causal, ``max_len`` slots).
 - attention ``"mla"``: latent attention. Keys and values are expanded from
   one compressed row a token, and **the layer's cache holds that row and the
   rotated shared key only** (``kv_lora_rank + qk_rope_head_dim`` values a
@@ -18,7 +22,15 @@ the attention every layer uses and, layer by layer, the feed-forward kind
 - feed-forward ``"dense"``: a gated SiLU MLP; ``"experts"``: a float32
   router over all routed experts, the ``experts_per_token`` largest, a
   grouped product over the experts that were chosen (``ops/moe.py``; no
-  capacity, no token dropped) plus the shared experts as one gated MLP.
+  capacity, no token dropped) plus the shared experts as one gated MLP,
+  summed or averaged. ``router`` ``"sigmoid"`` scores each expert by itself.
+  With ``held_experts`` the layer holds a share of its experts: it routes
+  over all of them and computes the part of the result its own give.
+
+``norm`` ``"layer"`` is a mean-subtracting LayerNorm (weight only);
+``parallel_block`` reads one norm a layer for attention and feed-forward
+both and adds both at once; ``tie_embeddings`` takes the embedding for the
+head, times ``logit_scale``.
 
 Each layer is handed its own cache state; ``init_cache`` makes the list.
 ``prefill`` and ``decode_step`` compute the head at one position a row (the
@@ -92,10 +104,28 @@ class DecoderConfig:
     first_dense_layers: int = 0
     norm_topk_prob: bool = False
     routed_scaling_factor: float = 1.0
+    router: str = "softmax"  # "softmax" | "sigmoid"
+    shared_combine: str = "sum"  # "sum" | "average"
+    #: (first, count) of the routed experts this chip holds (none: all)
+    held_experts: tuple[int, int] | None = None
+    # -- the block
+    head_size: int = 0  # a head's width where it is not hidden / heads
+    #: each layer's kind of "gqa" attention, "sliding_attention" | "full_attention"
+    layer_types: tuple[str, ...] | None = None
+    sliding_window: int = 0
+    norm: str = "rms"  # "rms" | "layer"
+    parallel_block: bool = False
+    rope_interleaved: bool = False
+    tie_embeddings: bool = False
+    logit_scale: float = 1.0
 
     @property
     def head_dim(self) -> int:
-        return self.hidden // self.heads
+        return self.head_size or self.hidden // self.heads
+
+    @property
+    def experts_held(self) -> int:
+        return self.held_experts[1] if self.held_experts else self.n_routed_experts
 
     @property
     def layer_pattern(self) -> tuple[str, ...]:
@@ -104,6 +134,15 @@ class DecoderConfig:
             return ("dense",) * self.layers
         dense = min(self.first_dense_layers, self.layers)
         return ("dense",) * dense + ("experts",) * (self.layers - dense)
+
+    @property
+    def attention_pattern(self) -> tuple[str, ...]:
+        """The attention kind of each layer: ``attention`` for every layer,
+        or with ``layer_types`` ``"sliding"`` or ``"full"`` (both ``"gqa"``
+        heads)."""
+        if self.layer_types is None:
+            return (self.attention,) * self.layers
+        return tuple(kind.removesuffix("_attention") for kind in self.layer_types)
 
     @property
     def cache_width(self) -> int:
@@ -124,7 +163,10 @@ class DecoderConfig:
     def from_hf(cls, hf: dict, **overrides: Any) -> "DecoderConfig":
         """From the keys of a published ``config.json`` (``model_type``
         ``deepseek_v2``: latent attention without a query projection rank,
-        greedy softmax routing in one group; ``mistral`` / ``llama``)."""
+        greedy softmax routing in one group; ``cohere2_moe``: windowed beside
+        full grouped-query attention in a parallel block, sigmoid routing,
+        averaged shared experts, and under ``held_here`` the share of the
+        experts this chip holds; ``mistral`` / ``llama``)."""
         kind = hf.get("model_type", "mistral")
         common = dict(
             vocab_size=hf["vocab_size"],
@@ -135,17 +177,56 @@ class DecoderConfig:
             intermediate=hf["intermediate_size"],
             max_len=hf.get("max_position_embeddings", 8192),
             rope_theta=float(hf.get("rope_theta", 10000.0)),
-            rms_eps=hf.get("rms_norm_eps", 1e-5),
+            rms_eps=hf.get("rms_norm_eps") or hf.get("layer_norm_eps", 1e-5),
         )
-        if kind == "deepseek_v2":
-            required = {
+
+        def require(required: dict) -> None:
+            for key, only in required.items():
+                if hf.get(key, only) != only:
+                    raise ValueError(f"{kind} with {key}={hf[key]!r}: only {only!r} is implemented")
+
+        if kind == "cohere2_moe":
+            require({
+                "use_qk_norm": False, "use_parallel_block": True, "first_k_dense_replace": 0,
+                "use_gated_activation": True, "shared_expert_combination_strategy": "average",
+                "position_embedding_type": "rope_gptj", "order_of_interleaved_layers": "local_attn_first",
+                "expert_selection_fn": "sigmoid", "hidden_act": "silu", "attention_bias": False,
+                "rotary_pct": 1, "tie_word_embeddings": True,
+            })
+            layer_types = tuple(hf["layer_types"][: hf["num_hidden_layers"]])
+            unknown = set(layer_types) - {"sliding_attention", "full_attention"}
+            if unknown or len(layer_types) != hf["num_hidden_layers"]:
+                raise ValueError(f"cohere2_moe with layer_types={hf['layer_types']!r}: a kind a layer, sliding or full")
+            # the chip's share of a deployment: num_experts counts the experts
+            # held here, held_here says which they are and of how many
+            share = hf.get("held_here")
+            held = tuple(share["experts"]) if share else None
+            if held is not None and held[1] != hf["num_experts"]:
+                raise ValueError(f"held_here.experts={share['experts']!r} beside num_experts={hf['num_experts']!r}")
+            common.update(
+                head_size=hf["head_dim"],
+                layer_types=layer_types,
+                sliding_window=hf["sliding_window"],
+                norm="layer",
+                parallel_block=True,
+                rope_interleaved=True,
+                tie_embeddings=True,
+                logit_scale=float(hf.get("logit_scale", 1.0)),
+                n_routed_experts=share["of_experts"] if share else hf["num_experts"],
+                held_experts=held,
+                experts_per_token=hf["num_experts_per_tok"],
+                n_shared_experts=hf["num_shared_experts"],
+                moe_intermediate=hf["intermediate_size"],  # one expert's width: the config has no key of its own
+                norm_topk_prob=hf.get("norm_topk_prob", False),
+                router="sigmoid",
+                shared_combine="average",
+            )
+        elif kind == "deepseek_v2":
+            require({
                 "q_lora_rank": None, "hidden_act": "silu", "scoring_func": "softmax",
                 "topk_method": "greedy", "n_group": 1, "moe_layer_freq": 1,
                 "attention_bias": False, "tie_word_embeddings": False,
-            }
-            for key, only in required.items():
-                if hf.get(key, only) != only:
-                    raise ValueError(f"deepseek_v2 with {key}={hf[key]!r}: only {only!r} is implemented")
+            })
             scaling = hf.get("rope_scaling")
             if scaling is not None and scaling.get("type") != "yarn":
                 raise ValueError(f"rope_scaling of type {scaling.get('type')!r}: only 'yarn' is implemented")
@@ -243,11 +324,11 @@ def _layer_shapes(cfg: DecoderConfig, kind: str) -> dict[str, tuple]:
         shapes.update(gate_w=(h, 2 * cfg.intermediate), down_w=(cfg.intermediate, h))
     else:
         e, w = cfg.n_routed_experts, cfg.moe_intermediate
-        shared = cfg.n_shared_experts * w
+        held, shared = cfg.experts_held, cfg.n_shared_experts * w
         shapes.update(
             router_w=(h, e),
-            experts_gate_w=(e, h, 2 * w),
-            experts_down_w=(e, w, h),
+            experts_gate_w=(held, h, 2 * w),
+            experts_down_w=(held, w, h),
             shared_gate_w=(h, 2 * shared),
             shared_down_w=(shared, h),
         )
@@ -277,13 +358,16 @@ def init_decoder_params(
             )
         ).astype(dtype),
         "final_norm": jnp.ones((cfg.hidden,), jnp.float32),
-        "lm_head": dense(next(keys), (cfg.hidden, cfg.vocab_size)),
         "layers": [],
     }
+    head_key = next(keys)
+    if not cfg.tie_embeddings:
+        p["lm_head"] = dense(head_key, (cfg.hidden, cfg.vocab_size))
     for kind in pattern:
         lp = {name: dense(next(keys), shape) for name, shape in _layer_shapes(cfg, kind).items()}
         lp["attn_norm"] = jnp.ones((cfg.hidden,), jnp.float32)
-        lp["mlp_norm"] = jnp.ones((cfg.hidden,), jnp.float32)
+        if not cfg.parallel_block:
+            lp["mlp_norm"] = jnp.ones((cfg.hidden,), jnp.float32)
         if cfg.attention == "mla":
             lp["kv_norm"] = jnp.ones((cfg.kv_lora_rank,), jnp.float32)
         p["layers"].append(lp)
@@ -312,6 +396,18 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     x32 = x.astype(jnp.float32)
     out = x32 * lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
     return (out * scale).astype(x.dtype)
+
+
+def layer_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """Mean-subtracting LayerNorm in float32, weight only."""
+    x32 = x.astype(jnp.float32)
+    centred = x32 - x32.mean(-1, keepdims=True)
+    out = centred * lax.rsqrt((centred * centred).mean(-1, keepdims=True) + eps)
+    return (out * scale).astype(x.dtype)
+
+
+def _norm(x: jax.Array, scale: jax.Array, cfg: DecoderConfig) -> jax.Array:
+    return (layer_norm if cfg.norm == "layer" else rms_norm)(x, scale, cfg.rms_eps)
 
 
 def rope_frequencies(dim: int, theta: float, yarn: YarnScaling | None = None) -> np.ndarray:
@@ -343,16 +439,22 @@ def _rope_table_scale(cfg: DecoderConfig) -> float:
 
 def rope(
     x: jax.Array, positions: jax.Array, theta: float,
-    yarn: YarnScaling | None = None, table_scale: float = 1.0,
+    yarn: YarnScaling | None = None, table_scale: float = 1.0, interleaved: bool = False,
 ) -> jax.Array:
-    """Rotary embedding in the half-split layout (``x1 | x2``): x
-    ``[b, t, h, d]``, positions ``[b, t]``."""
+    """Rotary embedding of x ``[b, t, h, d]`` at positions ``[b, t]``: pair
+    ``i`` is ``(x[i], x[i + d/2])`` in the half-split layout (``x1 | x2``),
+    ``(x[2i], x[2i + 1])`` in the ``interleaved`` one."""
     freqs = jnp.asarray(rope_frequencies(x.shape[-1], theta, yarn))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [b, t, d/2]
     cos = (jnp.cos(angles) * table_scale)[:, :, None, :]
     sin = (jnp.sin(angles) * table_scale)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    x32 = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = x32[..., 0::2], x32[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).reshape(x.shape)
+    else:
+        x1, x2 = jnp.split(x32, 2, axis=-1)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
 
 
@@ -363,20 +465,25 @@ def _gated_mlp(h: jax.Array, gate_w: jax.Array, down_w: jax.Array) -> jax.Array:
 
 def _experts_layer(h: jax.Array, lp: Params, cfg: DecoderConfig, counted: jax.Array | None):
     """Routed plus shared experts over ``h`` ``[b, t, hidden]``; also how
-    many of the ``counted`` ``[b, t]`` tokens' choices each expert took
-    (``None``: every token's) and how many experts took any. The other
-    tokens are padding and go through the shared experts alone."""
+    many of the ``counted`` ``[b, t]`` tokens' choices each expert held here
+    took (``None``: every token's) and how many of them took any. The other
+    tokens are padding and go through the shared experts alone. The shared
+    experts are one gated MLP as wide as all of them, which is their sum; an
+    average is that over their number."""
     b, t, hidden = h.shape
     flat = h.reshape(b * t, hidden)
     weights, experts = route_top_k(
         flat, lp["router_w"], cfg.experts_per_token,
-        renormalize=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
+        renormalize=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor, scoring=cfg.router,
     )
     y, load = routed_experts(
         flat, weights, experts, lp["experts_gate_w"], lp["experts_down_w"],
-        None if counted is None else counted.reshape(-1),
+        None if counted is None else counted.reshape(-1), cfg.held_experts,
     )
-    y = y + _gated_mlp(flat, lp["shared_gate_w"], lp["shared_down_w"])
+    shared = _gated_mlp(flat, lp["shared_gate_w"], lp["shared_down_w"])
+    if cfg.shared_combine == "average":
+        shared = shared / cfg.n_shared_experts
+    y = y + shared
     return y.reshape(b, t, hidden), load, jnp.count_nonzero(load).astype(jnp.int32)
 
 
@@ -384,28 +491,31 @@ def _experts_layer(h: jax.Array, lp: Params, cfg: DecoderConfig, counted: jax.Ar
 
 
 class Cache(NamedTuple):
-    """Static-shape cache, one state a layer: ``{"k", "v"}`` ``[b, max_len,
-    kv_heads, head_dim]`` for a ``"gqa"`` layer, ``{"latent"}`` ``[b,
-    max_len, kv_lora_rank + qk_rope_head_dim]`` for an ``"mla"`` layer.
+    """Static-shape cache, one state a layer: ``{"k", "v"}`` ``[b, slots,
+    kv_heads, head_dim]`` for a ``"gqa"`` layer (``max_len`` slots; a
+    ``"sliding"`` layer ``min(sliding_window, max_len)``, as a ring: position
+    ``p`` lies in slot ``p mod slots``), ``{"latent"}`` ``[b, max_len,
+    kv_lora_rank + qk_rope_head_dim]`` for an ``"mla"`` layer.
 
-    ``valid`` marks usable slots: left-pad positions of shorter prompts in a
+    ``valid`` marks usable positions: left-pad positions of shorter prompts in a
     batch stay False forever, so generated tokens never attend to pads.
     """
 
     layers: list
     length: jax.Array  # [] int32 — filled prefix
-    valid: jax.Array  # [b, max_len] bool — non-pad filled slots
+    valid: jax.Array  # [b, max_len] bool — non-pad filled positions
 
 
 def init_cache(cfg: DecoderConfig, batch: int, max_len: int) -> Cache:
-    def state() -> dict:
-        if cfg.attention == "mla":
+    def state(kind: str) -> dict:
+        if kind == "mla":
             return {"latent": jnp.zeros((batch, max_len, cfg.cache_width), cfg.dtype)}
-        shape = (batch, max_len, cfg.kv_heads, cfg.head_dim)
+        slots = min(cfg.sliding_window, max_len) if kind == "sliding" else max_len
+        shape = (batch, slots, cfg.kv_heads, cfg.head_dim)
         return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
     return Cache(
-        layers=[state() for _ in range(cfg.layers)],
+        layers=[state(kind) for kind in cfg.attention_pattern],
         length=jnp.zeros((), jnp.int32),
         valid=jnp.zeros((batch, max_len), bool),
     )
@@ -418,13 +528,42 @@ def _write(buffer: jax.Array, chunk: jax.Array, start: jax.Array) -> jax.Array:
     return lax.dynamic_update_slice(buffer, chunk.astype(buffer.dtype), at)
 
 
+def _ring_write(buffer: jax.Array, chunk: jax.Array, start: jax.Array, wraps: bool) -> jax.Array:
+    """``chunk`` ``[b, t, ...]``, which holds positions ``[start, start + t)``,
+    into the ring ``buffer`` ``[b, slots, ...]``: position ``p`` into slot ``p
+    mod slots``; of a chunk longer than the ring its last ``slots`` positions.
+    A ring that never ``wraps`` (as long as the positions there are) is
+    written as any buffer is."""
+    slots, t = buffer.shape[1], chunk.shape[1]
+    if not wraps:
+        return _write(buffer, chunk, start)
+    if t == 1:
+        return _write(buffer, chunk, start % slots)
+    kept = min(t, slots)
+    at = (start + (t - kept) + jnp.arange(kept, dtype=jnp.int32)) % slots
+    return buffer.at[:, at].set(chunk[:, t - kept :].astype(buffer.dtype))
+
+
+def _ring_positions(slots: int, last: jax.Array) -> jax.Array:
+    """The position each slot of a ring holds once position ``last`` is
+    written: the newest ``p <= last`` with ``p mod slots`` its slot; negative
+    where nothing was written yet."""
+    slot = jnp.arange(slots, dtype=jnp.int32)
+    return last - (last - slot) % slots
+
+
 # -- attention ----------------------------------------------------------------
 
 
-def _mask(q_slot: jax.Array, k_valid: jax.Array) -> jax.Array:
-    """``[b, t, s]``: causal by slot, and only slots that hold a real token."""
-    k_slot = jnp.arange(k_valid.shape[1])
-    return (q_slot[:, :, None] >= k_slot[None, None, :]) & k_valid[:, None, :]
+def _mask(q_slot: jax.Array, k_valid: jax.Array, k_slot: jax.Array | None = None, window: int = 0) -> jax.Array:
+    """``[b, t, s]``: causal by slot, only slots that hold a real token, and
+    of a ``window`` the keys under it back. ``k_slot`` ``[s]`` where the keys
+    do not lie in slot order (a ring)."""
+    if k_slot is None:
+        k_slot = jnp.arange(k_valid.shape[1])
+    back = q_slot[:, :, None] - k_slot[None, None, :]
+    seen = (back >= 0) & k_valid[:, None, :]
+    return seen & (back < window) if window else seen
 
 
 def _softmax(scores: jax.Array, mask: jax.Array, dtype: Any) -> jax.Array:
@@ -434,24 +573,66 @@ def _softmax(scores: jax.Array, mask: jax.Array, dtype: Any) -> jax.Array:
     return jax.nn.softmax(jnp.where(mask, scores.astype(jnp.float32), -1e30), axis=-1).astype(dtype)
 
 
-def _gqa_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only):
+#: grouped-query attention walks its queries in blocks whose scores (a batch's,
+#: every head's, against every key) number at most this
+ATTENTION_BLOCK_SCORES = 1 << 27
+
+
+def _grouped_query(q, k, v, q_slot, k_valid, k_slot, window, cfg):
+    """Softmax attention of ``q`` ``[b, t, heads, d]`` over ``k``, ``v`` ``[b, s,
+    kv_heads, d]``, ``heads / kv_heads`` query heads a key head: ``[b, t, heads
+    * d]``. The queries are walked in blocks, so that the scores held at
+    once are a block's."""
+    b, t, heads, d = q.shape
+    s, kv = k.shape[1], k.shape[2]
+
+    def block(args):
+        q, q_slot = args
+        qg = q.reshape(b, -1, kv, heads // kv, d)
+        scores = jnp.einsum("btkgd,bskd->bkgts", qg, k) * cfg.softmax_scale
+        probs = _softmax(scores, _mask(q_slot, k_valid, k_slot, window), v.dtype)
+        return jnp.einsum("bkgts,bskd->btkgd", probs, v).reshape(b, -1, heads * d)
+
+    rows = t
+    while b * heads * rows * s > ATTENTION_BLOCK_SCORES and rows % 2 == 0:
+        rows //= 2
+    if rows == t:
+        return block((q, q_slot))
+    split = lambda a: jnp.moveaxis(a.reshape((b, t // rows, rows) + a.shape[2:]), 1, 0)  # noqa: E731
+    out = lax.map(block, (split(q), split(q_slot)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, t, heads * d)
+
+
+def _gqa_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only, kind="gqa", wraps=False):
+    """Grouped-query attention over ``h`` ``[b, t, hidden]``; ``kind``
+    ``"sliding"`` sees a window back, ``"full"`` turns nothing by position.
+    ``k_valid`` is the chunk's own where it is ``chunk_only``, else every
+    position's (``[b, max_len]``). A state that ``wraps`` is a ring shorter
+    than the positions the cache counts."""
     b, t, _ = h.shape
     q = (h @ lp["q_w"].astype(cfg.dtype)).reshape(b, t, cfg.heads, cfg.head_dim)
     k, v = jnp.split(h @ lp["kv_w"].astype(cfg.dtype), 2, axis=-1)
     k = k.reshape(b, t, cfg.kv_heads, cfg.head_dim)
     v = v.reshape(b, t, cfg.kv_heads, cfg.head_dim)
-    q = rope(q, q_pos, cfg.rope_theta)
-    k = rope(k, q_pos, cfg.rope_theta)
+    if kind != "full":
+        q = rope(q, q_pos, cfg.rope_theta, interleaved=cfg.rope_interleaved)
+        k = rope(k, q_pos, cfg.rope_theta, interleaved=cfg.rope_interleaved)
+    window = cfg.sliding_window if kind == "sliding" else 0
+    k_slot = None
     if state is not None:
-        state = {"k": _write(state["k"], k, start), "v": _write(state["v"], v, start)}
+        slots = state["k"].shape[1]
+        if wraps and t > 1 and not chunk_only:
+            raise NotImplementedError(
+                f"a chunk of {t} tokens into a ring of {slots} slots that wraps: prefill a prompt whole"
+            )
+        state = {"k": _ring_write(state["k"], k, start, wraps), "v": _ring_write(state["v"], v, start, wraps)}
         if not chunk_only:
             k, v = state["k"], state["v"]
-    g = cfg.heads // cfg.kv_heads
-    qg = q.reshape(b, t, cfg.kv_heads, g, cfg.head_dim)
-    scores = jnp.einsum("btkgd,bskd->bkgts", qg, k) * cfg.softmax_scale
-    probs = _softmax(scores, _mask(q_slot, k_valid), v.dtype)
-    out = jnp.einsum("bkgts,bskd->btkgd", probs, v)
-    return out.reshape(b, t, cfg.heads * cfg.head_dim), state
+            if wraps:  # what each slot holds now, and whether that is a real token
+                k_slot = _ring_positions(slots, start)
+                k_valid = jnp.take(k_valid, jnp.maximum(k_slot, 0), axis=1) & (k_slot >= 0)
+    out = _grouped_query(q, k, v, q_slot, k_valid, k_slot, window, cfg)
+    return out, state
 
 
 def _mla_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only):
@@ -496,10 +677,10 @@ def _mla_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only)
 
 class ExpertStats(NamedTuple):
     """What the expert layers of one forward pass took: ``load`` ``[expert
-    layers, experts]`` int32, the choices of the real tokens each expert
-    got; ``touched`` ``[]`` int32, over the expert layers the experts a real
-    token chose: those whose weights the pass had to read (padding takes no
-    routed expert)."""
+    layers, experts held here]`` int32, the choices of the real tokens each
+    expert got; ``touched`` ``[]`` int32, over the expert layers the experts
+    a real token chose: those whose weights the pass had to read (padding
+    takes no routed expert, nor does a choice of an expert held elsewhere)."""
 
     load: jax.Array
     touched: jax.Array
@@ -507,7 +688,7 @@ class ExpertStats(NamedTuple):
     @staticmethod
     def none(cfg: DecoderConfig) -> "ExpertStats":
         n = sum(kind == "experts" for kind in cfg.layer_pattern)
-        return ExpertStats(jnp.zeros((n, max(cfg.n_routed_experts, 1)), jnp.int32), jnp.zeros((), jnp.int32))
+        return ExpertStats(jnp.zeros((n, max(cfg.experts_held, 1)), jnp.int32), jnp.zeros((), jnp.int32))
 
     def __add__(self, other: "ExpertStats") -> "ExpertStats":  # type: ignore[override]
         return ExpertStats(self.load + other.load, self.touched + other.touched)
@@ -542,23 +723,26 @@ def _stack(
         if chunk_only:
             q_slot = q_slot - start  # slots within the chunk
     states, loads, touched = [], [], jnp.zeros((), jnp.int32)
-    for i, (lp, kind) in enumerate(zip(params["layers"], cfg.layer_pattern)):
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+    for i, (lp, kind, attention) in enumerate(zip(params["layers"], cfg.layer_pattern, cfg.attention_pattern)):
+        h = _norm(x, lp["attn_norm"], cfg)
         state = cache.layers[i] if cache is not None else None
-        if cfg.attention == "mla":
+        if attention == "mla":
             a, state = _mla_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only)
         else:
-            a, state = _gqa_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only)
+            wraps = state is not None and state["k"].shape[1] < cache.valid.shape[1]
+            a, state = _gqa_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only, attention, wraps)
         states.append(state)
-        x = x + (a @ lp["o_w"].astype(cfg.dtype))
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+        a = a @ lp["o_w"].astype(cfg.dtype)
+        if not cfg.parallel_block:  # the feed-forward reads a norm of its own, over x + a
+            x, a = x + a, None
+            h = _norm(x, lp["mlp_norm"], cfg)
         if kind == "experts":
             y, load, n_touched = _experts_layer(h, lp, cfg, attn_mask)
             loads.append(load)
             touched = touched + n_touched
         else:
             y = _gated_mlp(h, lp["gate_w"], lp["down_w"])
-        x = x + y
+        x = x + y if a is None else x + a + y
     stats = ExpertStats(jnp.stack(loads), touched) if loads else ExpertStats.none(cfg)
     if cache is not None:
         cache = Cache(layers=states, length=start + t, valid=valid_full)
@@ -566,7 +750,10 @@ def _stack(
 
 
 def _head(params: Params, x: jax.Array, cfg: DecoderConfig) -> jax.Array:
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    x = _norm(x, params["final_norm"], cfg)
+    if cfg.tie_embeddings:
+        logits = jnp.einsum("...h,vh->...v", x, params["tok_emb"].astype(cfg.dtype))
+        return logits.astype(jnp.float32) * cfg.logit_scale
     return (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
 
 

@@ -4,8 +4,8 @@ over the experts tokens chose.
 ``route_top_k`` scores in float32 (the product at ``Precision.HIGHEST``: on
 the chip a float32 product otherwise runs in one bfloat16 pass, and a near
 tie would go to another expert than the one the published model takes) and
-keeps the ``k`` largest, greedily, with their softmax weights as they are or
-renormalised.
+keeps the ``k`` largest, greedily, with their weights as they are or
+renormalised: a softmax over all the experts, or a sigmoid of each.
 
 ``routed_experts`` sorts the ``tokens * k`` (token, expert) pairs by expert
 and runs each expert's gated MLP over its own rows with
@@ -18,6 +18,13 @@ rows and belong to no group, so they are not computed, an expert that only
 padding chose has no row and its weights are not read, and a padding
 token's result is zero. The per-expert row counts of the real tokens come
 back for the callers' counters (``expert_sizes``).
+
+A chip that holds a share of a layer's experts says which (``held``: the
+first and how many; the weights it hands over are theirs): the router is as
+wide as all the experts and chooses among all, and a pair whose expert lies
+elsewhere goes where a padding token's goes, behind the last group. The
+result is the part the held experts give; what the others would add is
+another chip's to compute, and nothing here stands in for it.
 """
 
 from __future__ import annotations
@@ -34,8 +41,10 @@ def route_top_k(
     *,
     renormalize: bool = False,
     scale: float = 1.0,
+    scoring: str = "softmax",
 ) -> tuple[jax.Array, jax.Array]:
-    """Softmax over all experts in float32, the ``k`` largest of it:
+    """Scores of all experts in float32 (a softmax over them, or with
+    ``scoring`` ``"sigmoid"`` each expert's own), the ``k`` largest:
     weights ``[n, k]`` float32 and expert ids ``[n, k]`` int32. A tie goes to
     the expert with the lower id (``lax.top_k``)."""
     logits = jnp.matmul(
@@ -43,20 +52,19 @@ def route_top_k(
         gate_w.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
     )
-    scores = jax.nn.softmax(logits, axis=-1)
+    scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" else jax.nn.softmax(logits, axis=-1)
     weights, experts = lax.top_k(scores, k)
     if renormalize:
         weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
     return weights * scale, experts.astype(jnp.int32)
 
 
-def expert_sizes(experts: jax.Array, n_experts: int, counted: jax.Array | None = None) -> jax.Array:
+def expert_sizes(experts: jax.Array, n_experts: int) -> jax.Array:
     """How many of the (token, choice) pairs ``experts`` ``[n, k]`` name each
-    expert, ``[n_experts]`` int32; of the ``counted`` ``[n]`` tokens alone
-    where given. A compare-and-sum: a scatter-add into so few bins collides."""
+    expert, ``[n_experts]`` int32; an id outside ``[0, n_experts)`` is
+    counted nowhere. A compare-and-sum: a scatter-add into so few bins
+    collides."""
     hit = experts[:, :, None] == jnp.arange(n_experts, dtype=experts.dtype)
-    if counted is not None:
-        hit = hit & counted[:, None, None]
     return hit.sum((0, 1), dtype=jnp.int32)
 
 
@@ -67,19 +75,27 @@ def routed_experts(
     gate_up_w: jax.Array,  # [experts, hidden, 2 * width]: gate | up
     down_w: jax.Array,  # [experts, width, hidden]
     counted: jax.Array | None = None,  # [n] bool: False = a padding token
+    held: tuple[int, int] | None = None,  # (first, count) of the experts whose weights these are
 ) -> tuple[jax.Array, jax.Array]:
     """``sum_i weights[:, i] * E_{experts[:, i]}(h)`` with ``E`` a gated SiLU
     MLP, ``[n, hidden]`` in ``h``'s dtype (float32 accumulation), and how
     many (token, choice) pairs each expert took, ``[experts]`` int32. Of the
     ``counted`` tokens alone where given: the others' pairs are in no
-    expert's group and their rows of the result are zero."""
+    expert's group and their rows of the result are zero. Of the ``held``
+    experts alone where given (``experts`` numbers all of a layer's): the sum
+    runs over the choices that lie among them."""
     n, k = experts.shape
     n_experts = gate_up_w.shape[0]
-    # a padding token's pairs sort behind the last expert's rows, into no group
-    keys = experts if counted is None else jnp.where(counted[:, None], experts, n_experts)
+    computed = None if counted is None else jnp.broadcast_to(counted[:, None], (n, k))
+    if held is not None:
+        experts = experts - held[0]  # numbered as the weights are
+        here = (experts >= 0) & (experts < n_experts)
+        computed = here if computed is None else computed & here
+    # a pair that is not computed sorts behind the last expert's rows, into no group
+    keys = experts if computed is None else jnp.where(computed, experts, n_experts)
     order = jnp.argsort(keys.reshape(-1))  # stable: an expert's rows stay in token order
     token = order // k
-    sizes = expert_sizes(experts, n_experts, counted)
+    sizes = expert_sizes(keys, n_experts)
     x = h[token]
     gate_up = lax.ragged_dot(x, gate_up_w.astype(h.dtype), sizes, preferred_element_type=jnp.float32)
     gate, up = jnp.split(gate_up, 2, axis=-1)
@@ -88,9 +104,11 @@ def routed_experts(
     # back to (token, choice) order with a gather (the inverse permutation):
     # a scatter-add over the sorted rows costs the chip far more
     out = out[jnp.argsort(order)].reshape(n, k, -1)
-    y = (out * weights[..., None]).sum(1)
-    if counted is not None:
+    if computed is not None:
         # the rows past the groups hold whatever the device left there: the
-        # mask decides a padding token's result, not those rows
-        y = jnp.where(counted[:, None], y, 0.0)
+        # mask decides what a pair outside every group adds (nothing, whatever
+        # its weight), not those rows
+        out = jnp.where(computed[..., None], out, 0.0)
+        weights = jnp.where(computed, weights, 0.0)
+    y = (out * weights[..., None]).sum(1)
     return y.astype(h.dtype), sizes

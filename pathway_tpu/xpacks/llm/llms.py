@@ -101,7 +101,8 @@ class TpuPipelineChat(UDF):
     are given) and its rows to ``max_batch_size``, always, so the compiled
     programs are one prefill a bucket and one decode loop (the padding takes
     no routed expert: the programs get the mask, and ``chat.fetch`` counts
-    the pairs left out): ``chat_prefill``
+    the pairs left out, and of the real tokens' pairs those whose expert
+    this chip holds): ``chat_prefill``
     (the prompts into a cache of ``max_prompt_len + max_new_tokens`` slots,
     the head at each row's last position) and ``chat_decode`` (the remaining
     tokens through the cache). A prompt over ``max_prompt_len`` tokens keeps
@@ -252,7 +253,7 @@ class TpuPipelineChat(UDF):
                         first, first_logit, rest, rest_logits, pre, dec = fetched
                         toks = np.concatenate([first[:, None], rest], axis=1)
                         logits = np.concatenate([first_logit[:, None], rest_logits], axis=1)
-                        load = pre.load + dec.load  # [expert layers, experts]
+                        load = pre.load + dec.load  # [expert layers, experts held here]
                         # the (token, choice) pairs of the call's shapes, and
                         # those of them that were padding and took no expert
                         steps = max_new_tokens - 1
@@ -265,14 +266,19 @@ class TpuPipelineChat(UDF):
                             expert_tokens_mean=int(round(float(load.mean(-1).sum()))),
                             expert_pairs=pairs_per_token * (ids.size + max_batch_size * steps),
                             expert_pairs_skipped=pairs_per_token * (ids.size - real_tokens + pad_rows * steps),
+                            # of the real tokens' pairs, those an expert held here took
+                            expert_pairs_held=int(load.sum()),
                             decode_touched=int(dec.touched),
                             decode_layer_steps=expert_layers * steps,
+                            # what the call's cache holds: every layer's slots, a windowed layer's its ring
+                            cache_bytes=sum(a.nbytes for a in jax.tree.leaves(cache.layers)),
                         )
                     self.last_generation = {
                         "rows": len(prompts), "bucket": width, "tokens": toks, "logits": logits,
                         "prompt_tokens": [len(e) for e, _ in encoded],
                         "expert_load": load, "prefill_touched": int(pre.touched),
                         "decode_touched": int(dec.touched),
+                        "prefill_pairs_held": int(pre.load.sum()), "decode_pairs_held": int(dec.load.sum()),
                     }
                 with _tracing.detail("chat.detokenize"):
                     return [self.tokenizer.decode(list(row)) for row in toks[: len(prompts)]]

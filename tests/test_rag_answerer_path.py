@@ -128,6 +128,9 @@ def test_the_chats_stages_carry_the_counts_the_metrics_read(chat):
     assert fetch["expert_pairs"] == 2 * 2 * (4 * 32 + 4 * 4)
     assert fetch["expert_pairs_skipped"] == 2 * 2 * (4 * 32 - 50 + 1 * 4)
     assert fetch["decode_layer_steps"] == 2 * 4
+    # every expert is held here: all the real tokens' pairs; three latent caches of 4 x 37 rows of 20 bfloat16
+    assert fetch["expert_pairs_held"] == fetch["expert_pairs"] - fetch["expert_pairs_skipped"]
+    assert fetch["cache_bytes"] == 3 * 4 * 37 * 20 * 2
     assert 2 * 4 <= fetch["decode_touched"] == chat.last_generation["decode_touched"] <= 2 * 4 * 3 * 2
     assert "chat.tokenize" not in after  # a detail stage: only while someone looks
 
@@ -140,6 +143,40 @@ def test_a_full_batch_of_full_width_prompts_skips_no_pair(chat):
     assert chat.last_generation["prompt_tokens"] == [32] * 4
     assert after["expert_pairs"] - before["expert_pairs"] == 2 * 2 * (4 * 32 + 4 * 4)
     assert after["expert_pairs_skipped"] == before["expert_pairs_skipped"]
+
+
+def test_a_chat_that_holds_a_share_of_the_experts_counts_the_pairs_it_held_and_its_rings_bytes():
+    """The windowed, parallel-block decoder behind the same chat: 2 of 8
+    experts held, a window of 8 under a cache of 37 positions."""
+    from pathway_tpu.models.decoder import DecoderConfig
+
+    hf = {
+        "model_type": "cohere2_moe", "vocab_size": 512, "hidden_size": 64, "num_hidden_layers": 4,
+        "num_attention_heads": 8, "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 32,
+        "num_experts": 2, "num_experts_per_tok": 2, "num_shared_experts": 2, "sliding_window": 8,
+        "layer_types": ["sliding_attention"] * 3 + ["full_attention"], "layer_norm_eps": 1e-5, "rope_theta": 50000,
+        "norm_topk_prob": True, "expert_selection_fn": "sigmoid", "shared_expert_combination_strategy": "average",
+        "position_embedding_type": "rope_gptj", "tie_word_embeddings": True, "use_parallel_block": True,
+        "held_here": {"experts": [4, 2], "of_experts": 8},
+    }
+    chat = TpuPipelineChat(
+        DecoderConfig.from_hf(hf), max_new_tokens=5, max_prompt_len=32, max_batch_size=4,
+        prompt_buckets=[16, 32], keep_tail=6, eos_id=None,
+    )
+    chat._fn([_words(2)])
+    before = _this_threads_stages()["chat.fetch"]["counts"]
+    out = chat._fn([_words(20), _words(9, 50)])  # 22 and 11 tokens, both longer than the window: the bucket of 32
+    after = _this_threads_stages()["chat.fetch"]["counts"]
+    fetch = {name: value - before.get(name, 0) for name, value in after.items()}
+    made = chat.last_generation
+    assert len(out) == 2 and made["expert_load"].shape == (4, 2)
+    real_pairs = fetch["expert_pairs"] - fetch["expert_pairs_skipped"]
+    assert real_pairs == 4 * 2 * (33 + 2 * 4)  # four expert layers, two choices, 33 prompt tokens and 4 steps of 2 rows
+    assert 0 < fetch["expert_pairs_held"] == made["expert_load"].sum() < real_pairs  # 2 of 8 experts: some, not all
+    assert fetch["expert_pairs_held"] == made["prefill_pairs_held"] + made["decode_pairs_held"]
+    assert fetch["decode_touched"] <= 4 * 4 * 2  # of the two held experts, a layer a step
+    # three rings of 8 slots and one layer of all 37 positions: keys and values, 2 heads of 16, bfloat16
+    assert fetch["cache_bytes"] == 4 * (3 * 8 + 37) * 2 * 2 * 16 * 2
 
 
 # -- the question answerer over a DataIndex's reply ------------------------------
