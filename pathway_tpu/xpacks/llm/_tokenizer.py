@@ -12,7 +12,7 @@ from __future__ import annotations
 import functools
 import hashlib
 import re
-from typing import Any, Protocol, Sequence
+from typing import Any, Callable, Protocol, Sequence
 
 import numpy as np
 
@@ -121,6 +121,13 @@ class HashTokenizer:
         return " ".join(f"<{i}>" for i in ids if i > 3)
 
 
+def _bucket(n: int, minimum: int) -> int:
+    b = minimum
+    while b < n:
+        b *= 2
+    return b
+
+
 def pad_to_buckets(
     ids: np.ndarray,
     mask: np.ndarray,
@@ -135,17 +142,61 @@ def pad_to_buckets(
     expensive); batch likewise (min ``batch_bucket_min``).
     """
     b, t = ids.shape
-    bt = batch_bucket_min
-    while bt < b:
-        bt *= 2
-    tt = seq_bucket_min
-    while tt < t:
-        tt *= 2
+    bt = _bucket(b, batch_bucket_min)
+    tt = _bucket(t, seq_bucket_min)
     out_ids = np.zeros((bt, tt), np.int32)
     out_mask = np.zeros((bt, tt), bool)
     out_ids[:b, :t] = ids
     out_mask[:b, :t] = mask
     return out_ids, out_mask, b
+
+
+def plan_pieces(
+    lengths: Sequence[int],
+    row_cost: Callable[[int], float],
+    dispatch_cost: float,
+    batch_bucket_min: int = 8,
+    seq_bucket_min: int = 8,
+) -> list[tuple[int, int]]:
+    """Cut a chunk whose rows are ordered longest first into the
+    consecutive pieces ``[(start, stop), ...]`` that cost least when each
+    is padded by :func:`pad_to_buckets` on its own: a piece pays
+    ``row_cost(seq)`` for every row of its row bucket, ``seq`` being the
+    sequence bucket of its first (longest) row, plus ``dispatch_cost``.
+
+    A piece's later rows are no longer than its first, so moving the head
+    of the next piece into a piece's padding rows costs nothing and can
+    only shorten the next piece: some best plan fills every piece but the
+    last to exactly a row bucket. The search walks those plans alone, from
+    the chunk's end: ``len(lengths) / batch_bucket_min`` states by the few
+    row buckets. Every piece's shape is one the uncut chunk could have had
+    (a row bucket up to the chunk's own, a sequence bucket from
+    ``seq_bucket_min`` up to the chunk's own)."""
+    n = len(lengths)
+    if n <= batch_bucket_min:
+        return [(0, n)]
+    # best[p] = (cost, stop): the cheapest cover of rows [p, n) begins
+    # with the piece [p, stop)
+    best: dict[int, tuple[float, int]] = {n: (0.0, n)}
+    last_start = (n - 1) // batch_bucket_min * batch_bucket_min
+    for start in range(last_start, -1, -batch_bucket_min):
+        row = row_cost(_bucket(lengths[start], seq_bucket_min))
+        # the chunk's tail as one piece, then every exact row bucket
+        least = _bucket(n - start, batch_bucket_min) * row + dispatch_cost
+        stop = n
+        rows = batch_bucket_min
+        while start + rows < n:
+            cost = rows * row + dispatch_cost + best[start + rows][0]
+            if cost < least:
+                least, stop = cost, start + rows
+            rows *= 2
+        best[start] = (least, stop)
+    pieces, start = [], 0
+    while start < n:
+        stop = best[start][1]
+        pieces.append((start, stop))
+        start = stop
+    return pieces
 
 
 class WordPieceTokenizer:

@@ -24,7 +24,12 @@ from pathway_tpu.internals.udfs import (
     async_executor,
     batch_executor,
 )
-from pathway_tpu.xpacks.llm._tokenizer import HashTokenizer, Tokenizer, pad_to_buckets
+from pathway_tpu.xpacks.llm._tokenizer import (
+    HashTokenizer,
+    Tokenizer,
+    pad_to_buckets,
+    plan_pieces,
+)
 
 _ENCODER_PRESETS = {
     "all-MiniLM-L6-v2": "minilm_l6",
@@ -58,6 +63,25 @@ def _resolve_device_resident(device_resident: "bool | None") -> bool:
 #: dispatch whose program walks the batch in blocks of this many tokens:
 #: the size that was fastest a row at every shape timed (PERF.md, PR 26)
 _STEP_BLOCK_TOKENS = 4096
+
+#: what one more dispatch of a chunk is worth, in encoder FLOPs: a chunk is
+#: cut into a further piece (``plan_pieces``) only where that saves more
+#: padded work than this.  A piece more costs the pump some 3.4 ms (TPU
+#: v5e host, MiniLM chunks of 256 rows: 0.9 ms of ``embed.dispatch`` and
+#: one more ``knn.add.dispatch`` of 2.5 ms, since the index gathers once a
+#: parent device batch), and the chip walks a step block in 7.6 ms for
+#: BGE-base (0.78 TFLOP) and 0.8 ms for MiniLM: some 103 TFLOP/s, at which
+#: 3.4 ms are 0.35 TFLOP (PERF.md, PRs 26, 33 and 36).  Counted in FLOPs,
+#: not tokens: a small encoder's padding is cheap and a dispatch is not
+_DISPATCH_FLOPS = 0.35e12
+
+
+def _row_flops(cfg: Any, seq: int) -> int:
+    """Forward FLOPs of one padded row of ``seq`` tokens through an
+    encoder of ``cfg``'s widths: a layer's QKV, output and two FFN
+    products, its scores and its weighted values."""
+    h, f = cfg.hidden, cfg.intermediate
+    return cfg.layers * (seq * (8 * h * h + 4 * h * f) + 4 * seq * seq * h)
 
 
 def _in_row_blocks(step: Callable, *arrays: Any) -> Any:
@@ -220,8 +244,10 @@ class TpuEncoderEmbedder(UDF):
         # RESIDENT_UDF=0 restores eager host materialisation.
         self.device_resident = _resolve_device_resident(device_resident)
 
+        row_flops = functools.partial(_row_flops, cfg)
+
         def embed_batch(texts: list) -> list:
-            # the pieces of a call are stages only while someone looks
+            # the steps of a call are stages only while someone looks
             # (tracing.detail); ``embed.dispatch`` is always one
             with _tracing.detail("embed.tokenize") as st:
                 ids, mask = self.tokenizer.encode_batch(
@@ -230,28 +256,79 @@ class TpuEncoderEmbedder(UDF):
                 if st:
                     st.add(tokens=int(np.count_nonzero(mask)))
             with _tracing.detail("embed.pad") as st:
-                ids, mask, real = pad_to_buckets(
-                    ids, mask, seq_bucket_min=self.seq_bucket_min
+                # the chunk goes to the chip as the pieces that hold the
+                # least padding: rows ordered longest first (stable: equal
+                # lengths keep their order) and cut where ``plan_pieces``
+                # finds a dispatch worth its while
+                lengths = mask.sum(axis=1)
+                order = np.argsort(-lengths, kind="stable")
+                plan = plan_pieces(
+                    lengths[order].tolist(),
+                    row_flops,
+                    _DISPATCH_FLOPS,
+                    seq_bucket_min=self.seq_bucket_min,
                 )
-                ids_only = self._mask_from_ids and bool(
-                    np.array_equal(mask, ids != 0)
-                )
-                st.add(rows=real, padded_rows=len(ids), padded_tokens=ids.size)
-            with _tracing.stage("embed.dispatch") as st:
-                # the jitted steps are looked up here, at call time: the
-                # benchmark wraps these two attributes
-                if ids_only:
-                    h2d = ids.nbytes
-                    vecs_dev = self._jit_embed_ids(jnp.asarray(ids))
+
+                def cut(rows: np.ndarray) -> tuple:
+                    # as wide as the rows' last tokens reach (their counts
+                    # say less under a tokenizer that pads on the left)
+                    p_mask = mask[rows]
+                    used = np.flatnonzero(p_mask.any(axis=0))
+                    width = int(used.max(initial=0)) + 1
+                    return rows, ids[rows, :width], p_mask[:, :width]
+
+                if len(plan) == 1:
+                    # the whole chunk, in the order it came
+                    cuts = [(None, ids, mask)]
                 else:
-                    h2d = ids.nbytes + mask.nbytes
-                    vecs_dev = self._jit_embed(
-                        jnp.asarray(ids), jnp.asarray(mask)
+                    cuts = [cut(order[start:stop]) for start, stop in plan]
+                pieces = []
+                for rows, p_ids, p_mask in cuts:
+                    tokens = int(np.count_nonzero(p_mask))
+                    p_ids, p_mask, real = pad_to_buckets(
+                        p_ids, p_mask, seq_bucket_min=self.seq_bucket_min
                     )
-                _dres.record_h2d(h2d)
-                st.add(h2d_bytes=h2d)
-            with _tracing.detail("embed.rows_out", rows=real):
-                return _rows_from_device(vecs_dev, real, self.device_resident)
+                    if self._mask_from_ids and np.array_equal(
+                        p_mask, p_ids != 0
+                    ):
+                        p_mask = None
+                    pieces.append((rows, p_ids, p_mask, real, tokens))
+                st.add(
+                    rows=len(texts),
+                    padded_rows=sum(len(p[1]) for p in pieces),
+                    padded_tokens=sum(p[1].size for p in pieces),
+                    pieces=len(pieces),
+                )
+            # every piece is enqueued before any row is looked at
+            on_device = []
+            for rows, p_ids, p_mask, real, tokens in pieces:
+                with _tracing.stage("embed.dispatch") as st:
+                    # the jitted steps are looked up here, at call time: the
+                    # benchmark wraps these two attributes
+                    if p_mask is None:
+                        h2d = p_ids.nbytes
+                        vecs_dev = self._jit_embed_ids(jnp.asarray(p_ids))
+                    else:
+                        h2d = p_ids.nbytes + p_mask.nbytes
+                        vecs_dev = self._jit_embed(
+                            jnp.asarray(p_ids), jnp.asarray(p_mask)
+                        )
+                    _dres.record_h2d(h2d)
+                    st.add(
+                        h2d_bytes=h2d, tokens=tokens, padded_tokens=p_ids.size
+                    )
+                on_device.append((rows, vecs_dev, real))
+            with _tracing.detail("embed.rows_out", rows=len(texts)):
+                out: list = [None] * len(texts)
+                for rows, vecs_dev, real in on_device:
+                    cells = _rows_from_device(
+                        vecs_dev, real, self.device_resident
+                    )
+                    if rows is None:
+                        return cells
+                    for i, cell in zip(rows.tolist(), cells):
+                        out[i] = cell
+                return out
 
         super().__init__(
             embed_batch,

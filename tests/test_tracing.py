@@ -730,8 +730,8 @@ RAG_STAGES = {
     "sink.emit": ("rows",),
     "udf.batch": ("rows", "narrowed"),
     "embed.tokenize": ("tokens",),
-    "embed.pad": ("rows", "padded_rows", "padded_tokens"),
-    "embed.dispatch": ("h2d_bytes",),
+    "embed.pad": ("rows", "padded_rows", "padded_tokens", "pieces"),
+    "embed.dispatch": ("h2d_bytes", "tokens", "padded_tokens"),
     "embed.rows_out": ("rows",),
     "knn.add.host": ("rows",),
     "knn.add.dispatch": ("rows", "h2d_bytes"),
@@ -1138,7 +1138,12 @@ class TestStagesOfARagRun:
         assert stages["knn.add.dispatch"]["counts"]["rows"] == N_DOCS
         assert stages["knn.search.fetch"]["counts"]["queries"] == N_QUERIES
         assert stages["sink.emit"]["counts"]["rows"] == N_DOCS + N_QUERIES
+        # a dispatch is a piece of a chunk; texts of one length are one piece
+        assert stages["embed.dispatch"]["calls"] == stages["embed.pad"]["counts"]["pieces"]
         assert stages["embed.dispatch"]["calls"] == stages["udf.batch"]["calls"]
+        assert stages["embed.dispatch"]["counts"]["tokens"] == (
+            stages["embed.tokenize"]["counts"]["tokens"]
+        )
         # int32 ids, and nothing else, go up for a text: 4 bytes a padded token
         assert stages["embed.dispatch"]["counts"]["h2d_bytes"] == (
             4 * stages["embed.pad"]["counts"]["padded_tokens"]
