@@ -3,8 +3,9 @@
 The chat path of the LLM xpack. The reference's local chat wraps a HF
 ``pipeline`` on CPU/GPU torch (reference: python/pathway/xpacks/llm/llms.py:441
 HFPipelineChat); here decode is native JAX on TPU. A ``DecoderConfig`` states
-the attention every layer uses and, layer by layer, the feed-forward kind
-(``layer_pattern``):
+the attention every layer uses, layer by layer the feed-forward kind
+(``layer_pattern``) and, with ``layer_types``, layer by layer the operator
+in attention's place (``attention_pattern``):
 
 - attention ``"gqa"``: grouped-query heads over RoPE (the Mistral-style
   layer); the layer's cache holds keys and values, ``[b, slots, kv_heads,
@@ -12,7 +13,16 @@ the attention every layer uses and, layer by layer, the feed-forward kind
   it: ``"sliding_attention"`` (RoPE; key ``j`` is seen by query ``i`` iff
   ``0 <= i - j < sliding_window``; the cache is a ring of ``min(window,
   max_len)`` slots, position ``p`` in slot ``p mod slots``) and
-  ``"full_attention"`` (no positions at all, causal, ``max_len`` slots).
+  ``"full_attention"`` (no positions at all, causal, ``max_len`` slots);
+  ``"gqa"`` there is the rotating layer itself. ``qk_norm`` puts an RMS norm
+  over each head's values of the queries and of the keys before the rotation.
+- operator ``"conv"``: a gated short convolution in attention's place. ``B, C,
+  u`` are thirds of one projection, ``z = B * u``, a causal depthwise filter
+  of ``conv_taps`` taps runs over ``z`` channel by channel, and ``C`` gates
+  what it gives. **The layer's cache holds the last ``conv_taps`` ``z`` of a
+  row, ``[b, hidden, conv_taps]``, whatever ``max_len``**; a padding position's
+  ``z`` is zero, so a left-padded row's first tokens see what an unpadded
+  row's see.
 - attention ``"mla"``: latent attention. Keys and values are expanded from
   one compressed row a token, and **the layer's cache holds that row and the
   rotated shared key only** (``kv_lora_rank + qk_rope_head_dim`` values a
@@ -22,8 +32,10 @@ the attention every layer uses and, layer by layer, the feed-forward kind
 - feed-forward ``"dense"``: a gated SiLU MLP; ``"experts"``: a float32
   router over all routed experts, the ``experts_per_token`` largest, a
   grouped product over the experts that were chosen (``ops/moe.py``; no
-  capacity, no token dropped) plus the shared experts as one gated MLP,
-  summed or averaged. ``router`` ``"sigmoid"`` scores each expert by itself.
+  capacity, no token dropped) plus the shared experts (where there are any)
+  as one gated MLP, summed or averaged. ``router`` ``"sigmoid"`` scores each
+  expert by itself; with ``router_bias`` the layer's ``expert_bias`` is added
+  for the choice and left out of the weights.
   With ``held_experts`` the layer holds a share of its experts: it routes
   over all of them and computes the part of the result its own give.
 
@@ -110,7 +122,7 @@ class DecoderConfig:
     held_experts: tuple[int, int] | None = None
     # -- the block
     head_size: int = 0  # a head's width where it is not hidden / heads
-    #: each layer's kind of "gqa" attention, "sliding_attention" | "full_attention"
+    #: each layer's operator: "sliding_attention" | "full_attention" | "gqa" | "conv"
     layer_types: tuple[str, ...] | None = None
     sliding_window: int = 0
     norm: str = "rms"  # "rms" | "layer"
@@ -118,6 +130,9 @@ class DecoderConfig:
     rope_interleaved: bool = False
     tie_embeddings: bool = False
     logit_scale: float = 1.0
+    qk_norm: bool = False  # an RMS norm over each head of the queries and of the keys
+    router_bias: bool = False  # the experts are chosen by score + expert_bias
+    conv_taps: int = 0  # the length of a "conv" layer's filter, and of its state
 
     @property
     def head_dim(self) -> int:
@@ -137,9 +152,9 @@ class DecoderConfig:
 
     @property
     def attention_pattern(self) -> tuple[str, ...]:
-        """The attention kind of each layer: ``attention`` for every layer,
-        or with ``layer_types`` ``"sliding"`` or ``"full"`` (both ``"gqa"``
-        heads)."""
+        """The operator of each layer: ``attention`` for every layer, or
+        with ``layer_types`` ``"sliding"``, ``"full"`` or ``"gqa"`` (all
+        ``"gqa"`` heads), or ``"conv"``."""
         if self.layer_types is None:
             return (self.attention,) * self.layers
         return tuple(kind.removesuffix("_attention") for kind in self.layer_types)
@@ -166,7 +181,11 @@ class DecoderConfig:
         greedy softmax routing in one group; ``cohere2_moe``: windowed beside
         full grouped-query attention in a parallel block, sigmoid routing,
         averaged shared experts, and under ``held_here`` the share of the
-        experts this chip holds; ``mistral`` / ``llama``)."""
+        experts this chip holds; ``lfm2_moe``: gated short convolutions
+        beside grouped-query attention that rotates, whatever its
+        ``layer_types`` string, and norms each head of its queries and keys,
+        sigmoid routing with a selection bias, no shared expert;
+        ``mistral`` / ``llama``)."""
         kind = hf.get("model_type", "mistral")
         common = dict(
             vocab_size=hf["vocab_size"],
@@ -176,7 +195,7 @@ class DecoderConfig:
             kv_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
             intermediate=hf["intermediate_size"],
             max_len=hf.get("max_position_embeddings", 8192),
-            rope_theta=float(hf.get("rope_theta", 10000.0)),
+            rope_theta=float(hf.get("rope_theta") or (hf.get("rope_parameters") or {}).get("rope_theta", 10000.0)),
             rms_eps=hf.get("rms_norm_eps") or hf.get("layer_norm_eps", 1e-5),
         )
 
@@ -252,6 +271,33 @@ class DecoderConfig:
                 norm_topk_prob=hf.get("norm_topk_prob", False),
                 routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
             )
+        elif kind == "lfm2_moe":
+            require({
+                "conv_bias": False, "use_expert_bias": True, "tie_embedding": True, "hidden_act": "silu",
+                "attention_bias": False,
+            })
+            if (hf.get("rope_parameters") or {}).get("rope_type", "default") != "default":
+                raise ValueError(f"lfm2_moe with rope_parameters={hf['rope_parameters']!r}: only 'default' is implemented")
+            layer_types = tuple(hf["layer_types"])
+            if set(layer_types) - {"conv", "full_attention"} or len(layer_types) != hf["num_hidden_layers"]:
+                raise ValueError(f"lfm2_moe with layer_types={hf['layer_types']!r}: a kind a layer, conv or full_attention")
+            common.update(
+                rms_eps=hf["norm_eps"],
+                head_size=hf.get("head_dim") or 0,
+                # this family's "full_attention" turns by position: the kind is the model's, not the string's
+                layer_types=tuple("conv" if t == "conv" else "gqa" for t in layer_types),
+                conv_taps=hf["conv_L_cache"],
+                qk_norm=True,
+                tie_embeddings=True,
+                n_routed_experts=hf["num_experts"],
+                experts_per_token=hf["num_experts_per_tok"],
+                moe_intermediate=hf["moe_intermediate_size"],
+                first_dense_layers=hf["num_dense_layers"],
+                norm_topk_prob=hf.get("norm_topk_prob", False),
+                routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+                router="sigmoid",
+                router_bias=True,
+            )
         elif kind not in ("mistral", "llama"):
             raise ValueError(f"no decoder layer for model_type {kind!r}")
         common.update(overrides)
@@ -301,13 +347,44 @@ def tiny_latent_moe_decoder(vocab_size: int = 512) -> DecoderConfig:
     )
 
 
+def tiny_hybrid_moe_decoder(vocab_size: int = 512) -> DecoderConfig:
+    """The gated-short-convolution layer pattern at a size for tests: a
+    convolution over a dense layer, then one period of attention (rotating,
+    a norm a head) and three convolutions over routed experts that a biased
+    sigmoid router picks, no shared expert, the head tied."""
+    return DecoderConfig(
+        vocab_size=vocab_size,
+        hidden=64,
+        layers=5,
+        heads=4,
+        kv_heads=2,
+        intermediate=160,
+        max_len=4096,
+        rope_theta=1000000.0,
+        layer_types=("conv", "gqa", "conv", "conv", "conv"),
+        conv_taps=3,
+        qk_norm=True,
+        tie_embeddings=True,
+        n_routed_experts=8,
+        experts_per_token=2,
+        moe_intermediate=32,
+        first_dense_layers=1,
+        norm_topk_prob=True,
+        router="sigmoid",
+        router_bias=True,
+    )
+
+
 # -- parameters ---------------------------------------------------------------
 
 
-def _layer_shapes(cfg: DecoderConfig, kind: str) -> dict[str, tuple]:
-    """Matrix shapes of one layer, by parameter name (norms apart)."""
+def _layer_shapes(cfg: DecoderConfig, kind: str, operator: str) -> dict[str, tuple]:
+    """Matrix shapes of one layer, by parameter name (norms apart): its
+    feed-forward ``kind`` and its ``operator`` (``attention_pattern``)."""
     h = cfg.hidden
-    if cfg.attention == "mla":
+    if operator == "conv":
+        shapes = _conv_shapes(cfg)
+    elif cfg.attention == "mla":
         shapes = {
             "q_w": (h, cfg.heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)),
             "kva_w": (h, cfg.cache_width),
@@ -325,13 +402,9 @@ def _layer_shapes(cfg: DecoderConfig, kind: str) -> dict[str, tuple]:
     else:
         e, w = cfg.n_routed_experts, cfg.moe_intermediate
         held, shared = cfg.experts_held, cfg.n_shared_experts * w
-        shapes.update(
-            router_w=(h, e),
-            experts_gate_w=(held, h, 2 * w),
-            experts_down_w=(held, w, h),
-            shared_gate_w=(h, 2 * shared),
-            shared_down_w=(shared, h),
-        )
+        shapes.update(router_w=(h, e), experts_gate_w=(held, h, 2 * w), experts_down_w=(held, w, h))
+        if shared:
+            shapes.update(shared_gate_w=(h, 2 * shared), shared_down_w=(shared, h))
     return shapes
 
 
@@ -341,14 +414,16 @@ def init_decoder_params(
     """``dtype=jnp.bfloat16`` stores weights half-size (7B fits a single
     16 GB chip); each tensor is drawn in f32 and cast immediately, so the
     f32 peak is one tensor, not the model. A matrix is scaled by the width
-    it contracts over (its last but one axis)."""
+    it contracts over (its last but one axis; a filter ``conv_w`` by its
+    taps). A router's ``expert_bias`` is drawn a twentieth wide: a buffer
+    that moves some choices, as a trained one does."""
 
-    def dense(key, shape):
-        scale = 1.0 / math.sqrt(shape[-2])
+    def dense(key, shape, contracts=-2):
+        scale = 1.0 / math.sqrt(shape[contracts])
         return (scale * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
 
-    pattern = cfg.layer_pattern
-    n_keys = 2 + sum(len(_layer_shapes(cfg, kind)) for kind in pattern)
+    pattern = list(zip(cfg.layer_pattern, cfg.attention_pattern))
+    n_keys = 2 + sum(len(_layer_shapes(cfg, *kinds)) + (kinds[0] == "experts" and cfg.router_bias) for kinds in pattern)
     keys = iter(jax.random.split(rng, n_keys))
     p: Params = {
         "tok_emb": (
@@ -363,22 +438,30 @@ def init_decoder_params(
     head_key = next(keys)
     if not cfg.tie_embeddings:
         p["lm_head"] = dense(head_key, (cfg.hidden, cfg.vocab_size))
-    for kind in pattern:
-        lp = {name: dense(next(keys), shape) for name, shape in _layer_shapes(cfg, kind).items()}
+    for kind, operator in pattern:
+        lp = {
+            name: dense(next(keys), shape, -1 if name == "conv_w" else -2)
+            for name, shape in _layer_shapes(cfg, kind, operator).items()
+        }
         lp["attn_norm"] = jnp.ones((cfg.hidden,), jnp.float32)
         if not cfg.parallel_block:
             lp["mlp_norm"] = jnp.ones((cfg.hidden,), jnp.float32)
         if cfg.attention == "mla":
             lp["kv_norm"] = jnp.ones((cfg.kv_lora_rank,), jnp.float32)
+        if cfg.qk_norm and operator != "conv":
+            lp["q_norm"] = jnp.ones((cfg.head_dim,), jnp.float32)
+            lp["k_norm"] = jnp.ones((cfg.head_dim,), jnp.float32)
+        if kind == "experts" and cfg.router_bias:
+            lp["expert_bias"] = 0.05 * jax.random.normal(next(keys), (cfg.n_routed_experts,), jnp.float32)
         p["layers"].append(lp)
     return p
 
 
 def decoder_param_spec(path: tuple, leaf: Any) -> P:
     name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-    if name in ("q_w", "kv_w", "kvb_w", "gate_w", "shared_gate_w"):
+    if name in ("q_w", "kv_w", "kvb_w", "gate_w", "shared_gate_w", "conv_in_w"):
         return P(None, MODEL_AXIS)
-    if name in ("o_w", "down_w", "shared_down_w"):
+    if name in ("o_w", "down_w", "shared_down_w", "conv_w"):
         return P(MODEL_AXIS, None)
     if name in ("experts_gate_w", "experts_down_w"):
         return P(EXPERT_AXIS, None, None)
@@ -468,22 +551,22 @@ def _experts_layer(h: jax.Array, lp: Params, cfg: DecoderConfig, counted: jax.Ar
     many of the ``counted`` ``[b, t]`` tokens' choices each expert held here
     took (``None``: every token's) and how many of them took any. The other
     tokens are padding and go through the shared experts alone. The shared
-    experts are one gated MLP as wide as all of them, which is their sum; an
-    average is that over their number."""
+    experts (where the layer has any) are one gated MLP as wide as all of
+    them, which is their sum; an average is that over their number."""
     b, t, hidden = h.shape
     flat = h.reshape(b * t, hidden)
     weights, experts = route_top_k(
         flat, lp["router_w"], cfg.experts_per_token,
         renormalize=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor, scoring=cfg.router,
+        select_bias=lp["expert_bias"] if cfg.router_bias else None,
     )
     y, load = routed_experts(
         flat, weights, experts, lp["experts_gate_w"], lp["experts_down_w"],
         None if counted is None else counted.reshape(-1), cfg.held_experts,
     )
-    shared = _gated_mlp(flat, lp["shared_gate_w"], lp["shared_down_w"])
-    if cfg.shared_combine == "average":
-        shared = shared / cfg.n_shared_experts
-    y = y + shared
+    if cfg.n_shared_experts:
+        shared = _gated_mlp(flat, lp["shared_gate_w"], lp["shared_down_w"])
+        y = y + (shared / cfg.n_shared_experts if cfg.shared_combine == "average" else shared)
     return y.reshape(b, t, hidden), load, jnp.count_nonzero(load).astype(jnp.int32)
 
 
@@ -495,7 +578,10 @@ class Cache(NamedTuple):
     kv_heads, head_dim]`` for a ``"gqa"`` layer (``max_len`` slots; a
     ``"sliding"`` layer ``min(sliding_window, max_len)``, as a ring: position
     ``p`` lies in slot ``p mod slots``), ``{"latent"}`` ``[b, max_len,
-    kv_lora_rank + qk_rope_head_dim]`` for an ``"mla"`` layer.
+    kv_lora_rank + qk_rope_head_dim]`` for an ``"mla"`` layer, ``{"conv"}``
+    ``[b, hidden, conv_taps]`` for a ``"conv"`` layer: the inputs of its
+    filter at the row's last ``conv_taps`` positions, a size that does not
+    follow ``max_len``.
 
     ``valid`` marks usable positions: left-pad positions of shorter prompts in a
     batch stay False forever, so generated tokens never attend to pads.
@@ -508,6 +594,8 @@ class Cache(NamedTuple):
 
 def init_cache(cfg: DecoderConfig, batch: int, max_len: int) -> Cache:
     def state(kind: str) -> dict:
+        if kind == "conv":
+            return _conv_state(cfg, batch)
         if kind == "mla":
             return {"latent": jnp.zeros((batch, max_len, cfg.cache_width), cfg.dtype)}
         slots = min(cfg.sliding_window, max_len) if kind == "sliding" else max_len
@@ -614,6 +702,8 @@ def _gqa_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only,
     k, v = jnp.split(h @ lp["kv_w"].astype(cfg.dtype), 2, axis=-1)
     k = k.reshape(b, t, cfg.kv_heads, cfg.head_dim)
     v = v.reshape(b, t, cfg.kv_heads, cfg.head_dim)
+    if cfg.qk_norm:  # over each head's own values, before the rotation
+        q, k = rms_norm(q, lp["q_norm"], cfg.rms_eps), rms_norm(k, lp["k_norm"], cfg.rms_eps)
     if kind != "full":
         q = rope(q, q_pos, cfg.rope_theta, interleaved=cfg.rope_interleaved)
         k = rope(k, q_pos, cfg.rope_theta, interleaved=cfg.rope_interleaved)
@@ -672,6 +762,46 @@ def _mla_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only)
     return out.reshape(b, t, cfg.heads * vd), state
 
 
+# -- the gated short convolution ----------------------------------------------
+# The kind in one place: its parameters' shapes, its cache state, its step.
+
+
+def _conv_shapes(cfg: DecoderConfig) -> dict[str, tuple]:
+    """``conv_in_w`` is ``B | C | u`` side by side, ``conv_w`` the filter, a
+    row a channel and ``conv_w[:, -1]`` the tap on the newest position;
+    ``o_w`` is the projection out, under attention's name for it."""
+    h = cfg.hidden
+    return {"conv_in_w": (h, 3 * h), "conv_w": (h, cfg.conv_taps), "o_w": (h, h)}
+
+
+def _conv_state(cfg: DecoderConfig, batch: int) -> dict:
+    return {"conv": jnp.zeros((batch, cfg.hidden, cfg.conv_taps), cfg.dtype)}
+
+
+def _short_conv(h, lp, cfg, state, real):
+    """The gated short convolution over ``h`` ``[b, t, hidden]``, a chunk or
+    one token alike, before ``o_w``: ``C * c`` with ``c[i] = sum_j conv_w[:, j] *
+    z[i - (taps - 1) + j]`` (float32 sums), ``z = B * u``. What lies before
+    the chunk is the state, the row's last ``taps`` ``z`` (zeros in an empty
+    cache and where there is none); the state that comes back is the last
+    ``taps`` of both. ``z`` is zero at a position that is not ``real``
+    ``[b, t]``, so padding on the left adds nothing to the first tokens'
+    sums, to the bit: a row's state and what it is served do not follow
+    how far it was padded."""
+    taps, t = cfg.conv_taps, h.shape[1]
+    gate_in, gate_out, u = jnp.split(h @ lp["conv_in_w"].astype(cfg.dtype), 3, axis=-1)
+    z = gate_in * u
+    if real is not None:
+        z = jnp.where(real[:, :, None], z, 0)
+    before = jnp.zeros((h.shape[0], taps, cfg.hidden), z.dtype) if state is None else state["conv"].transpose(0, 2, 1)
+    seen = jnp.concatenate([before.astype(z.dtype), z], axis=1)  # [b, taps + t, hidden]: z[i] lies at taps + i
+    filt = lp["conv_w"].astype(jnp.float32)
+    c = sum(filt[:, j] * seen[:, 1 + j : 1 + j + t].astype(jnp.float32) for j in range(taps))
+    if state is not None:
+        state = {"conv": seen[:, t:].transpose(0, 2, 1).astype(state["conv"].dtype)}
+    return gate_out * c.astype(cfg.dtype), state
+
+
 # -- the layer stack ----------------------------------------------------------
 
 
@@ -704,7 +834,8 @@ def _stack(
     chunk_only: bool = False,
 ) -> tuple[jax.Array, Cache | None, ExpertStats]:
     """The layers over a chunk: hidden states ``[b, t, hidden]`` before the
-    final norm, the cache with the chunk appended, the experts' counts."""
+    final norm, the cache with the chunk appended (a ``"conv"`` layer's state
+    moved on by it), the experts' counts."""
     b, t = token_ids.shape
     x = params["tok_emb"][token_ids].astype(cfg.dtype)
     start = cache.length if cache is not None else jnp.zeros((), jnp.int32)
@@ -726,7 +857,9 @@ def _stack(
     for i, (lp, kind, attention) in enumerate(zip(params["layers"], cfg.layer_pattern, cfg.attention_pattern)):
         h = _norm(x, lp["attn_norm"], cfg)
         state = cache.layers[i] if cache is not None else None
-        if attention == "mla":
+        if attention == "conv":
+            a, state = _short_conv(h, lp, cfg, state, attn_mask)
+        elif attention == "mla":
             a, state = _mla_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only)
         else:
             wraps = state is not None and state["k"].shape[1] < cache.valid.shape[1]

@@ -5,7 +5,10 @@ over the experts tokens chose.
 the chip a float32 product otherwise runs in one bfloat16 pass, and a near
 tie would go to another expert than the one the published model takes) and
 keeps the ``k`` largest, greedily, with their weights as they are or
-renormalised: a softmax over all the experts, or a sigmoid of each.
+renormalised: a softmax over all the experts, or a sigmoid of each. A router
+with a selection bias (``select_bias``, one float32 an expert, a buffer the
+published model balances its experts' load with) picks by score plus bias
+and weighs by the score alone.
 
 ``routed_experts`` sorts the ``tokens * k`` (token, expert) pairs by expert
 and runs each expert's gated MLP over its own rows with
@@ -42,20 +45,28 @@ def route_top_k(
     renormalize: bool = False,
     scale: float = 1.0,
     scoring: str = "softmax",
+    select_bias: jax.Array | None = None,  # [experts] float32
 ) -> tuple[jax.Array, jax.Array]:
     """Scores of all experts in float32 (a softmax over them, or with
     ``scoring`` ``"sigmoid"`` each expert's own), the ``k`` largest:
     weights ``[n, k]`` float32 and expert ids ``[n, k]`` int32. A tie goes to
-    the expert with the lower id (``lax.top_k``)."""
+    the expert with the lower id (``lax.top_k``). With ``select_bias`` the
+    ``k`` largest of ``score + select_bias`` are chosen and their weights
+    are the scores without it; renormalised, they are divided by their sum
+    ``+ 1e-6``, as the published router that has such a bias divides."""
     logits = jnp.matmul(
         h.astype(jnp.float32),
         gate_w.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
     )
     scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" else jax.nn.softmax(logits, axis=-1)
-    weights, experts = lax.top_k(scores, k)
+    if select_bias is None:
+        weights, experts = lax.top_k(scores, k)
+    else:
+        _, experts = lax.top_k(scores + select_bias.astype(jnp.float32), k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
     if renormalize:
-        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+        weights = weights / (weights.sum(-1, keepdims=True) + (1e-20 if select_bias is None else 1e-6))
     return weights * scale, experts.astype(jnp.int32)
 
 

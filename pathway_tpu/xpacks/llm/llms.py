@@ -102,7 +102,8 @@ class TpuPipelineChat(UDF):
     programs are one prefill a bucket and one decode loop (the padding takes
     no routed expert: the programs get the mask, and ``chat.fetch`` counts
     the pairs left out, and of the real tokens' pairs those whose expert
-    this chip holds): ``chat_prefill``
+    this chip holds, and the bytes of the cache and of the states in it
+    whose size does not follow its slots): ``chat_prefill``
     (the prompts into a cache of ``max_prompt_len + max_new_tokens`` slots,
     the head at each row's last position) and ``chat_decode`` (the remaining
     tokens through the cache). A prompt over ``max_prompt_len`` tokens keeps
@@ -171,6 +172,16 @@ class TpuPipelineChat(UDF):
         self.last_generation: dict | None = None
         cfg = self.config
         cache_len = max_prompt_len + max_new_tokens
+
+        def cache_leaves(slots: int) -> list:
+            return jax.tree.leaves(jax.eval_shape(lambda: _decoder.init_cache(cfg, max_batch_size, slots)).layers)
+
+        # of a call's cache, the states whose size does not follow its slots (a "conv" layer's last inputs)
+        state_bytes = sum(
+            a.size * a.dtype.itemsize
+            for a, longer in zip(cache_leaves(cache_len), cache_leaves(cache_len + 1))
+            if a.shape == longer.shape
+        )
 
         # params ride as a runtime argument, not a closure (a closed-over
         # array is inlined into every bucket's module as a constant), and
@@ -272,6 +283,7 @@ class TpuPipelineChat(UDF):
                             decode_layer_steps=expert_layers * steps,
                             # what the call's cache holds: every layer's slots, a windowed layer's its ring
                             cache_bytes=sum(a.nbytes for a in jax.tree.leaves(cache.layers)),
+                            state_bytes=state_bytes,
                         )
                     self.last_generation = {
                         "rows": len(prompts), "bucket": width, "tokens": toks, "logits": logits,
