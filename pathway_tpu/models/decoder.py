@@ -62,7 +62,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from pathway_tpu.ops.moe import route_top_k, routed_experts
+from pathway_tpu.ops.moe import in_blocks, route_top_k, routed_experts, rows_walked
 from pathway_tpu.parallel.mesh import EXPERT_AXIS, MODEL_AXIS
 
 Params = dict
@@ -549,10 +549,14 @@ def _gated_mlp(h: jax.Array, gate_w: jax.Array, down_w: jax.Array) -> jax.Array:
 def _experts_layer(h: jax.Array, lp: Params, cfg: DecoderConfig, counted: jax.Array | None):
     """Routed plus shared experts over ``h`` ``[b, t, hidden]``; also how
     many of the ``counted`` ``[b, t]`` tokens' choices each expert held here
-    took (``None``: every token's) and how many of them took any. The other
-    tokens are padding and go through the shared experts alone. The shared
-    experts (where the layer has any) are one gated MLP as wide as all of
-    them, which is their sum; an average is that over their number."""
+    took (``None``: every token's), how many of them took any, and the sorted
+    rows the grouped products were handed for them. The other tokens are
+    padding and go through the shared experts alone, and where the call is
+    walked in blocks (``ops.moe.in_blocks``: a prefill) a row of the batch
+    that holds none but padding goes through none either: its result is
+    zero. The shared experts (where the layer has any) are one
+    gated MLP as wide as all of them, which is their sum; an average is that
+    over their number."""
     b, t, hidden = h.shape
     flat = h.reshape(b * t, hidden)
     weights, experts = route_top_k(
@@ -565,9 +569,17 @@ def _experts_layer(h: jax.Array, lp: Params, cfg: DecoderConfig, counted: jax.Ar
         None if counted is None else counted.reshape(-1), cfg.held_experts,
     )
     if cfg.n_shared_experts:
-        shared = _gated_mlp(flat, lp["shared_gate_w"], lp["shared_down_w"])
-        y = y + (shared / cfg.n_shared_experts if cfg.shared_combine == "average" else shared)
-    return y.reshape(b, t, hidden), load, jnp.count_nonzero(load).astype(jnp.int32)
+        def shared(rows: jax.Array) -> jax.Array:
+            return _gated_mlp(rows, lp["shared_gate_w"], lp["shared_down_w"])
+
+        if counted is None or not in_blocks(experts.size):
+            shared_y = shared(flat)
+        else:
+            shared_y = lax.map(lambda row: lax.cond(row[1].any(), shared, jnp.zeros_like, row[0]), (h, counted))
+            shared_y = shared_y.reshape(b * t, hidden)
+        y = y + (shared_y / cfg.n_shared_experts if cfg.shared_combine == "average" else shared_y)
+    walked = rows_walked(load, experts.size)
+    return y.reshape(b, t, hidden), load, jnp.count_nonzero(load).astype(jnp.int32), walked
 
 
 # -- cache --------------------------------------------------------------------
@@ -810,18 +822,23 @@ class ExpertStats(NamedTuple):
     layers, experts held here]`` int32, the choices of the real tokens each
     expert got; ``touched`` ``[]`` int32, over the expert layers the experts
     a real token chose: those whose weights the pass had to read (padding
-    takes no routed expert, nor does a choice of an expert held elsewhere)."""
+    takes no routed expert, nor does a choice of an expert held elsewhere);
+    ``walked`` ``[]`` int32, over the expert layers the sorted (token,
+    choice) rows the grouped products gathered and multiplied
+    (``ops.moe.rows_walked``)."""
 
     load: jax.Array
     touched: jax.Array
+    walked: jax.Array
 
     @staticmethod
     def none(cfg: DecoderConfig) -> "ExpertStats":
         n = sum(kind == "experts" for kind in cfg.layer_pattern)
-        return ExpertStats(jnp.zeros((n, max(cfg.experts_held, 1)), jnp.int32), jnp.zeros((), jnp.int32))
+        zero = jnp.zeros((), jnp.int32)
+        return ExpertStats(jnp.zeros((n, max(cfg.experts_held, 1)), jnp.int32), zero, zero)
 
     def __add__(self, other: "ExpertStats") -> "ExpertStats":  # type: ignore[override]
-        return ExpertStats(self.load + other.load, self.touched + other.touched)
+        return ExpertStats(*(mine + theirs for mine, theirs in zip(self, other)))
 
 
 def _stack(
@@ -853,7 +870,7 @@ def _stack(
         k_valid = real if chunk_only else valid_full
         if chunk_only:
             q_slot = q_slot - start  # slots within the chunk
-    states, loads, touched = [], [], jnp.zeros((), jnp.int32)
+    states, loads, touched, walked = [], [], jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32)
     for i, (lp, kind, attention) in enumerate(zip(params["layers"], cfg.layer_pattern, cfg.attention_pattern)):
         h = _norm(x, lp["attn_norm"], cfg)
         state = cache.layers[i] if cache is not None else None
@@ -870,13 +887,13 @@ def _stack(
             x, a = x + a, None
             h = _norm(x, lp["mlp_norm"], cfg)
         if kind == "experts":
-            y, load, n_touched = _experts_layer(h, lp, cfg, attn_mask)
+            y, load, n_touched, n_walked = _experts_layer(h, lp, cfg, attn_mask)
             loads.append(load)
-            touched = touched + n_touched
+            touched, walked = touched + n_touched, walked + n_walked
         else:
             y = _gated_mlp(h, lp["gate_w"], lp["down_w"])
         x = x + y if a is None else x + a + y
-    stats = ExpertStats(jnp.stack(loads), touched) if loads else ExpertStats.none(cfg)
+    stats = ExpertStats(jnp.stack(loads), touched, walked) if loads else ExpertStats.none(cfg)
     if cache is not None:
         cache = Cache(layers=states, length=start + t, valid=valid_full)
     return x, cache, stats
@@ -943,7 +960,7 @@ def prefill(
     logits, layers, valid, stats = lax.map(group, (split(prompt_ids), split(prompt_mask), split(pos_offset)))
     join = lambda a: a.reshape((b,) + a.shape[2:])  # noqa: E731
     cache = Cache(jax.tree.map(join, layers), jnp.asarray(t, jnp.int32), join(valid))
-    return join(logits), cache, pos_offset, ExpertStats(stats.load.sum(0), stats.touched.sum(0))
+    return join(logits), cache, pos_offset, jax.tree.map(lambda a: a.sum(0), stats)
 
 
 def decode_step(

@@ -17,10 +17,23 @@ expert over every token, and an expert's weights are read only where it has
 rows. There is no capacity: every pair is computed whatever the skew, all
 tokens on one expert included. Padding takes no expert: given the mask of
 real tokens, a padding token's pairs are sorted behind the last expert's
-rows and belong to no group, so they are not computed, an expert that only
-padding chose has no row and its weights are not read, and a padding
-token's result is zero. The per-expert row counts of the real tokens come
-back for the callers' counters (``expert_sizes``).
+rows and belong to no group, an expert that only padding chose has no row
+and its weights are not read, and a padding token's result is zero. The
+per-expert row counts of the real tokens come back for the callers'
+counters (``expert_sizes``).
+
+What is not computed is not walked either. A call of more pairs than a block
+(``BLOCK_ROWS``: a prefill's group of rows) goes through the products in
+blocks of the sorted rows, as many blocks as the pairs in a group fill
+(``rows_walked``; a traced trip count, so every pair is still computed where
+every pair lies in a group): a block gathers its own rows of ``h``, takes of
+each expert's group what lies in it, and writes its rows of the result; the
+pairs behind the last group are never gathered, multiplied or written. The
+way back is walked the same way: the result comes back to (token, choice)
+order in blocks of tokens, and a block in which no token took a pair here is
+not gathered. An expert whose group straddles two blocks is read twice. A
+call no longer than a block (a decode step) is one product over all its rows
+and holds no loop.
 
 A chip that holds a share of a layer's experts says which (``held``: the
 first and how many; the weights it hands over are theirs): the router is as
@@ -79,6 +92,30 @@ def expert_sizes(experts: jax.Array, n_experts: int) -> jax.Array:
     return hit.sum((0, 1), dtype=jnp.int32)
 
 
+#: the sorted (token, choice) rows one grouped product is handed where a call
+#: holds more: fixed from a sweep on the chip over the prefill shapes of three
+#: models (12,288 to 24,576 pairs, 16 and 64 experts, hidden 2,048 and 4,096:
+#: 1,024 and 2,048 read alike, 4,096 worse; PERF.md section 6, PR 39)
+BLOCK_ROWS = 2048
+
+
+def in_blocks(pairs: int) -> bool:
+    """Whether a call of ``pairs`` pairs is walked in blocks, as far as what
+    is computed reaches (a prefill's group of rows), or is no longer than one
+    and goes whole, in one product and no loop (a decode step)."""
+    return pairs > BLOCK_ROWS
+
+
+def rows_walked(sizes: jax.Array, pairs: int) -> jax.Array:
+    """The sorted rows ``routed_experts`` gathers and multiplies for group
+    sizes ``sizes`` in a call of ``pairs`` pairs, ``[]`` int32: whole blocks
+    as far as the pairs in a group reach, or all ``pairs`` where the call
+    goes whole."""
+    if not in_blocks(pairs):
+        return jnp.asarray(pairs, jnp.int32)
+    return (sizes.sum(dtype=jnp.int32) + (BLOCK_ROWS - 1)) // BLOCK_ROWS * BLOCK_ROWS
+
+
 def routed_experts(
     h: jax.Array,  # [n, hidden]
     weights: jax.Array,  # [n, k] float32
@@ -107,19 +144,63 @@ def routed_experts(
     order = jnp.argsort(keys.reshape(-1))  # stable: an expert's rows stay in token order
     token = order // k
     sizes = expert_sizes(keys, n_experts)
-    x = h[token]
-    gate_up = lax.ragged_dot(x, gate_up_w.astype(h.dtype), sizes, preferred_element_type=jnp.float32)
-    gate, up = jnp.split(gate_up, 2, axis=-1)
-    act = (jax.nn.silu(gate) * up).astype(h.dtype)
-    out = lax.ragged_dot(act, down_w.astype(h.dtype), sizes, preferred_element_type=jnp.float32)
-    # back to (token, choice) order with a gather (the inverse permutation):
-    # a scatter-add over the sorted rows costs the chip far more
-    out = out[jnp.argsort(order)].reshape(n, k, -1)
-    if computed is not None:
-        # the rows past the groups hold whatever the device left there: the
-        # mask decides what a pair outside every group adds (nothing, whatever
-        # its weight), not those rows
-        out = jnp.where(computed[..., None], out, 0.0)
-        weights = jnp.where(computed, weights, 0.0)
-    y = (out * weights[..., None]).sum(1)
-    return y.astype(h.dtype), sizes
+    back = jnp.argsort(order).reshape(n, k)  # where each pair's row lies in the sorted order
+    pairs = n * k
+
+    def product(rows_of: jax.Array, groups: jax.Array) -> jax.Array:
+        """The experts over sorted rows, each the token's ``rows_of`` names,
+        of which each expert's group is ``groups`` long: ``[rows, hidden]``
+        float32."""
+        gate_up = lax.ragged_dot(h[rows_of], gate_up_w.astype(h.dtype), groups, preferred_element_type=jnp.float32)
+        gate, up = jnp.split(gate_up, 2, axis=-1)
+        act = (jax.nn.silu(gate) * up).astype(h.dtype)
+        return lax.ragged_dot(act, down_w.astype(h.dtype), groups, preferred_element_type=jnp.float32)
+
+    def combine(out: jax.Array, back: jax.Array, weights: jax.Array, computed: jax.Array | None) -> jax.Array:
+        """Some tokens' rows of the sorted-order result ``out`` back in
+        (token, choice) order with a gather (the inverse permutation: a
+        scatter-add over the sorted rows costs the chip far more), weighed
+        and summed: ``[tokens, hidden]`` in ``h``'s dtype."""
+        rows = out[back]  # [tokens, k, hidden] float32
+        if computed is not None:
+            # the rows past the groups hold whatever the device left there:
+            # the mask decides what a pair outside every group adds (nothing,
+            # whatever its weight), not those rows
+            rows = jnp.where(computed[..., None], rows, 0.0)
+            weights = jnp.where(computed, weights, 0.0)
+        return (rows * weights[..., None]).sum(1).astype(h.dtype)
+
+    if not in_blocks(pairs):
+        return combine(product(token, sizes), back, weights, computed), sizes
+
+    # the computed pairs are the first sizes.sum() of the sorted rows: as many
+    # blocks as they fill are gathered, multiplied and written, and an
+    # expert's group in a block is what of it lies there
+    block = BLOCK_ROWS
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    token = jnp.pad(token, (0, -pairs % block))  # a last block's rows past the pairs lie in no group
+
+    def one_block(i, out):
+        lo = i * block
+        within = jnp.clip(ends, lo, lo + block) - jnp.clip(starts, lo, lo + block)
+        rows = product(lax.dynamic_slice(token, (lo,), (block,)), within)
+        return lax.dynamic_update_slice(out, rows, (lo, 0))
+
+    out = lax.fori_loop(
+        0, rows_walked(sizes, pairs) // block, one_block, jnp.zeros((token.shape[0], h.shape[1]), jnp.float32)
+    )
+    # and back in blocks of the tokens that name a block of pairs: one in
+    # which no token took a pair is not gathered, its rows of the result are zero
+    tokens = max(block // k, 1)
+    taken = jnp.ones((n, k), bool) if computed is None else computed
+
+    def one_back(args):  # a block's (back, weights, taken)
+        return lax.cond(
+            args[2].any(), lambda: combine(out, *args), lambda: jnp.zeros((tokens, h.shape[1]), h.dtype)
+        )
+
+    y = lax.map(
+        one_back, tuple(jnp.pad(a, ((0, -n % tokens), (0, 0))).reshape(-1, tokens, k) for a in (back, weights, taken))
+    )
+    return y.reshape(-1, h.shape[1])[:n], sizes

@@ -100,10 +100,15 @@ class TpuPipelineChat(UDF):
     that holds the longest (powers of two up to ``max_prompt_len`` where none
     are given) and its rows to ``max_batch_size``, always, so the compiled
     programs are one prefill a bucket and one decode loop (the padding takes
-    no routed expert: the programs get the mask, and ``chat.fetch`` counts
-    the pairs left out, and of the real tokens' pairs those whose expert
-    this chip holds, and the bytes of the cache and of the states in it
-    whose size does not follow its slots): ``chat_prefill``
+    no routed expert and is not walked by a prefill's expert layers, which go
+    over the pairs an expert held here took, in blocks, back over the tokens
+    that took one, and through the shared experts with the rows that hold a
+    real token: the programs get the mask, and ``chat.fetch`` counts the
+    pairs left out, of the real tokens' pairs those whose expert this chip
+    holds, the sorted rows the grouped products were handed, and the bytes
+    of the cache and of the states in it whose size does not follow its
+    slots; attention, dense layers and the head are still paid for every row
+    of the cap): ``chat_prefill``
     (the prompts into a cache of ``max_prompt_len + max_new_tokens`` slots,
     the head at each row's last position) and ``chat_decode`` (the remaining
     tokens through the cache). A prompt over ``max_prompt_len`` tokens keeps
@@ -265,6 +270,7 @@ class TpuPipelineChat(UDF):
                         toks = np.concatenate([first[:, None], rest], axis=1)
                         logits = np.concatenate([first_logit[:, None], rest_logits], axis=1)
                         load = pre.load + dec.load  # [expert layers, experts held here]
+                        walked = int(pre.walked + dec.walked)  # sorted rows the grouped products were handed
                         # the (token, choice) pairs of the call's shapes, and
                         # those of them that were padding and took no expert
                         steps = max_new_tokens - 1
@@ -279,6 +285,7 @@ class TpuPipelineChat(UDF):
                             expert_pairs_skipped=pairs_per_token * (ids.size - real_tokens + pad_rows * steps),
                             # of the real tokens' pairs, those an expert held here took
                             expert_pairs_held=int(load.sum()),
+                            expert_rows_walked=walked,
                             decode_touched=int(dec.touched),
                             decode_layer_steps=expert_layers * steps,
                             # what the call's cache holds: every layer's slots, a windowed layer's its ring
@@ -291,6 +298,7 @@ class TpuPipelineChat(UDF):
                         "expert_load": load, "prefill_touched": int(pre.touched),
                         "decode_touched": int(dec.touched),
                         "prefill_pairs_held": int(pre.load.sum()), "decode_pairs_held": int(dec.load.sum()),
+                        "expert_rows_walked": walked,
                     }
                 with _tracing.detail("chat.detokenize"):
                     return [self.tokenizer.decode(list(row)) for row in toks[: len(prompts)]]
