@@ -326,9 +326,8 @@ class InputDriver:
         #: arrival (``time.monotonic``) of the oldest row fed to the session
         #: and not yet committed: when its reader saw it arrive, or the poll
         #: that found it where the reader cannot say. The pump counts the
-        #: autocommit window from it; the runner pops it per commit for the
-        #: stage's ``commit_wait_ns``, the ingest-wait span and the
-        #: ingest->sink latency histogram
+        #: autocommit window from it; the runner pops it per commit, with
+        #: the one below (``take_pending``)
         self.first_pending_wall: float | None = None
         #: when the poll that set ``first_pending_wall`` ran: the two differ
         #: by the time the row queued while the pump was away
@@ -549,6 +548,14 @@ class InputDriver:
                 self.sync_group.mark_done(self)
             return "done"
         return "data" if produced else "idle"
+
+    def take_pending(self) -> tuple[float | None, float | None]:
+        """Pop what a commit takes from this driver: its oldest pending
+        row's arrival and the time of the poll that took it (both by
+        ``time.monotonic``; None where no row reached the session)."""
+        taken = self.first_pending_wall, self.first_pending_polled
+        self.first_pending_wall = self.first_pending_polled = None
+        return taken
 
 
 class BatchScheduleDriver:

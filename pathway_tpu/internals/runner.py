@@ -67,30 +67,33 @@ _INGEST_LATENCY = _metrics.REGISTRY.histogram(
 _OUT_ROWS = _metrics.REGISTRY.counter("pathway_output_rows_total")
 
 
-def _take_ingest_stamp(
-    drivers: list,
-) -> tuple[float | None, float | None, list[str]]:
-    """Pop the oldest pending-row arrival stamp across connector drivers
-    (InputDriver.poll sets it when rows enter a session: the row's arrival
-    at its reader, or that poll's time where the reader cannot say); the
-    commit that follows delivers those rows, closing the latency window.
-    Also returns when the poll that took that oldest row ran, and the
-    source names whose stamps were popped — the tracing ingest-wait span
-    labels itself with them."""
-    best = polled = None
-    sources: list[str] = []
-    for d in drivers:
-        inner = getattr(d, "driver", d)
-        stamp = getattr(inner, "first_pending_wall", None)
-        if stamp is not None:
-            inner.first_pending_wall = None
+class _Arrivals:
+    """What one commit took from its connector drivers: the one place a
+    commit's arrival stamps are computed. ``oldest`` is the arrival of the
+    commit's oldest row (``InputDriver.poll`` sets it when rows enter a
+    session: the row's arrival at its reader, or that poll's time where
+    the reader cannot say) and ``polled`` when the poll that took that row
+    ran, both by ``time.monotonic`` and None for a commit with no row;
+    ``sources`` names the drivers that gave a row. The stage's
+    ``commit_wait_ns`` and ``arrival_to_poll_ns``, the sampled trace's
+    ``origin_mono`` and the latency histogram's origin all come from here."""
+
+    __slots__ = ("oldest", "polled", "sources")
+
+    def __init__(self, drivers: list) -> None:
+        self.oldest = self.polled = None
+        self.sources: list[str] = []
+        for d in drivers:
+            inner = getattr(d, "driver", d)
+            take = getattr(inner, "take_pending", None)
+            oldest, polled = take() if take is not None else (None, None)
+            if oldest is None:
+                continue
             name = getattr(inner, "source_name", None)
             if name:
-                sources.append(str(name))
-            if best is None or stamp < best:
-                best = stamp
-                polled = getattr(inner, "first_pending_polled", None)
-    return best, polled, sources
+                self.sources.append(str(name))
+            if self.oldest is None or oldest < self.oldest:
+                self.oldest, self.polled = oldest, polled
 
 
 def _elapsed_ns(since: float | None, until: float | None) -> int:
@@ -155,8 +158,8 @@ def _resume_and_commit(sched, scopes: list, drivers: list, snapshot_mgr) -> None
         restored_time = snapshot_mgr.restore(scopes, drivers)
         if restored_time is not None:
             sched.time = max(sched.time, restored_time + 1)
-    with _tracing.stage("commit"):
-        sched.commit()
+    with _tracing.commit_stage() as commit:
+        commit.time = sched.commit()
 
 
 def _commit_step(
@@ -164,30 +167,32 @@ def _commit_step(
 ) -> tuple[int, float]:
     """One data commit of any runner, inside the ``commit`` stage the pump
     hands over. The four recorders every commit pays stand side by side
-    here: the stage's counts, the sampled trace, the latency histogram
-    and the flight ring. The counts are of the commit's oldest row, from
-    its arrival: ``commit_wait_ns`` until the commit began, and
-    ``arrival_to_poll_ns`` until the poll that took it, which is the part
-    of its autocommit window the pump did not wait again (0 for a row
-    polled as it arrived). The mesh leader passes what only it
-    has: ``announce`` runs between the trace's begin and the commit (the
-    context tuple rides the first exchange round's frames, so followers
-    adopt it at commit start) and ``peer_spans`` is where the followers'
-    spans arrive. Returns the commit's time and when it started."""
+    here: the stage's counts and its record on the time line, the sampled
+    trace, the latency histogram and the flight ring; what they know of
+    the rows' arrivals is one :class:`_Arrivals`. The counts are of the
+    commit's oldest row, from its arrival: ``commit_wait_ns`` until the
+    commit began, and ``arrival_to_poll_ns`` until the poll that took it,
+    which is the part of its autocommit window the pump did not wait
+    again (0 for a row polled as it arrived). The mesh leader passes what
+    only it has: ``announce`` runs between the trace's begin and the commit
+    (the context tuple rides the first exchange round's frames, so
+    followers adopt it at commit start) and ``peer_spans`` is where the
+    followers' spans arrive. Returns the commit's time and when it
+    started."""
     started = _time.monotonic()
-    stamp, polled, sources = _take_ingest_stamp(drivers)
+    arrivals = _Arrivals(drivers)
     commit.add(
-        commit_wait_ns=_elapsed_ns(stamp, started),
-        arrival_to_poll_ns=_elapsed_ns(stamp, polled),
+        commit_wait_ns=_elapsed_ns(arrivals.oldest, started),
+        arrival_to_poll_ns=_elapsed_ns(arrivals.oldest, arrivals.polled),
     )
     rows_before = _OUT_ROWS.value
     ctx = _tracing.TRACER.begin(
-        sched.time, origin_mono=stamp, sources=sources
+        sched.time, origin_mono=arrivals.oldest, sources=arrivals.sources
     )
     if announce is not None:
         announce()
-    time = sched.commit()
-    _observe_commit_latency(stamp, started, rows_before)
+    commit.time = time = sched.commit()
+    _observe_commit_latency(arrivals.oldest, started, rows_before)
     _metrics.FLIGHT.record("commit", time=time)
     if ctx is not None:
         _tracing.TRACER.end(
@@ -244,7 +249,8 @@ def _end_run(
 ) -> None:
     """A run's last commit (a ``commit`` stage too), then the traces, the
     journal's last offsets and the final snapshot."""
-    with _tracing.stage("commit"):
+    with _tracing.commit_stage() as commit:
+        commit.time = sched.time
         sched.finish()
     _tracing.TRACER.export()
     for d in persistent:
@@ -323,7 +329,7 @@ def _pump_drivers(w0: "GraphRunner", drivers: list, on_data, on_idle=None) -> No
                 pending = True
         if pending and (flush_now or _time.monotonic() >= deadline):
             end_poll()
-            with _tracing.stage("commit") as commit:
+            with _tracing.commit_stage() as commit:
                 on_data(commit)
             pending = False
             idle_spins = 0
@@ -1200,7 +1206,8 @@ class GraphRunner:
     def run_static(self) -> Scheduler:
         sched = self._make_scheduler()
         t0 = _time.monotonic()
-        with _tracing.stage("commit"):
+        with _tracing.commit_stage() as commit:
+            commit.time = 0  # every static source's rows are of time 0
             sched.run_static()
         _after_commit(sched.time, [self.scope], self.drivers, t0, w0=self)
         return sched
@@ -1217,8 +1224,8 @@ class GraphRunner:
         if persistent:
             # flush replayed events as the first commit so downstream state
             # is rebuilt even if no new input arrives
-            with _tracing.stage("commit"):
-                sched.commit()
+            with _tracing.commit_stage() as commit:
+                commit.time = sched.commit()
         snapshot_mgr = self._operator_snapshot_manager()
         _resume_and_commit(sched, scopes, drivers, snapshot_mgr)
 
@@ -1860,8 +1867,8 @@ class DistributedGraphRunner:
             # would shift every later commit timestamp off the
             # uninterrupted run's numbering, breaking sink bit-identity.
             transport.broadcast(("cmd", "commit"))
-            with _tracing.stage("commit"):
-                barrier_time = sched.commit()
+            with _tracing.commit_stage() as commit:
+                commit.time = barrier_time = sched.commit()
             # followers snapshot (and publish) EVERY commit, including this
             # one; the leader must too, or a worker that dies before the
             # first data commit forces a rollback to a boundary the leader

@@ -55,17 +55,25 @@ runs, so the program's stages lie on the device trace's clock, and (3)
 the sampled commit's span list, when there is one. A stage that no
 metric reads from the table is opened with :func:`detail` instead: it
 exists only while reader (2) or (3) is there to see it.
+
+The table is a sum over the run; its time line (:func:`commit_timeline`)
+is one record a commit stage of the run thread (:func:`commit_stage`): the
+commit's time, its interval, and every stage that exited inside it, folded
+by name. A full garbage collection is a stage too (``gc.full``), on
+whichever thread the collector ran.
 """
 
 from __future__ import annotations
 
 import functools
+import gc
 import json
 import os
 import sys
 import tempfile
 import threading
 import time as _time
+from array import array
 from collections import deque
 from typing import Any
 
@@ -94,6 +102,8 @@ __all__ = [
     "begin",
     "end",
     "stage_totals",
+    "commit_stage",
+    "commit_timeline",
     "traced_run",
 ]
 
@@ -1236,6 +1246,16 @@ def _active_trace_id() -> str | None:
 #: time is what the run thread did outside every other stage
 RUN_STAGE = "run"
 
+#: name of the stage a commit runs under; the run thread's make the time line
+COMMIT_STAGE = "commit"
+
+#: name of the stage a full (generation 2) garbage collection runs under
+GC_STAGE = "gc.full"
+
+#: commits the time line keeps (``bge-live-rag``, the busiest cell, makes
+#: some 580 a window)
+TIMELINE_COMMITS = 1024
+
 _now = _time.perf_counter_ns
 
 #: ``jax.profiler.TraceAnnotation`` once jax has been imported by someone
@@ -1303,12 +1323,14 @@ class _Stage:
             have[key] = have.get(key, 0) + value
 
     def __enter__(self) -> "_Stage":
-        self.thread.stack.append(self)
         annotation = _trace_annotation  # resolved when the run began
         if annotation is not None and annotation.is_enabled():
             # a jax.profiler session is running: the stage goes into it
             self.annotation = annotation("pw:" + self.name)
             self.annotation.__enter__()
+        # on the stack last: a collection that falls in here is a child of
+        # the stage that was open, not of this one before its clock runs
+        self.thread.stack.append(self)
         self.t0 = _now()
         return self
 
@@ -1335,6 +1357,9 @@ class _Stage:
             have = entry[4]
             for key, value in counts.items():
                 have[key] = have.get(key, 0) + value
+        commit = self.owner._commit
+        if commit is not None and th is commit.thread:
+            commit.fold(self, t1)
         ctx = TRACER._ctx
         if ctx is not None and th is self.owner._run_thread:
             # the sampled commit's context belongs to the run thread
@@ -1344,6 +1369,50 @@ class _Stage:
             cat = self.cat or ("device_wait" if self.wait else "stage")
             ctx.span(name, cat, self.t0 / 1e9, t1 / 1e9, **args)
         return False
+
+
+class _CommitStage(_Stage):
+    """A ``commit`` stage (:func:`commit_stage`). The outermost one open on
+    the run thread is the time line's open record
+    (:meth:`StageTable.timeline`): whoever commits inside it leaves the
+    commit's ``time`` here, and every stage that exits on its thread while
+    it is open is folded into it by name."""
+
+    __slots__ = ("time", "places", "folded")
+
+    def __enter__(self) -> "_CommitStage":
+        self.time: int | None = None
+        #: a folded stage's place in ``folded``, by name
+        self.places: dict[str, int] = {}
+        #: ``first_t0_ns, last_t1_ns, calls`` of each folded stage, flat
+        self.folded: list[int] = []
+        super().__enter__()
+        owner = self.owner
+        if owner._commit is None and self.thread is owner._run_thread:
+            owner._commit = self
+        return self
+
+    def fold(self, st: _Stage, t1: int) -> None:
+        """A stage exited on this commit's thread: the commit's own closes
+        the record, any other is folded into it by name."""
+        if st is self:
+            owner = self.owner
+            owner._commit = None
+            # two tuples and one array a record: nothing the collector
+            # has to walk however long the ring
+            owner._timeline.append((
+                self.time, self.t0, t1,
+                tuple(self.places), array("q", self.folded),
+            ))
+            return
+        folded = self.folded
+        place = self.places.get(st.name)
+        if place is None:
+            self.places[st.name] = len(folded)
+            folded += (st.t0, t1, 1)
+        else:
+            folded[place + 1] = t1
+            folded[place + 2] += 1
 
 
 class StageTable:
@@ -1358,12 +1427,19 @@ class StageTable:
     included, sum to ``run_wall_ns`` exactly."""
 
     def __init__(self) -> None:
-        self._lock = threading.Lock()
+        # re-entrant: a collection that falls while it is held opens a
+        # stage (``_on_collection``), on a thread that may have no table yet
+        self._lock = threading.RLock()
         self._threads: list[_ThreadStages] = []  # guarded-by: self._lock
         self._local = threading.local()
         self._run_thread: _ThreadStages | None = None
         self._root: _Stage | None = None
         self._run_wall_ns = 0
+        #: the time line: the commit open on the run thread and the records
+        #: of the closed ones
+        self._commit: _CommitStage | None = None
+        self._timeline: deque = deque(maxlen=TIMELINE_COMMITS)
+        self._collection: _Stage | None = None  # the open ``gc.full``
 
     def _thread(self) -> _ThreadStages:
         try:
@@ -1391,6 +1467,14 @@ class StageTable:
             thread = self._thread()
         return _Stage(self, thread, name, cat, wait, label, counts)
 
+    def commit_stage(self) -> _CommitStage:
+        """The stage a runner commits under: ``with commit_stage() as
+        commit`` and ``commit.time = ...`` inside. The run thread's are the
+        time line's records (:meth:`timeline`)."""
+        return _CommitStage(
+            self, self._thread(), COMMIT_STAGE, None, False, None, {}
+        )
+
     def begin_run(self) -> _Stage | None:
         """Zero every thread's table and open the run's own stage on this
         thread. A run begun while another is open (an iterate body, a
@@ -1408,6 +1492,9 @@ class StageTable:
         th.stack.clear()
         self._run_thread = th
         self._run_wall_ns = 0
+        self._commit = self._collection = None
+        self._timeline.clear()
+        gc.callbacks.append(self._on_collection)
         self._root = self.stage(RUN_STAGE).__enter__()
         return self._root
 
@@ -1417,6 +1504,50 @@ class StageTable:
         root.__exit__(None, None, None)
         self._run_wall_ns = root.thread.table[RUN_STAGE][1]
         self._root = None
+        try:
+            gc.callbacks.remove(self._on_collection)
+        except ValueError:
+            pass
+
+    def _on_collection(self, phase: str, info: dict) -> None:
+        """``gc.callbacks``' hook while a run is open: a full collection is
+        a ``gc.full`` stage of the thread it ran on, so its time leaves the
+        self time of the stage it interrupted; inside a commit of the run
+        thread it is folded into the record like any stage."""
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            self._collection = self.stage(GC_STAGE).__enter__()
+            return
+        st, self._collection = self._collection, None
+        if st is not None:
+            st.__exit__(None, None, None)
+
+    def timeline(self) -> list[dict]:
+        """One record a commit stage of the run thread in the current (or
+        last) run, oldest first, the newest :data:`TIMELINE_COMMITS`, every
+        stamp in ``perf_counter_ns``: ``time`` (what ``Scheduler.commit()``
+        returned: the identifier every sink callback of the commit is
+        handed), ``t0_ns`` and ``t1_ns`` (the stage's own reads, so the
+        interval is its ``pw:commit`` annotation's) and ``stages`` (every
+        stage that exited on the run thread while the commit was open, by
+        name: ``first_t0_ns``, ``last_t1_ns``, ``calls``)."""
+        return [
+            {
+                "time": time,
+                "t0_ns": t0,
+                "t1_ns": t1,
+                "stages": {
+                    name: {
+                        "first_t0_ns": folded[place],
+                        "last_t1_ns": folded[place + 1],
+                        "calls": folded[place + 2],
+                    }
+                    for place, name in zip(range(0, len(folded), 3), names)
+                },
+            }
+            for time, t0, t1, names, folded in list(self._timeline)
+        ]
 
     def totals(self) -> dict:
         """``{"run_wall_ns", "running", "stages", "threads"}``: ``stages``
@@ -1465,6 +1596,8 @@ STAGES = StageTable()
 
 stage = STAGES.stage
 stage_totals = STAGES.totals
+commit_stage = STAGES.commit_stage
+commit_timeline = STAGES.timeline
 
 
 class _NoStage:

@@ -13,6 +13,7 @@ if "xla_force_host_platform_device_count" not in flags:
     ).strip()
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -22,3 +23,22 @@ def pytest_configure(config):
         "markers",
         "slow: long-running chaos soak / scale tests excluded from tier-1",
     )
+
+
+@pytest.fixture
+def own_stage_table():
+    """For a test that reads ``tracing.stage_totals()`` or
+    ``commit_timeline()`` after its own ``pw.run()``: a run that an earlier
+    test of this worker left open (a ``pw.run()`` on a thread nobody
+    stopped) keeps the process-wide table, since a run begun while another
+    is open leaves the table alone, and the test would read that run's
+    rows. Close it, and take its collector's hook away."""
+    import gc
+
+    from pathway_tpu.internals import tracing
+
+    tracing.STAGES._root = None
+    gc.callbacks[:] = [
+        hook for hook in gc.callbacks
+        if getattr(hook, "__self__", None) is not tracing.STAGES
+    ]

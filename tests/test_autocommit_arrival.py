@@ -52,7 +52,7 @@ class FakeClock:
 
 
 @pytest.fixture
-def clock(monkeypatch):
+def clock(monkeypatch, own_stage_table):
     fake = FakeClock()
     monkeypatch.setattr(runner, "_time", fake)
     monkeypatch.setattr(connectors, "_time", fake)
@@ -198,10 +198,15 @@ def test_the_stamp_is_the_oldest_uncommitted_push_and_the_commit_pops_it(clock):
     clock.now = T0 + 3.5
     assert driver.poll() == "data"
     assert driver.first_pending_wall == T0 + 1.0
-    assert runner._take_ingest_stamp([driver]) == (
+    took = runner._Arrivals([driver])
+    assert (took.oldest, took.polled, took.sources) == (
         T0 + 1.0, T0 + 2.0, [driver.source_name],
     )
     assert driver.first_pending_wall is None
+    assert driver.first_pending_polled is None
+    # popped once: a second commit with no new row has no stamp
+    again = runner._Arrivals([driver])
+    assert (again.oldest, again.polled, again.sources) == (None, None, [])
     # the next commit's oldest row is the next push
     clock.now = T0 + 4.0
     reader.push(4)
@@ -218,8 +223,8 @@ def test_a_reader_with_no_stamps_gives_the_polls_time(clock):
     assert driver.poll() == "data"
     assert driver.first_pending_wall == T0 + 2.0
     assert driver.first_pending_polled == T0 + 2.0
-    stamp, polled, _sources = runner._take_ingest_stamp([driver])
-    assert runner._elapsed_ns(stamp, polled) == 0
+    took = runner._Arrivals([driver])
+    assert runner._elapsed_ns(took.oldest, took.polled) == 0
 
 
 def test_the_oldest_stamp_of_several_drivers_brings_its_own_poll(clock):
@@ -233,9 +238,9 @@ def test_the_oldest_stamp_of_several_drivers_brings_its_own_poll(clock):
     assert drivers[0].poll() == "data"
     clock.now = T0 + 4.0
     assert drivers[1].poll() == "data"
-    stamp, polled, sources = runner._take_ingest_stamp(drivers)
-    assert (stamp, polled) == (T0 + 1.0, T0 + 4.0)
-    assert len(sources) == 2
+    took = runner._Arrivals(drivers)
+    assert (took.oldest, took.polled) == (T0 + 1.0, T0 + 4.0)
+    assert len(took.sources) == 2
     assert all(d.first_pending_wall is None for d in drivers)
 
 
@@ -260,7 +265,7 @@ def test_an_event_a_synchronization_group_held_counts_from_its_release(clock):
     # fast's first event waited for slow's frontier: held and released
     assert fast.first_pending_wall == T0 + 2.0
     assert slow.first_pending_wall == T0 + 1.0
-    runner._take_ingest_stamp(drivers)
+    runner._Arrivals(drivers)
     clock.now = T0 + 5.0
     readers[1].push(45)
     clock.now = T0 + 6.0

@@ -19,6 +19,8 @@ import pytest
 from pathway_tpu.internals import metrics as _metrics
 from pathway_tpu.internals import tracing
 
+pytestmark = pytest.mark.usefixtures("own_stage_table")
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -1030,6 +1032,347 @@ class TestStagesOfARun:
         )
         assert out.returncode == 0, out.stderr[-2000:]
         assert out.stdout.strip().splitlines()[-1] == "False"
+
+
+_perf_ns = time.perf_counter_ns  # the stages' clock
+
+
+class _PushClock:
+    """Stands in for the ``time`` module of ``engine/connectors.py``: the
+    real clock, keeping every stamp a thread other than the pump's took,
+    which are the stamps ``QueueReader.push`` put on the rows."""
+
+    def __init__(self) -> None:
+        self.pump = threading.get_ident()
+        self.pushed: dict[int, list[float]] = {}  # feed's thread -> stamps
+        self.sleep = time.sleep
+
+    def monotonic(self) -> float:
+        now = time.monotonic()
+        thread = threading.get_ident()
+        if thread != self.pump:
+            self.pushed.setdefault(thread, []).append(now)
+        return now
+
+
+def _run_two_feeds(monkeypatch=None, rows=(12, 5)):
+    """A streaming ``pw.run()`` with two Python connectors (a windowed one
+    and one that commits at its poll) and a sink each. Returns what each
+    sink saw, as ``(commit time, perf_counter_ns inside the callback, which
+    of its feed's rows)``, and, with ``monkeypatch``, the clock that kept
+    the pushes' stamps with each feed's thread."""
+    import pathway_tpu as pw
+    from pathway_tpu.engine import connectors
+    from pathway_tpu.internals.parse_graph import G
+
+    G.clear()
+    clock = None
+    if monkeypatch is not None:
+        clock = _PushClock()
+        monkeypatch.setattr(connectors, "_time", clock)
+    threads: list = [None, None]
+
+    def feed(place: int, n: int, pause_every: int):
+        class Feed(pw.io.python.ConnectorSubject):
+            def run(self) -> None:
+                threads[place] = threading.get_ident()
+                for i in range(n):
+                    self.next(a=1000 * place + i)
+                    if i % pause_every == pause_every - 1:
+                        time.sleep(0.02)
+
+        return Feed()
+
+    seen: list[list] = [[], []]
+    for place, (n, window, pause_every) in enumerate(
+        ((rows[0], 10, 4), (rows[1], None, 1))
+    ):
+        table = pw.io.python.read(
+            feed(place, n, pause_every), schema=pw.schema_from_types(a=int),
+            autocommit_duration_ms=window,
+        )
+        pw.io.subscribe(
+            table,
+            on_change=lambda key, row, time, is_addition, place=place: seen[
+                place
+            ].append((time, _perf_ns(), row["a"] % 1000)),
+        )
+    pw.run()
+    G.clear()
+    return seen, clock, threads
+
+
+def _arrivals_by_commit(seen, clock, threads) -> dict[int, list[float]]:
+    """``commit time -> the arrivals of the rows a sink saw in it``, on the
+    stages' clock: ``QueueReader.push`` stamped them with ``time.monotonic``
+    (kept by the clock with each feed's thread), and the two clocks'
+    distance is read here."""
+    distance_ns = time.perf_counter_ns() - int(time.monotonic() * 1e9)
+    arrived: dict[int, list[float]] = {}
+    for place, rows in enumerate(seen):
+        stamps = clock.pushed[threads[place]]
+        assert len(stamps) == len(rows)
+        for commit_time, _at, which in rows:
+            arrived.setdefault(commit_time, []).append(
+                stamps[which] * 1e9 + distance_ns
+            )
+    return arrived
+
+
+class TestCommitTimeLine:
+    """The stage table's time line (ISSUE 40): one record a commit of the
+    run thread, and full collections as a stage of their own."""
+
+    def test_one_record_for_every_commit_time_a_sink_saw(self):
+        seen, _clock, _threads = _run_two_feeds()
+        assert [len(rows) for rows in seen] == [12, 5]
+        commits = tracing.commit_timeline()
+        times = [record["time"] for record in commits]
+        # the first commit, the data commits and the last one: each once
+        assert len(times) == len(set(times))
+        assert times == sorted(times)
+        totals = tracing.stage_totals()["stages"]
+        assert len(commits) == totals["commit"]["calls"]
+        by_time = {record["time"]: record for record in commits}
+        for rows in seen:
+            for commit_time, at, _which in rows:
+                record = by_time[commit_time]  # a KeyError: no record
+                assert record["t0_ns"] <= at <= record["t1_ns"]
+                assert record["stages"]["sink.emit"]["calls"] >= 1
+        # records lie in time order and apart, inside the run
+        for before, after in zip(commits, commits[1:]):
+            assert before["t0_ns"] < before["t1_ns"] <= after["t0_ns"]
+        assert sum(r["t1_ns"] - r["t0_ns"] for r in commits) == (
+            totals["commit"]["total_ns"]
+        )
+
+    def test_a_row_arrived_before_the_commit_that_took_it_began(
+        self, monkeypatch
+    ):
+        """What a reader cuts a row's wait by: ``record.t0_ns`` less the
+        row's own send is never negative, for any row of either feed."""
+        seen, clock, threads = _run_two_feeds(monkeypatch)
+        by_time = {r["time"]: r for r in tracing.commit_timeline()}
+        arrived = _arrivals_by_commit(seen, clock, threads)
+        assert sum(len(stamps) for stamps in arrived.values()) == 12 + 5
+        for commit_time, stamps in arrived.items():
+            record = by_time[commit_time]
+            assert max(stamps) <= record["t0_ns"] + 50e3
+        # a feed that pauses every fourth row cannot land in one commit
+        assert len({t for t, _at, _which in seen[0]}) >= 2
+
+    def test_the_stages_counts_are_of_the_records_oldest_rows(
+        self, monkeypatch
+    ):
+        """``commit_wait_ns`` is of each commit's oldest row over all its
+        drivers, until ``_commit_step`` read the clock a little inside the
+        stage: the records' own ``t0_ns`` less the oldest push they took
+        sum to it less that little."""
+        seen, clock, threads = _run_two_feeds(monkeypatch)
+        by_time = {r["time"]: r for r in tracing.commit_timeline()}
+        counts = tracing.stage_totals()["stages"]["commit"]["counts"]
+        arrived = _arrivals_by_commit(seen, clock, threads)
+        assert len(arrived) >= 2
+        waited = sum(
+            by_time[commit_time]["t0_ns"] - min(stamps)
+            for commit_time, stamps in arrived.items()
+        )
+        slack = len(arrived) * 200e3
+        assert -slack <= counts["commit_wait_ns"] - waited <= slack
+        assert 0 <= counts["arrival_to_poll_ns"] <= counts["commit_wait_ns"]
+
+    def test_a_stage_entered_twice_folds_to_two_calls(self):
+        table = tracing.StageTable()
+        root = table.begin_run()
+        with table.stage("before.any.commit"):
+            pass
+        with table.commit_stage() as commit:
+            commit.time = 7
+            with table.stage("twice") as first:
+                pass
+            with table.stage("once"):
+                with table.commit_stage():  # an iterate body's: a child
+                    pass
+            with table.stage("twice") as second:
+                pass
+            second_t0 = second.t0
+        with table.stage("after.the.commit"):
+            pass
+        table.end_run(root)
+        (record,) = table.timeline()
+        assert record["time"] == 7
+        assert list(record["stages"]) == ["twice", "commit", "once"]
+        twice = record["stages"]["twice"]
+        rows = table.totals()["stages"]
+        assert twice["calls"] == 2 == rows["twice"]["calls"]
+        assert twice["first_t0_ns"] == first.t0 < second_t0
+        assert twice["last_t1_ns"] > second_t0
+        assert rows["twice"]["total_ns"] < (
+            twice["last_t1_ns"] - twice["first_t0_ns"]
+        )
+        assert record["t0_ns"] <= twice["first_t0_ns"]
+        assert twice["last_t1_ns"] <= record["t1_ns"]
+        assert record["stages"]["once"]["calls"] == 1
+        # the table's row has both commits, the record's interval its own
+        assert rows["commit"]["calls"] == 2
+        inner = record["stages"]["commit"]
+        assert record["t1_ns"] - record["t0_ns"] == (
+            rows["commit"]["total_ns"]
+            - (inner["last_t1_ns"] - inner["first_t0_ns"])
+        )
+
+    def test_only_the_run_threads_commits_are_records(self):
+        table = tracing.StageTable()
+        root = table.begin_run()
+
+        def elsewhere() -> None:
+            with table.commit_stage():
+                with table.stage("device.fetch_rows"):
+                    pass
+
+        with table.commit_stage() as commit:
+            commit.time = 1
+            worker = threading.Thread(target=elsewhere, name="other-runner")
+            worker.start()
+            worker.join()
+        # a stage that is only named like a commit's is a stage like any
+        with table.stage("commit"):
+            pass
+        table.end_run(root)
+        (record,) = table.timeline()
+        assert record["time"] == 1 and record["stages"] == {}
+        assert "commit" in table.totals()["threads"]["other-runner"]
+        assert table.totals()["stages"]["commit"]["calls"] == 2
+        # a table no run has begun on keeps no record at all
+        bare = tracing.StageTable()
+        with bare.commit_stage():
+            pass
+        assert bare.timeline() == []
+
+    def test_the_ring_never_passes_its_bound(self, monkeypatch):
+        monkeypatch.setattr(tracing, "TIMELINE_COMMITS", 8)
+        table = tracing.StageTable()
+        root = table.begin_run()
+        for i in range(30):
+            with table.commit_stage() as commit:
+                commit.time = i
+            assert len(table.timeline()) == min(i + 1, 8)
+        table.end_run(root)
+        assert [r["time"] for r in table.timeline()] == list(range(22, 30))
+        # the next run begins with an empty line
+        root = table.begin_run()
+        assert table.timeline() == []
+        table.end_run(root)
+        assert tracing.STAGES._timeline.maxlen == 1024
+
+    def test_a_full_collection_is_a_stage_of_its_own(self):
+        import gc
+
+        table = tracing.StageTable()
+        root = table.begin_run()
+        with table.stage("interrupted"):
+            gc.collect(1)  # a younger generation: no stage
+            gc.collect()
+        table.end_run(root)
+        rows = table.totals()["stages"]
+        assert rows["gc.full"]["calls"] == 1
+        assert rows["gc.full"]["counts"] == {}
+        assert rows["gc.full"]["total_ns"] > 0
+        # the collection's time is out of the interrupted stage's own
+        assert rows["interrupted"]["self_ns"] == (
+            rows["interrupted"]["total_ns"] - rows["gc.full"]["total_ns"]
+        )
+        assert _self_sum(table.totals()) == table.totals()["run_wall_ns"]
+
+    def test_a_collection_inside_a_commit_is_folded_into_its_record(self):
+        import gc
+
+        table = tracing.StageTable()
+        root = table.begin_run()
+        gc.collect()  # between commits: in the table alone
+        with table.commit_stage() as commit:
+            commit.time = 3
+            gc.collect()
+
+        def elsewhere() -> None:
+            gc.collect()
+
+        worker = threading.Thread(target=elsewhere, name="feed")
+        worker.start()
+        worker.join()
+        table.end_run(root)
+        (record,) = table.timeline()
+        folded = record["stages"]["gc.full"]
+        assert folded["calls"] == 1
+        assert record["t0_ns"] <= folded["first_t0_ns"] < folded["last_t1_ns"]
+        assert folded["last_t1_ns"] <= record["t1_ns"]
+        totals = table.totals()
+        assert totals["stages"]["gc.full"]["calls"] == 2
+        assert totals["threads"]["feed"]["gc.full"]["calls"] == 1
+
+    def test_a_collection_while_the_tables_lock_is_held_does_not_wait(self):
+        """``totals()`` allocates under the lock; a collection that falls
+        there opens a stage, on a thread that may have no table yet."""
+        table = tracing.StageTable()
+        root = table.begin_run()
+        done: list = []
+
+        def no_table_yet() -> None:
+            with table._lock:
+                table._on_collection("start", {"generation": 2})
+                table._on_collection("stop", {"generation": 2})
+            done.append(table.totals()["threads"]["reader"]["gc.full"]["calls"])
+
+        worker = threading.Thread(target=no_table_yet, name="reader", daemon=True)
+        worker.start()
+        worker.join(10)
+        table.end_run(root)
+        assert done == [1]
+
+    def test_the_collectors_hook_is_gone_after_the_run(self):
+        import gc
+
+        def hooks() -> list:
+            return [
+                hook for hook in gc.callbacks
+                if getattr(hook, "__self__", None) is tracing.STAGES
+            ]
+
+        assert hooks() == []
+        during: list = []
+
+        @tracing.traced_run
+        def run(fail: bool) -> None:
+            during.append(len(hooks()))
+            with tracing.commit_stage():
+                inner = tracing.STAGES.begin_run()  # a run inside a run
+                tracing.STAGES.end_run(inner)
+                during.append(len(hooks()))
+                if fail:
+                    raise RuntimeError("the run raised")
+
+        run(False)
+        assert hooks() == []
+        with pytest.raises(RuntimeError):
+            run(True)
+        assert hooks() == []
+        assert during == [1, 1, 1, 1]
+        _run_counting_stream(n=3)
+        assert hooks() == []
+
+    def test_stage_totals_has_the_keys_it_had(self):
+        _run_counting_stream(n=6)
+        totals = tracing.stage_totals()
+        assert set(totals) == {"run_wall_ns", "running", "stages", "threads"}
+        for row in totals["stages"].values():
+            assert set(row) == {"calls", "total_ns", "self_ns", "wait", "counts"}
+        line = tracing.commit_timeline()
+        assert len(line) == totals["stages"]["commit"]["calls"]
+        for record in line:
+            assert set(record) == {"time", "t0_ns", "t1_ns", "stages"}
+            for folded in record["stages"].values():
+                assert set(folded) == {"first_t0_ns", "last_t1_ns", "calls"}
+        json.dumps(line)  # plain dicts, lists and numbers all the way down
 
 
 @pytest.fixture(scope="class")
