@@ -460,13 +460,26 @@ class DeviceBatchHandle:
                 copy_async()
             self._prefetched = True
 
+    def ready(self) -> bool:
+        """Whether ``host()`` would come back without waiting for the chip
+        to produce the batch: the host twin is here, or the device array
+        says it is computed (what is left is the copy, which ``prefetch``
+        started). An array that cannot say (a host array standing in for
+        one) counts as ready."""
+        if self._host is not None:
+            return True
+        dev = self.dev  # once: the worker's decay may drop it meanwhile
+        is_ready = getattr(dev, "is_ready", None)
+        return is_ready is None or is_ready()
+
     def host(self) -> np.ndarray:
         if self._host is None:
             from pathway_tpu.engine import device_residency as _dres
 
             # blocks until the device has produced the batch: on the run
-            # thread (a sink reading a lazy row) or on the pipeline's
-            # completion worker (decay)
+            # thread (a sink reading a row of a batch that was ready, or
+            # ``_add_host``) or on the pipeline's completion worker (a
+            # sink's emission handed over to it, then decay)
             with _tracing.stage("device.fetch_rows", wait=True) as fetch:
                 self._host = np.asarray(self.dev)
                 fetch.add(d2h_bytes=int(self._host.nbytes))
@@ -507,6 +520,15 @@ def stage_device_batches() -> list:
     handles = list(_LIVE_HANDLES)
     _LIVE_HANDLES.clear()
     return handles
+
+
+def unready_device_batches() -> set:
+    """This commit's device batches that the chip has not finished: what a
+    sink asks before it reads its rows on the run thread. Empty on a
+    host-only commit, at the cost of one truthiness test."""
+    if not _LIVE_HANDLES:
+        return set()
+    return {handle for handle in list(_LIVE_HANDLES) if not handle.ready()}
 
 
 class LazyDeviceVector:

@@ -20,12 +20,38 @@ This module turns the barrier into a pipeline stage:
   stays async end to end — the only ``block_until_ready``-equivalent
   wait is the completion worker's ``decay()``.
 - **completion queue (device->host)** — a single daemon worker pops
-  staged commits strictly FIFO and completes them (awaits the DMA,
+  what is staged strictly FIFO and completes it (awaits the DMA,
   releases HBM), so commit completion is **in order** by construction:
   commit N's device effects are fully host-resident before commit N+1's
   are.  Exactly-once/checkpoint semantics are preserved by the runner
   calling :meth:`drain_until` before persistence/snapshot ``on_commit``
   hooks — a checkpoint for commit N can only be cut after N completed.
+- **a sink's emission (device->host, for a consumer that is not the
+  pump)** — a subscribe sink whose rows point into a device batch the
+  chip has not finished (``DeviceBatchHandle.ready()`` false) does not
+  wait for it on the run thread: ``SubscribeNode.process`` emits the rows
+  that are ready itself and hands the rest of its batch to
+  :meth:`DevicePipeline.hand_over` **while the sink runs**, not at the
+  boundary.  The worker waits for the download in the run thread's
+  place and calls the user's ``on_change`` for those rows, then
+  the sink's ``on_time_end`` if the scheduler left it one; so a row is
+  delivered when its batch is down, not after its commit's other
+  operators, and the run thread goes on to them (in a RAG graph: the
+  queries' embedding, the index update and the search's enqueue lie
+  under the documents' encoder instead of behind it).  **A user's
+  ``on_change`` / ``on_time_end`` can therefore run on the thread
+  ``pw-device-pipeline``**; one sink's callbacks keep their order (rows
+  of ``t``, ``on_time_end(t)``, rows of ``t+1``: while anything of a sink
+  is with the worker, everything of that sink goes behind it), across
+  sinks nothing is promised, and ``on_end`` stays on the run thread,
+  behind :meth:`drain`.  What was handed over is part of its commit:
+  :meth:`drain_until` and :meth:`drain` wait for it (a checkpoint, a
+  published read view and ``on_end`` see every row of their commit
+  delivered), the in-flight bound counts it, and a callback that raises
+  on the worker is raised on the run thread at the next seam, like a
+  failed decay; nothing more is delivered behind it.  A sink of host
+  rows, of rows that are ready, or in a commit with no device batch
+  never leaves the run thread.
 - **double buffering / backpressure** — at most ``depth`` commits
   (default 2, ``PATHWAY_TPU_DEVICE_INFLIGHT``) may be in flight;
   staging commit N+depth blocks until commit N retires, bounding HBM to
@@ -40,7 +66,8 @@ This module turns the barrier into a pipeline stage:
   is not decided here: that is the UDF's ``max_batch_size``.
 
 ``PATHWAY_TPU_ASYNC_DEVICE=0`` is the escape hatch: the commit boundary
-then decays inline, bit-identical to the pre-pipeline engine (PR-2
+then decays inline and every sink emits on the run thread, waiting
+where it has to, bit-identical to the pre-pipeline engine (PR-2
 style: the synchronous path stays the spec; tests/test_device_pipeline.py
 holds the two modes to bit-identical sinks on all three schedulers).
 
@@ -71,6 +98,8 @@ __all__ = [
     "commit_boundary",
     "drain",
     "drain_until",
+    "hand_over",
+    "holds",
     "reset",
     "ingest_window_scale",
 ]
@@ -167,8 +196,12 @@ class DevicePipeline:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._cv = threading.Condition(self._lock)
-        #: FIFO of (commit_time, handles, dispatch_perf) awaiting completion
+        #: FIFO of (commit_time, handles, dispatch_perf, sink, work)
+        #: awaiting completion: a commit's handles to decay (no sink), or
+        #: what a sink of that commit left to the worker (:meth:`hand_over`)
         self._staged: deque = deque()  # guarded-by: self._cv
+        #: sink -> pieces of its work staged or running (:meth:`holds`)
+        self._held: dict = {}  # guarded-by: self._cv
         self._active_time: int | None = None  # guarded-by: self._cv
         self._completed_time = -1  # guarded-by: self._cv
         self._worker: threading.Thread | None = None
@@ -252,14 +285,54 @@ class DevicePipeline:
 
     # -- staging side (scheduler thread) -------------------------------------
 
+    def _in_flight_locked(self, but: int | None = None) -> int:
+        """Commits with anything staged or completing, ``but`` aside: a
+        commit's decay and what its sinks handed over count once."""
+        times = self._staged_times_locked()
+        if self._active_time is not None:
+            times.add(self._active_time)
+        times.discard(but)
+        return len(times)
+
+    def _staged_times_locked(self) -> set:
+        return {entry[0] for entry in self._staged}
+
+    def holds(self, sink) -> bool:
+        """Whether anything ``sink`` handed over is still staged or
+        running: its next callback then goes behind it, to keep the sink's
+        order (rows of ``t``, ``on_time_end(t)``, rows of ``t + 1``)."""
+        return sink in self._held  # one key's read: no lock
+
+    def hand_over(self, time: int, sink, work, handles=()) -> None:
+        """``sink`` (a node of commit ``time``) would have held the run
+        thread at a device batch the chip has not finished: the worker
+        takes ``work`` (the sink's callbacks for its rows, or its
+        ``on_time_end``) behind what is staged already; the download of
+        ``handles``, the batches it will wait for, is started here. Called
+        while the sink runs, not at the boundary, so the rows are delivered
+        when their batch is down and not after their commit's other
+        operators. Only where :func:`async_enabled`; the commit's boundary
+        applies the in-flight bound."""
+        self._raise_pending()
+        for handle in handles:
+            handle.prefetch()  # start the DMA; the worker awaits it
+        self._ensure_worker()
+        with self._cv:
+            self._held[sink] = self._held.get(sink, 0) + 1
+            self._staged.append(
+                (int(time), (), _time.perf_counter(), sink, work)
+            )
+            self._g_depth.value = float(self._in_flight_locked())
+            self._cv.notify_all()
+
     def commit_boundary(self, time: int) -> None:
         """End-of-commit hook, replacing the inline decay barrier.
 
         Sync mode (``PATHWAY_TPU_ASYNC_DEVICE=0``): decay inline —
         bit-identical to the pre-pipeline engine.  Async mode: start the
         D2H DMA for every live handle, stage the commit on the FIFO
-        (blocking only when ``depth`` commits are already in flight),
-        and return to the host sweep immediately."""
+        (blocking only when ``depth`` earlier commits are still in
+        flight), and return to the host sweep immediately."""
         from pathway_tpu.engine import device as _device
         from pathway_tpu.engine import device_residency as _dres
 
@@ -269,7 +342,9 @@ class DevicePipeline:
         # only ever sees host-resident state (exactly-once discipline)
         _dres.decay_resident_batches()
         handles = _device.stage_device_batches()
-        if not handles:
+        if not handles and not self._held:
+            # nothing of this commit's on the device, and no sink's work
+            # with the worker that the in-flight bound would have to count
             return
         if not async_enabled():
             for handle in handles:
@@ -285,11 +360,8 @@ class DevicePipeline:
             self._ensure_worker()
             blocked = False
             with self._cv:
-                while (
-                    len(self._staged)
-                    + (1 if self._active_time is not None else 0)
-                    >= self.controller.depth
-                ):
+                # what this commit's own sinks handed over is part of it
+                while self._in_flight_locked(but=time) >= self.controller.depth:
                     blocked = True
                     # genuine pipeline stall: host blocked on the device
                     # stage — attributed to the queue_wait bucket
@@ -300,13 +372,11 @@ class DevicePipeline:
                     err = self._take_error_locked()
                     if err is not None:
                         raise err
-                self._staged.append((int(time), handles, t0))
-                self._g_depth.value = float(
-                    len(self._staged)
-                    + (1 if self._active_time is not None else 0)
-                )
+                if handles:
+                    self._staged.append((int(time), handles, t0, None, None))
+                self._g_depth.value = float(self._in_flight_locked())
                 self._cv.notify_all()
-                staged_depth = len(self._staged)
+                staged_depth = len(self._staged_times_locked())
                 occupancy = self._occupancy
             self.controller.observe(
                 staged_depth=staged_depth, blocked=blocked, occupancy=occupancy
@@ -324,15 +394,21 @@ class DevicePipeline:
                     if self._stop:
                         return
                     self._cv.wait(timeout=0.5)
-                time_, handles, t_dispatch = self._staged.popleft()
+                time_, handles, t_dispatch, sink, work = self._staged.popleft()
                 self._active_time = time_
-                self._g_depth.value = float(len(self._staged) + 1)
+                self._g_depth.value = float(self._in_flight_locked())
+                failed = self._error is not None
                 self._cv.notify_all()
             t0 = _time.perf_counter()
             err: BaseException | None = None
             try:
-                for handle in handles:
-                    handle.decay()
+                if sink is None:
+                    for handle in handles:
+                        handle.decay()
+                elif not failed:
+                    # after a failure the run is coming down, and nothing
+                    # more is delivered, as inline
+                    work()
             except BaseException as e:  # noqa: BLE001 — surfaced on main thread
                 err = e
             t1 = _time.perf_counter()
@@ -348,9 +424,14 @@ class DevicePipeline:
                     self._g_occ.value = round(self._occupancy, 4)
                 self._completed_time = time_
                 self._active_time = None
-                self._g_depth.value = float(len(self._staged))
-                self._h_latency.observe(max(0.0, t1 - t_dispatch))
-                self._c_commits.inc()
+                self._g_depth.value = float(self._in_flight_locked())
+                if sink is None:
+                    self._h_latency.observe(max(0.0, t1 - t_dispatch))
+                    self._c_commits.inc()
+                elif self._held[sink] > 1:
+                    self._held[sink] -= 1
+                else:
+                    del self._held[sink]
                 if err is not None and self._error is None:
                     self._error = err
                 self._cv.notify_all()
@@ -403,9 +484,7 @@ class DevicePipeline:
 
     def inflight(self) -> int:
         with self._cv:
-            return len(self._staged) + (
-                1 if self._active_time is not None else 0
-            )
+            return self._in_flight_locked()
 
     def completed_time(self) -> int:
         return self._completed_time
@@ -457,6 +536,14 @@ PIPELINE = DevicePipeline()
 
 def commit_boundary(time: int) -> None:
     PIPELINE.commit_boundary(time)
+
+
+def holds(sink) -> bool:
+    return PIPELINE.holds(sink)
+
+
+def hand_over(time: int, sink, work, handles=()) -> None:
+    PIPELINE.hand_over(time, sink, work, handles)
 
 
 def drain() -> None:

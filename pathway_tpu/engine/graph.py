@@ -22,6 +22,7 @@ Key design points vs the reference:
 
 from __future__ import annotations
 
+import functools
 import itertools
 import logging
 import threading
@@ -30,6 +31,8 @@ from typing import Any, Callable, Iterable, Sequence
 
 import numpy as np
 
+from pathway_tpu.engine import device as _device
+from pathway_tpu.engine import device_pipeline as _device_pipeline
 from pathway_tpu.engine.batch import DeltaBatch, apply_batch_to_state
 from pathway_tpu.engine.device import VECTOR_THRESHOLD
 from pathway_tpu.engine.expression import EngineExpression, EvalContext
@@ -2657,8 +2660,23 @@ class UpdateCellsNode(InputMirrors, Node):
         return out
 
 
+def _reads_unready(row: tuple, unready: set) -> bool:
+    """Whether a host read of ``row`` would wait for one of the ``unready``
+    device batches; one the chip has finished meanwhile leaves the set."""
+    for value in row:
+        if type(value) is _device.LazyDeviceVector and value.batch in unready:
+            if not value.batch.ready():
+                return True
+            unready.discard(value.batch)
+    return False
+
+
 class SubscribeNode(Node):
-    """Sink: per-row callbacks + time/end notifications (subscribe_table)."""
+    """Sink: per-row callbacks + time/end notifications (subscribe_table).
+
+    One sink's callbacks keep their order whichever thread makes them: the
+    rows of a time, its ``on_time_end``, the rows of the next. Across sinks
+    nothing is promised. ``close`` follows the pipeline's ``drain``."""
 
     def __init__(
         self,
@@ -2677,9 +2695,26 @@ class SubscribeNode(Node):
         self._saw_data = False
 
     def process(self, time: int) -> DeltaBatch:
+        """Call ``on_change`` for the batch's rows, on this thread as long
+        as that holds it for no device batch: from the first row that
+        reads a batch of this commit the chip has not finished, the rest
+        (in order) is left to the completion worker, which waits for the
+        download in the run thread's place; so is every row while an
+        earlier emission of this sink is still with it. Decided from what
+        the sink sees and no option: ``PATHWAY_TPU_ASYNC_DEVICE=0``, a
+        commit with no device batch and rows that are ready emit inline."""
         batch = self.take(0)
+        on_change = self._on_change
         rows = 0
         retractions = 0
+        unready: set = set()
+        #: the rows the worker is to deliver (None: none yet)
+        deferred: list | None = None
+        if on_change is not None and _device_pipeline.async_enabled():
+            if _device_pipeline.holds(self):
+                deferred = []
+            else:
+                unready = _device.unready_device_batches()
         with _tracing.stage("sink.emit", cat="sink") as emit:
             for key, row, diff in batch:
                 if self.skip_errors and any(is_error(v) for v in row):
@@ -2689,9 +2724,24 @@ class SubscribeNode(Node):
                 rows += 1
                 if diff < 0:
                     retractions += 1
-                if self._on_change is not None:
-                    self._on_change(key, row, time, diff)
-            emit.add(rows=rows)
+                if on_change is None:
+                    continue
+                if deferred is None:
+                    if not (unready and _reads_unready(row, unready)):
+                        on_change(key, row, time, diff)
+                        continue
+                    deferred = []
+                deferred.append((key, row, diff))
+            if deferred:
+                _device_pipeline.hand_over(
+                    time,
+                    self,
+                    functools.partial(self._deliver, deferred, time),
+                    unready,
+                )
+                emit.add(rows=rows - len(deferred), deferred_rows=len(deferred))
+            else:
+                emit.add(rows=rows)
         if rows:
             _OUTPUT_ROWS.inc(rows)
             tr = _tracing.current()
@@ -2703,8 +2753,23 @@ class SubscribeNode(Node):
             )
         return batch
 
+    def _deliver(self, rows: list, time: int) -> None:
+        """The completion worker's half of ``process``: a ``sink.emit``
+        stage of that thread's table."""
+        on_change = self._on_change
+        with _tracing.stage("sink.emit", cat="sink", rows=len(rows)):
+            for key, row, diff in rows:
+                on_change(key, row, time, diff)
+
     def on_time_end(self, time: int) -> None:
-        if self._on_time_end is not None:
+        if self._on_time_end is None:
+            return
+        if _device_pipeline.holds(self):
+            # behind the rows of ``time`` that are with the worker
+            _device_pipeline.hand_over(
+                time, self, functools.partial(self._on_time_end, time)
+            )
+        else:
             self._on_time_end(time)
 
     def close(self) -> None:
@@ -3214,9 +3279,7 @@ class Scheduler:
         self._sweep(time)
         for node in self._nodes():
             node.on_time_end(time)
-        from pathway_tpu.engine import device_pipeline
-
-        device_pipeline.commit_boundary(time)
+        _device_pipeline.commit_boundary(time)
 
     def _settle(self) -> None:
         """``on_end`` hooks may inject final batches (buffer flush):
@@ -3231,9 +3294,9 @@ class Scheduler:
         for node in self._nodes():
             node.on_end()
         self._settle()
-        from pathway_tpu.engine import device_pipeline
-
-        device_pipeline.drain()
+        # every row a sink left to the completion worker is delivered
+        # before its ``close`` (the user's ``on_end``)
+        _device_pipeline.drain()
         for node in self._nodes():
             node.close()
 

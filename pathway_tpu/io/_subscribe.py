@@ -19,7 +19,14 @@ def subscribe(
     skip_errors: bool = True,
     _internal: bool = False,
 ) -> None:
-    """Call ``on_change(key, row: dict, time, is_addition)`` for every update."""
+    """Call ``on_change(key, row: dict, time, is_addition)`` for every update.
+
+    One sink's callbacks come in order (the rows of a time, its
+    ``on_time_end``, the rows of the next), but not always on the thread
+    that runs the graph: rows that read a device batch the chip has not
+    finished are delivered by the device pipeline's completion worker
+    (``engine/device_pipeline.py``), and ``on_time_end`` behind them;
+    ``on_end`` comes last, on the run thread."""
     column_names = table.column_names()
 
     def attach(scope: Scope, node: Node):

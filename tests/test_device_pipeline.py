@@ -72,6 +72,10 @@ class _GatedDev:
         self.shape = self._arr.shape
         self.dtype = self._arr.dtype
 
+    def is_ready(self):
+        """What ``jax.Array.is_ready`` says: the chip has produced it."""
+        return self._gate is None or self._gate.is_set()
+
     def __array__(self, dtype=None, copy=None):
         if self._gate is not None and not self._gate.wait(timeout=30):
             raise TimeoutError("test gate never opened")
@@ -537,6 +541,409 @@ def test_scheduler_boundary_decays_inline_in_sync_mode(monkeypatch):
     sess.insert(ref_scalar(1), (1, 2.0))
     sched.commit()
     assert captured and all(h.dev is None for h in captured)
+
+
+# -- a sink over device rows: all three schedulers, async against =0 -----------
+
+SCHEDULERS = ("single", "sharded", "mesh")
+WORKER = "pw-device-pipeline"
+
+
+class _SinkRig:
+    """session -> a fake device embed -> sinks, under one of the three
+    schedulers (two replicas under ``sharded`` and ``mesh``, the sinks on
+    worker 0 as the runners attach them). ``gate`` is what the embed's next
+    device batches wait for (None: a host array stands in for the device's,
+    ready at once). Every callback is logged with its thread."""
+
+    def __init__(self, kind, on_change=None):
+        from pathway_tpu.engine import distributed as dist
+
+        self.gate = None
+        self.log: list = []
+        self._inner = on_change
+        self._transport = None
+        scopes, self.sessions, embedded = [], [], []
+        for _w in range(1 if kind == "single" else 2):
+            sc = Scope()
+            sess = sc.input_session(2)
+            embedded.append(sc.batch_apply_table(sess, self._embed, [0, 1]))
+            scopes.append(sc)
+            self.sessions.append(sess)
+        n_shared = len(scopes[0].nodes)
+        self.scope, self.session, self.embedded = (
+            scopes[0], self.sessions[0], embedded[0]
+        )
+        self.sink = scopes[0].subscribe_table(
+            embedded[0],
+            on_change=self._on_change,
+            on_time_end=lambda t: self._note("end", t),
+            on_end=lambda: self._note("closed"),
+        )
+        #: a sink of host rows alone, in the same commits
+        self.host_sink = scopes[0].subscribe_table(
+            self.session,
+            on_change=lambda k, row, t, d: self._note("host", int(k), t, d),
+        )
+        if kind == "single":
+            self.sched = Scheduler(scopes[0])
+        elif kind == "sharded":
+            self.sched = ShardedScheduler(scopes)
+        else:
+            self._transport = dist.MeshTransport(
+                0, 1, addresses=[("127.0.0.1", 0)]
+            )
+            self.sched = dist.DistributedScheduler(
+                scopes, 0, 1, self._transport, n_shared=n_shared
+            )
+            self.sched.announce_topology()
+
+    def _embed(self, arg_rows):
+        mat = np.asarray(
+            [[float(a[0]), float(a[1]) * 2.0] for a in arg_rows], np.float32
+        )
+        dev = mat if self.gate is None else _GatedDev(mat, gate=self.gate)
+        return [(True, c) for c in dev_mod.lazy_rows(dev, len(arg_rows))]
+
+    def _note(self, *event):
+        self.log.append((*event, threading.current_thread().name))
+
+    def _on_change(self, key, row, time, diff):
+        if self._inner is not None:
+            self._inner(key, row, time, diff)
+        self._note("row", int(key), _host_row(row), time, diff)
+
+    def commit(self, keys, gate=None):
+        self.gate = gate
+        for key in keys:
+            self.session.insert(ref_scalar(key), (key, float(key) * 0.5))
+        return self.sched.commit()
+
+    def events(self, *kinds):
+        """The log without the threads, of the kinds asked for."""
+        return [e[:-1] for e in self.log if e[0] in kinds]
+
+    def threads(self, kind):
+        return {e[-1] for e in self.log if e[0] == kind}
+
+    def close(self):
+        if self._transport is not None:
+            self._transport.close()
+
+
+@pytest.fixture
+def rig(request):
+    made = []
+
+    def make(kind, **kwargs):
+        made.append(_SinkRig(kind, **kwargs))
+        return made[-1]
+
+    yield make
+    for r in made:
+        r.close()
+
+
+def _opens_soon(delay=0.15):
+    """A gate a timer opens: a device batch that takes ``delay`` seconds."""
+    gate = threading.Event()
+    timer = threading.Timer(delay, gate.set)
+    timer.daemon = True
+    timer.start()
+    return gate
+
+
+def _run_sink_program(rig, kind):
+    """Three commits over slow device batches and one of retractions, then
+    the end: everything the embedded table's sink was told, in order."""
+    r = rig(kind)
+    for commit in range(3):
+        r.commit(range(commit * 40, commit * 40 + 40), gate=_opens_soon(0.05))
+    r.gate = _opens_soon(0.05)
+    for i in range(10):
+        r.session.remove(ref_scalar(i), (i, float(i) * 0.5))
+        r.session.insert(ref_scalar(i), (i, float(i) * 0.5 + 9.0))
+    r.sched.commit()
+    r.sched.finish()
+    return r
+
+
+@pytest.mark.parametrize("kind", SCHEDULERS)
+def test_sink_over_device_rows_same_rows_same_order(kind, rig, monkeypatch):
+    """The asynchronous sink hands its rows to the worker; what the
+    callbacks see, and in which order, is the inline spec's."""
+    monkeypatch.setenv("PATHWAY_TPU_ASYNC_DEVICE", "0")
+    dp.PIPELINE.configure()
+    off = _run_sink_program(rig, kind)
+    assert off.threads("row") == {threading.current_thread().name}
+    monkeypatch.setenv("PATHWAY_TPU_ASYNC_DEVICE", "1")
+    dp.PIPELINE.configure()
+    on = _run_sink_program(rig, kind)
+    assert WORKER in on.threads("row")  # the hand-over was exercised
+    kinds = ("row", "end", "closed")
+    assert on.events(*kinds) == off.events(*kinds)
+    assert on.events("host") == off.events("host")
+    assert len(on.events("row")) == 140 and on.events("closed")
+
+
+@pytest.mark.parametrize("kind", SCHEDULERS)
+def test_sink_keeps_its_order_behind_a_slow_batch(kind, rig, async_on):
+    """Rows of t, ``on_time_end(t)``, rows of t + 1: a later commit whose
+    rows are ready waits its turn behind the emission still with the
+    worker, and nothing is delivered before the slow batch is down."""
+    r = rig(kind)
+    gate = threading.Event()
+    t0 = r.commit(range(8), gate=gate)
+    t1 = r.commit(range(8, 12))  # ready rows, behind the pending emission
+    assert r.events("row", "end") == []
+    assert dp.PIPELINE.inflight() == 2
+    gate.set()
+    dp.drain()
+    got = r.events("row", "end")
+    assert [e[0] for e in got] == ["row"] * 8 + ["end"] + ["row"] * 4 + ["end"]
+    assert [e[3] for e in got if e[0] == "row"] == [t0] * 8 + [t1] * 4
+    assert [e[1] for e in got if e[0] == "end"] == [t0, t1]
+    assert r.threads("row") == r.threads("end") == {WORKER}
+    # the next commit finds nothing of this sink with the worker
+    r.commit(range(12, 16))
+    assert r.log[-1][0] == "end" and r.log[-1][-1] != WORKER
+    assert {e[-1] for e in r.log if e[0] == "row" and e[3] > t1} == {
+        threading.current_thread().name
+    }
+
+
+@pytest.mark.parametrize("kind", SCHEDULERS)
+def test_ready_and_host_rows_stay_on_the_run_thread(kind, rig, async_on):
+    """A sink leaves the run thread only for a device batch the chip has
+    not finished: ready device rows are emitted inline, and so are the
+    host rows of another sink in a commit that holds a slow batch."""
+    me = threading.current_thread().name
+    r = rig(kind)
+    r.commit(range(20))  # every device batch ready
+    assert r.threads("row") == r.threads("host") == r.threads("end") == {me}
+    assert not dp.holds(r.sink)
+    r.commit(range(20, 40), gate=_opens_soon())
+    dp.drain()
+    assert r.threads("host") == {me}
+    assert WORKER in r.threads("row")
+    assert len(r.events("row")) == len(r.events("host")) == 40
+
+
+@pytest.mark.parametrize("kind", SCHEDULERS)
+def test_failing_on_change_on_the_worker_fails_the_run_thread(
+    kind, rig, async_on
+):
+    def boom(key, row, time, diff):
+        if threading.current_thread().name == WORKER:
+            raise RuntimeError("sink boom on the worker")
+
+    r = rig(kind, on_change=boom)
+    gate = threading.Event()
+    r.commit(range(6), gate=gate)
+    gate.set()
+    with pytest.raises(RuntimeError, match="sink boom on the worker"):
+        r.sched.finish()
+    # nothing was delivered behind the failure, and the pipeline is usable
+    assert r.events("row") == [] and r.events("closed") == []
+    assert not dp.holds(r.sink)
+    dp.PIPELINE.configure()
+    ok = rig(kind)
+    ok.commit(range(4), gate=_opens_soon(0.02))
+    ok.sched.finish()
+    assert len(ok.events("row")) == 4
+
+
+@pytest.mark.parametrize("kind", SCHEDULERS)
+def test_commit_seams_see_every_row_delivered(kind, rig, async_on):
+    """The journal's ``on_commit(t)`` (behind ``drain_until(t)`` in
+    ``_after_commit``) and the user's ``on_end`` come after every row of
+    their commit was handed to its callback."""
+    from pathway_tpu.internals import runner
+
+    r = rig(kind)
+    seen = {}
+
+    class Journal:
+        def on_commit(self, time):
+            seen[time] = r.events("row", "end")
+
+    t0 = r.commit(range(16), gate=_opens_soon())
+    runner._after_commit(t0, r.sched.scopes, [], persistent=[Journal()])
+    assert [e[0] for e in seen[t0]] == ["row"] * 16 + ["end"]
+    assert all(e[3] == t0 for e in seen[t0] if e[0] == "row")
+    r.commit(range(16, 24), gate=_opens_soon())
+    r.sched.finish()
+    assert r.log[-1][0] == "closed"
+    assert len(r.events("row")) == 24
+
+
+@pytest.mark.parametrize("kind", SCHEDULERS)
+def test_deferred_rows_are_counted_where_they_were_handed_over(
+    kind, rig, async_on
+):
+    """``sink.emit`` on the run thread: ``rows`` is what it emitted itself,
+    ``deferred_rows`` what it left to the worker, whose own ``sink.emit``
+    stage counts those as its ``rows``; the time line's record takes the
+    stage like any other."""
+    me = threading.current_thread().name
+    root = tracing.STAGES.begin_run()
+    try:
+        r = rig(kind)
+        with tracing.commit_stage() as commit:
+            commit.time = r.commit(range(10))  # ready: emitted inline
+        with tracing.commit_stage() as commit:
+            commit.time = r.commit(range(10, 40), gate=_opens_soon())
+        dp.drain()
+    finally:
+        tracing.STAGES.end_run(root)
+    inline = sum(1 for e in r.log if e[0] == "row" and e[-1] == me)
+    handed = sum(1 for e in r.log if e[0] == "row" and e[-1] == WORKER)
+    assert inline + handed == 40 and handed >= 15
+    totals = tracing.stage_totals()
+    counts = totals["stages"]["sink.emit"]["counts"]
+    assert counts["deferred_rows"] == handed
+    # the embedded table's rows emitted here, and the host sink's 40
+    assert counts["rows"] == inline + 40
+    worker = totals["threads"][WORKER]["sink.emit"]
+    assert worker["counts"] == {"rows": handed}
+    assert all("sink.emit" in rec["stages"] for rec in tracing.commit_timeline())
+
+
+def test_sync_mode_never_hands_over(rig, monkeypatch):
+    """``PATHWAY_TPU_ASYNC_DEVICE=0``: a slow batch holds the run thread,
+    as it always did, and no count of deferred rows appears."""
+    monkeypatch.setenv("PATHWAY_TPU_ASYNC_DEVICE", "0")
+    root = tracing.STAGES.begin_run()
+    try:
+        r = rig("single")
+        r.commit(range(12), gate=_opens_soon(0.05))
+    finally:
+        tracing.STAGES.end_run(root)
+    assert r.threads("row") == {threading.current_thread().name}
+    assert dp.PIPELINE._worker is None or not dp.PIPELINE._staged
+    counts = tracing.stage_totals()["stages"]["sink.emit"]["counts"]
+    assert counts == {"rows": 24}
+
+
+def test_a_batch_is_cut_at_the_first_row_that_would_wait(async_on):
+    """Rows that are ready go out on the run thread; from the first one of
+    an unfinished batch on, the rest of the sink's batch is the worker's,
+    in the batch's order."""
+    gate = threading.Event()
+    log: list = []
+
+    def embed(arg_rows):
+        mat = np.asarray([[float(a[0]), 0.0] for a in arg_rows], np.float32)
+        half = len(arg_rows) // 2
+        cells = dev_mod.lazy_rows(mat[:half], half)
+        cells += dev_mod.lazy_rows(
+            _GatedDev(mat[half:], gate=gate), len(arg_rows) - half
+        )
+        return [(True, c) for c in cells]
+
+    sc = Scope()
+    sess = sc.input_session(2)
+    ba = sc.batch_apply_table(sess, embed, [0, 1])
+    sc.subscribe_table(
+        ba,
+        on_change=lambda k, row, t, d: log.append(
+            (float(np.asarray(row[-1])[0]), threading.current_thread().name)
+        ),
+    )
+    sched = Scheduler(sc)
+    for key in range(10):
+        sess.insert(ref_scalar(key), (key, 0.0))
+    sched.commit()
+    inline = [v for v, th in log]
+    assert inline and all(th != WORKER for _v, th in log)
+    gate.set()
+    dp.drain()
+    assert len(log) == 10
+    assert [th for _v, th in log[len(inline):]] == [WORKER] * (10 - len(inline))
+    # the cut is at the first gated row: every row behind it is the worker's
+    assert all(v < 5 for v in inline) and sorted(v for v, _ in log) == [
+        float(i) for i in range(10)
+    ]
+
+
+def test_sink_order_holds_under_stress(rig, async_on):
+    """Many commits whose device batches finish at odd moments, the
+    interpreter switching threads every 10 us: no row is lost or doubled,
+    and one sink's callbacks stay in the inline order whichever thread made
+    each (rows of t, ``on_time_end(t)``, rows of t + 1)."""
+    import random
+
+    rng = random.Random(41)
+    pending: list = []
+    stop = threading.Event()
+
+    def opener():
+        while not stop.is_set() or pending:
+            if pending:
+                pending.pop(0).set()
+            time.sleep(rng.random() * 0.002)
+
+    th = threading.Thread(target=opener, daemon=True)
+    before = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    th.start()
+    try:
+        r = rig("single")
+        want: list = []
+        key = 0
+        for _commit in range(150):
+            n = rng.randrange(1, 6)
+            gate = None
+            if rng.random() < 0.6:
+                gate = threading.Event()
+                pending.append(gate)
+            t = r.commit(range(key, key + n), gate=gate)
+            want += [("row", k, t) for k in range(key, key + n)] + [("end", t)]
+            key += n
+        stop.set()
+        r.sched.finish()
+    finally:
+        stop.set()
+        sys.setswitchinterval(before)
+        th.join(timeout=30)
+    assert not th.is_alive()
+    got = [
+        (e[0], int(e[2][0][0]), e[3]) if e[0] == "row" else (e[0], e[1])
+        for e in r.log
+        if e[0] in ("row", "end")
+    ]
+    # the last commit (``finish``) holds no row: its ``on_time_end`` alone
+    assert got[: len(want)] == want and got[len(want):] == [("end", t + 1)]
+    assert {WORKER, threading.current_thread().name} <= r.threads("row")
+    assert not dp.holds(r.sink) and dp.PIPELINE.inflight() == 0
+
+
+def test_handed_over_work_counts_toward_the_inflight_bound(async_on):
+    """A commit whose sinks handed work over is in flight until the worker
+    has done it, whether or not it staged a device batch of its own; what
+    a commit's own sinks handed over does not hold its boundary up."""
+    gates = [threading.Event() for _ in range(2)]
+    done: list = []
+    for t, gate in enumerate(gates, start=1):
+        handle = dev_mod.DeviceBatchHandle(_GatedDev(np.zeros((1, 1)), gate=gate))
+        dp.hand_over(
+            t, "sink", lambda t=t, h=handle: done.append((t, h.host().shape))
+        )
+        dp.commit_boundary(t)  # its own emission pending: no stall
+    assert dp.PIPELINE.inflight() == 2 and done == []
+    assert dp.holds("sink") and not dp.holds("another")
+    dp.hand_over(3, "sink", lambda: done.append((3, None)))  # no batch of its own
+    third = threading.Thread(target=dp.commit_boundary, args=(3,))
+    third.start()
+    time.sleep(0.25)
+    assert third.is_alive()  # two earlier commits in flight: the bound holds
+    gates[0].set()
+    third.join(timeout=30)
+    assert not third.is_alive()
+    gates[1].set()
+    dp.drain()
+    assert [t for t, _ in done] == [1, 2, 3]
+    assert dp.PIPELINE.inflight() == 0 and not dp.holds("sink")
 
 
 # -- parity: sharded in-process scheduler -------------------------------------

@@ -1480,7 +1480,18 @@ class TestStagesOfARagRun:
         assert stages["knn.add.host"]["counts"]["rows"] == N_DOCS
         assert stages["knn.add.dispatch"]["counts"]["rows"] == N_DOCS
         assert stages["knn.search.fetch"]["counts"]["queries"] == N_QUERIES
-        assert stages["sink.emit"]["counts"]["rows"] == N_DOCS + N_QUERIES
+        # a sink emits its rows itself or leaves them to the completion
+        # worker (the documents', where the encoder has not finished), whose
+        # own ``sink.emit`` counts what it was handed
+        emitted = stages["sink.emit"]["counts"]
+        deferred = emitted.get("deferred_rows", 0)
+        assert emitted["rows"] + deferred == N_DOCS + N_QUERIES
+        handed = sum(
+            table["sink.emit"]["counts"]["rows"]
+            for table in rag_run["totals"]["threads"].values()
+            if "sink.emit" in table
+        )
+        assert handed == deferred
         # a dispatch is a piece of a chunk; texts of one length are one piece
         assert stages["embed.dispatch"]["calls"] == stages["embed.pad"]["counts"]["pieces"]
         assert stages["embed.dispatch"]["calls"] == stages["udf.batch"]["calls"]
@@ -1512,9 +1523,10 @@ class TestStagesOfARagRun:
         assert waits <= {
             "knn.search.fetch", "commit.device_wait", "device.fetch_rows"
         }
-        # the completion worker's fetches are rows of its own thread
+        # the completion worker's fetches, and the emissions that wait for
+        # them in the run thread's place, are rows of its own thread
         for table in rag_run["totals"]["threads"].values():
-            assert set(table) <= {"device.fetch_rows"}, table
+            assert set(table) <= {"device.fetch_rows", "sink.emit"}, table
 
     def test_a_knn_enqueue_is_not_device_kernel_time(self, rag_run):
         new = {
