@@ -13,9 +13,13 @@ in attention's place (``attention_pattern``):
   it: ``"sliding_attention"`` (RoPE; key ``j`` is seen by query ``i`` iff
   ``0 <= i - j < sliding_window``; the cache is a ring of ``min(window,
   max_len)`` slots, position ``p`` in slot ``p mod slots``) and
-  ``"full_attention"`` (no positions at all, causal, ``max_len`` slots);
-  ``"gqa"`` there is the rotating layer itself. ``qk_norm`` puts an RMS norm
-  over each head's values of the queries and of the keys before the rotation.
+  ``"full_attention"`` (causal, ``max_len`` slots); ``"gqa"`` there is the
+  rotating layer itself. How a kind turns by position is the configuration's
+  (``rope_of``): by default plain RoPE at ``rope_theta``, and ``layer_rope``
+  gives a kind a rotation of its own or none (Command A's full layer takes no
+  positions at all; Mellum's turns by YaRN, times its attention factor).
+  ``qk_norm`` puts an RMS norm over each head's values of the queries and of
+  the keys before the rotation.
 - operator ``"conv"``: a gated short convolution in attention's place. ``B, C,
   u`` are thirds of one projection, ``z = B * u``, a causal depthwise filter
   of ``conv_taps`` taps runs over ``z`` channel by channel, and ``C`` gates
@@ -90,6 +94,17 @@ def yarn_mscale(factor: float, mscale: float) -> float:
 
 
 @dataclasses.dataclass(frozen=True)
+class LayerRope:
+    """How one kind of attention layer turns its queries and keys by
+    position: RoPE at ``theta``, with YaRN's frequencies where ``yarn`` is
+    given, cos and sin times ``scale`` (YaRN's attention factor)."""
+
+    theta: float
+    yarn: YarnScaling | None = None
+    scale: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class DecoderConfig:
     vocab_size: int = 32000
     hidden: int = 4096
@@ -133,6 +148,9 @@ class DecoderConfig:
     qk_norm: bool = False  # an RMS norm over each head of the queries and of the keys
     router_bias: bool = False  # the experts are chosen by score + expert_bias
     conv_taps: int = 0  # the length of a "conv" layer's filter, and of its state
+    #: (kind, rotation) where an attention kind turns otherwise than by plain
+    #: RoPE at ``rope_theta``; a rotation of ``None``: the kind takes no positions
+    layer_rope: tuple[tuple[str, LayerRope | None], ...] = ()
 
     @property
     def head_dim(self) -> int:
@@ -159,6 +177,12 @@ class DecoderConfig:
             return (self.attention,) * self.layers
         return tuple(kind.removesuffix("_attention") for kind in self.layer_types)
 
+    def rope_of(self, kind: str) -> LayerRope | None:
+        """The rotation of an attention layer of ``kind`` (``attention_pattern``'s
+        names), ``None`` where it takes no positions: ``layer_rope``'s where it
+        names the kind, else plain RoPE at ``rope_theta``."""
+        return dict(self.layer_rope).get(kind, LayerRope(self.rope_theta))
+
     @property
     def cache_width(self) -> int:
         """Values a token a layer keeps in an ``"mla"`` layer's cache."""
@@ -184,8 +208,15 @@ class DecoderConfig:
         experts this chip holds; ``lfm2_moe``: gated short convolutions
         beside grouped-query attention that rotates, whatever its
         ``layer_types`` string, and norms each head of its queries and keys,
-        sigmoid routing with a selection bias, no shared expert;
-        ``mistral`` / ``llama``)."""
+        sigmoid routing with a selection bias, no shared expert; ``mellum``:
+        sliding beside full grouped-query attention in a sequential block,
+        each kind turned by its own section of ``rope_parameters`` (the
+        full layer by YaRN times its ``attention_factor``), softmax routing
+        renormalised, no shared expert, an untied head; ``mistral`` /
+        ``llama``). What ``"full_attention"`` is follows the family: in
+        ``cohere2_moe`` a layer that takes no positions, in ``lfm2_moe`` the
+        one kind of attention, turned by plain RoPE, in ``mellum`` a layer
+        turned by YaRN."""
         kind = hf.get("model_type", "mistral")
         common = dict(
             vocab_size=hf["vocab_size"],
@@ -226,6 +257,7 @@ class DecoderConfig:
                 head_size=hf["head_dim"],
                 layer_types=layer_types,
                 sliding_window=hf["sliding_window"],
+                layer_rope=(("full", None),),  # this family's full layer takes no positions
                 norm="layer",
                 parallel_block=True,
                 rope_interleaved=True,
@@ -297,6 +329,40 @@ class DecoderConfig:
                 routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
                 router="sigmoid",
                 router_bias=True,
+            )
+        elif kind == "mellum":
+            require({
+                "attention_bias": False, "hidden_act": "silu", "tie_word_embeddings": False,
+                "use_sliding_window": True,
+            })
+            if set(hf["mlp_layer_types"][: hf["num_hidden_layers"]]) != {"sparse"}:
+                raise ValueError(f"mellum with mlp_layer_types={hf['mlp_layer_types']!r}: only 'sparse' is implemented")
+            layer_types = tuple(hf["layer_types"][: hf["num_hidden_layers"]])
+            if set(layer_types) - {"sliding_attention", "full_attention"} or len(layer_types) != hf["num_hidden_layers"]:
+                raise ValueError(f"mellum with layer_types={hf['layer_types']!r}: a kind a layer, sliding or full")
+            sections = hf["rope_parameters"]
+            full, sliding = sections.get("full_attention") or {}, sections.get("sliding_attention") or {}
+            if full.get("rope_type") != "yarn" or sliding.get("rope_type", "default") != "default":
+                raise ValueError(
+                    f"mellum with rope_parameters={sections!r}: only a 'yarn' full_attention section "
+                    "beside a 'default' sliding_attention section is implemented"
+                )
+            yarn = YarnScaling(
+                factor=full["factor"], original_max_len=full["original_max_position_embeddings"],
+                beta_fast=full.get("beta_fast", 32), beta_slow=full.get("beta_slow", 1),
+            )
+            common.update(
+                rope_theta=float(sliding["rope_theta"]),
+                head_size=hf["head_dim"],
+                layer_types=layer_types,
+                sliding_window=hf["sliding_window"],
+                layer_rope=(("full", LayerRope(
+                    float(full["rope_theta"]), yarn, float(full.get("attention_factor") or yarn_mscale(yarn.factor, 1.0)),
+                )),),
+                n_routed_experts=hf["num_experts"],
+                experts_per_token=hf["num_experts_per_tok"],
+                moe_intermediate=hf["moe_intermediate_size"],
+                norm_topk_prob=hf.get("norm_topk_prob", False),
             )
         elif kind not in ("mistral", "llama"):
             raise ValueError(f"no decoder layer for model_type {kind!r}")
@@ -703,12 +769,32 @@ def _grouped_query(q, k, v, q_slot, k_valid, k_slot, window, cfg):
     return jnp.moveaxis(out, 0, 1).reshape(b, t, heads * d)
 
 
+def prefill_window_scores(cfg: DecoderConfig, width: int, lengths) -> tuple[int, int, int]:
+    """Over the ``"sliding"`` layers, the (query, key) scores a prefill of
+    rows of ``lengths`` real tokens, left-padded to ``width``, evaluates for
+    those rows, the causal pairs of their real tokens, and the scores their
+    windows need. ``_grouped_query`` walks a row's whole square, ``width``
+    queries by ``width`` keys, and masks it; a row's real token ``i`` (from 0)
+    is causal with ``i + 1`` keys and needs ``min(i + 1, sliding_window)``."""
+    layers, window = cfg.attention_pattern.count("sliding"), cfg.sliding_window
+    if not layers:
+        return 0, 0, 0
+
+    def needed(n: int) -> int:
+        inside = min(n, window)
+        return inside * (inside + 1) // 2 + (n - inside) * window
+
+    walked = layers * len(lengths) * width * width
+    return walked, layers * sum(n * (n + 1) // 2 for n in lengths), layers * sum(needed(n) for n in lengths)
+
+
 def _gqa_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only, kind="gqa", wraps=False):
     """Grouped-query attention over ``h`` ``[b, t, hidden]``; ``kind``
-    ``"sliding"`` sees a window back, ``"full"`` turns nothing by position.
-    ``k_valid`` is the chunk's own where it is ``chunk_only``, else every
-    position's (``[b, max_len]``). A state that ``wraps`` is a ring shorter
-    than the positions the cache counts."""
+    ``"sliding"`` sees a window back, and each kind turns by position as
+    ``rope_of`` says. ``k_valid``
+    is the chunk's own where it is ``chunk_only``, else every position's
+    (``[b, max_len]``). A state that ``wraps`` is a ring shorter than the
+    positions the cache counts."""
     b, t, _ = h.shape
     q = (h @ lp["q_w"].astype(cfg.dtype)).reshape(b, t, cfg.heads, cfg.head_dim)
     k, v = jnp.split(h @ lp["kv_w"].astype(cfg.dtype), 2, axis=-1)
@@ -716,9 +802,10 @@ def _gqa_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only,
     v = v.reshape(b, t, cfg.kv_heads, cfg.head_dim)
     if cfg.qk_norm:  # over each head's own values, before the rotation
         q, k = rms_norm(q, lp["q_norm"], cfg.rms_eps), rms_norm(k, lp["k_norm"], cfg.rms_eps)
-    if kind != "full":
-        q = rope(q, q_pos, cfg.rope_theta, interleaved=cfg.rope_interleaved)
-        k = rope(k, q_pos, cfg.rope_theta, interleaved=cfg.rope_interleaved)
+    turn = cfg.rope_of(kind)
+    if turn is not None:
+        q = rope(q, q_pos, turn.theta, turn.yarn, turn.scale, interleaved=cfg.rope_interleaved)
+        k = rope(k, q_pos, turn.theta, turn.yarn, turn.scale, interleaved=cfg.rope_interleaved)
     window = cfg.sliding_window if kind == "sliding" else 0
     k_slot = None
     if state is not None:

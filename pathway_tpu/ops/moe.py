@@ -35,6 +35,16 @@ not gathered. An expert whose group straddles two blocks is read twice. A
 call no longer than a block (a decode step) is one product over all its rows
 and holds no loop.
 
+A grouped product moves an expert's weights in tiles. The compiler's own
+tile on a width is the largest power of two up to 512 that divides it, so a
+width of 7 x 128 (an expert 896 wide) or 9 x 256 (a hidden size of 2,304)
+moves in tiles of 64-128 KB where widths of multiples of 512 move in 512 KB,
+and a decode step that reads its touched experts runs far under the memory's
+peak. Where a width is not a multiple of 512 the product is given tiles of
+its own (``tiling``): on each width the widest multiple of 128 that divides
+it, up to ``TILE_MAX``, where that is 512 or more on both. Elsewhere the
+compiler's tiles stand.
+
 A chip that holds a share of a layer's experts says which (``held``: the
 first and how many; the weights it hands over are theirs): the router is as
 wide as all the experts and chooses among all, and a pair whose expert lies
@@ -48,6 +58,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.xla_metadata import set_xla_metadata
 
 
 def route_top_k(
@@ -116,6 +127,40 @@ def rows_walked(sizes: jax.Array, pairs: int) -> jax.Array:
     return (sizes.sum(dtype=jnp.int32) + (BLOCK_ROWS - 1)) // BLOCK_ROWS * BLOCK_ROWS
 
 
+#: the widest tile ``tiling`` gives a contracted or an output width: two such
+#: bfloat16 tiles of weights, double-buffered, stay inside a kernel's 16 MB of
+#: scoped vector memory beside a block's rows
+TILE_MAX = 1152
+
+
+def tiling(rows: int, contracted: int, out: int) -> str | None:
+    """The tiles (rows, contracted, output) of a grouped product of ``rows``
+    sorted rows by weights ``[contracted, out]``, as the compiler's
+    ``ragged_dot_tiling`` reads them, where a width is not a multiple of 512
+    and each width has a tile of 512 or more: the widest multiple of 128
+    that divides it, up to ``TILE_MAX``; rows: the largest power of two up to
+    512 that divides them, as the compiler's. ``None`` elsewhere: the
+    compiler's own tiles stand."""
+
+    def wide(n: int) -> int:
+        return max((t for t in range(128, min(n, TILE_MAX) + 1, 128) if n % t == 0), default=0)
+
+    tiles = wide(contracted), wide(out)
+    if (contracted % 512 == 0 and out % 512 == 0) or min(tiles) < 512:
+        return None
+    return f"{min(rows & -rows, 512)},{tiles[0]},{tiles[1]}"
+
+
+def grouped_product(x: jax.Array, w: jax.Array, groups: jax.Array) -> jax.Array:
+    """``lax.ragged_dot`` of ``x`` ``[rows, k]`` by ``w`` ``[experts, k, n]``
+    over ``groups``, float32, in ``tiling``'s tiles where it gives any."""
+    tiles = tiling(*x.shape, w.shape[-1])
+    if tiles is None:
+        return lax.ragged_dot(x, w, groups, preferred_element_type=jnp.float32)
+    with set_xla_metadata(ragged_dot_tiling=tiles):
+        return lax.ragged_dot(x, w, groups, preferred_element_type=jnp.float32)
+
+
 def routed_experts(
     h: jax.Array,  # [n, hidden]
     weights: jax.Array,  # [n, k] float32
@@ -151,10 +196,10 @@ def routed_experts(
         """The experts over sorted rows, each the token's ``rows_of`` names,
         of which each expert's group is ``groups`` long: ``[rows, hidden]``
         float32."""
-        gate_up = lax.ragged_dot(h[rows_of], gate_up_w.astype(h.dtype), groups, preferred_element_type=jnp.float32)
+        gate_up = grouped_product(h[rows_of], gate_up_w.astype(h.dtype), groups)
         gate, up = jnp.split(gate_up, 2, axis=-1)
         act = (jax.nn.silu(gate) * up).astype(h.dtype)
-        return lax.ragged_dot(act, down_w.astype(h.dtype), groups, preferred_element_type=jnp.float32)
+        return grouped_product(act, down_w.astype(h.dtype), groups)
 
     def combine(out: jax.Array, back: jax.Array, weights: jax.Array, computed: jax.Array | None) -> jax.Array:
         """Some tokens' rows of the sorted-order result ``out`` back in

@@ -105,10 +105,11 @@ class TpuPipelineChat(UDF):
     that took one, and through the shared experts with the rows that hold a
     real token: the programs get the mask, and ``chat.fetch`` counts the
     pairs left out, of the real tokens' pairs those whose expert this chip
-    holds, the sorted rows the grouped products were handed, and the bytes
+    holds, the sorted rows the grouped products were handed, the bytes
     of the cache and of the states in it whose size does not follow its
-    slots; attention, dense layers and the head are still paid for every row
-    of the cap): ``chat_prefill``
+    slots, and of the windowed layers' prefill the scores walked, the real
+    tokens' causal pairs and the scores the windows need; attention, dense layers and the head are still paid
+    for every row of the cap): ``chat_prefill``
     (the prompts into a cache of ``max_prompt_len + max_new_tokens`` slots,
     the head at each row's last position) and ``chat_decode`` (the remaining
     tokens through the cache). A prompt over ``max_prompt_len`` tokens keeps
@@ -277,6 +278,11 @@ class TpuPipelineChat(UDF):
                         pad_rows = max_batch_size - len(prompts)
                         expert_layers = load.shape[0]
                         pairs_per_token = expert_layers * cfg.experts_per_token
+                        # of the windowed layers' prefill, the scores walked, the real tokens'
+                        # causal pairs, and the scores the windows need
+                        walked_scores, causal_scores, needed_scores = _decoder.prefill_window_scores(
+                            cfg, width, [len(e) for e, _ in encoded]
+                        )
                         st.add(
                             d2h_bytes=sum(a.nbytes for a in jax.tree.leaves(fetched)),
                             expert_tokens_max=int(load.max(-1).sum()),
@@ -291,6 +297,9 @@ class TpuPipelineChat(UDF):
                             # what the call's cache holds: every layer's slots, a windowed layer's its ring
                             cache_bytes=sum(a.nbytes for a in jax.tree.leaves(cache.layers)),
                             state_bytes=state_bytes,
+                            window_scores_walked=walked_scores,
+                            window_scores_causal=causal_scores,
+                            window_scores_needed=needed_scores,
                         )
                     self.last_generation = {
                         "rows": len(prompts), "bucket": width, "tokens": toks, "logits": logits,
