@@ -19,7 +19,10 @@ in attention's place (``attention_pattern``):
   gives a kind a rotation of its own or none (Command A's full layer takes no
   positions at all; Mellum's turns by YaRN, times its attention factor).
   ``qk_norm`` puts an RMS norm over each head's values of the queries and of
-  the keys before the rotation.
+  the keys before the rotation. A chunk over its own keys (a prefill, a pass
+  without a cache) is walked in square tiles, each query tile visiting only
+  the key tiles its row's real queries need (``_walked_attention``); over a
+  cache's keys every score is evaluated and masked (``_grouped_query``).
 - operator ``"conv"``: a gated short convolution in attention's place. ``B, C,
   u`` are thirds of one projection, ``z = B * u``, a causal depthwise filter
   of ``conv_taps`` taps runs over ``z`` channel by channel, and ``C`` gates
@@ -67,6 +70,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from pathway_tpu.ops.moe import in_blocks, route_top_k, routed_experts, rows_walked
+from pathway_tpu.ops.prefill_attention import walked_attention
 from pathway_tpu.parallel.mesh import EXPERT_AXIS, MODEL_AXIS
 
 Params = dict
@@ -748,7 +752,15 @@ def _grouped_query(q, k, v, q_slot, k_valid, k_slot, window, cfg):
     """Softmax attention of ``q`` ``[b, t, heads, d]`` over ``k``, ``v`` ``[b, s,
     kv_heads, d]``, ``heads / kv_heads`` query heads a key head: ``[b, t, heads
     * d]``. The queries are walked in blocks, so that the scores held at
-    once are a block's."""
+    once are a block's.
+
+    This masked product evaluates every (query, key) score; a chunk over its
+    own keys takes ``_walked_attention`` instead. It stays where the keys are
+    the cache's: a decode step (one query: a row's keys are one tile, and
+    nothing is left to skip), a chunk after a cache whose ring has wrapped
+    (``k_slot``: the keys do not lie in slot order, so no tile bound is a
+    range of slots), and latent attention (``_mla_attention``, which keeps
+    its own product)."""
     b, t, heads, d = q.shape
     s, kv = k.shape[1], k.shape[2]
 
@@ -769,13 +781,58 @@ def _grouped_query(q, k, v, q_slot, k_valid, k_slot, window, cfg):
     return jnp.moveaxis(out, 0, 1).reshape(b, t, heads * d)
 
 
+#: the side of the square tiles a chunk over its own keys is walked in: at the
+#: answerers' buckets (768 to 2,560 slots) and head widths (64 and 128) the
+#: kernel ran fastest at 256 of 128, 256 and 512, but at two shapes where 512
+#: was as fast within 0.05 ms (``tools/prefill_attention_sweep.py``)
+PREFILL_TILE = 256
+
+
+def prefill_tile(t: int) -> int:
+    """The tile a chunk of ``t`` slots is walked in: ``PREFILL_TILE``, or the
+    whole chunk where it is shorter."""
+    return min(t, PREFILL_TILE)
+
+
+def prefill_attention_tiles(first, t: int, tile: int, window: int):
+    """Which key tiles each query tile of a chunk visits: the one bound the
+    walk (``_walked_attention``) and its counts (``prefill_window_scores``,
+    ``prefill_attention_scores``) share, on the device or on the host alike.
+
+    ``first`` ``[b]`` is each row's first real slot (rows are left-padded:
+    ``t`` less its real tokens; ``t`` for a row of padding). The chunk's
+    ``t`` slots are cut into ``ceil(t / tile)`` tiles of ``tile``; query
+    tile ``i`` visits key tiles ``lo[r, i]`` to ``lo[r, i] + count[r, i] -
+    1`` (``[b, tiles]`` each), and only those can hold a score one of its
+    real queries needs: none before the row's first real slot (padding),
+    none after the query tile (causal), and with a ``window`` none wholly
+    more than ``window - 1`` slots behind the tile's first real query. A
+    query tile that holds only padding visits nothing."""
+    xp = jnp if isinstance(first, jax.Array) else np
+    tiles = xp.arange(-(-t // tile))
+    first = first[:, None]
+    last_query = xp.minimum((tiles + 1) * tile, t) - 1
+    first_query = xp.maximum(tiles * tile, first)  # of the tile's real queries
+    from_slot = xp.maximum(first, first_query - (window - 1)) if window else xp.broadcast_to(first, first_query.shape)
+    lo = from_slot // tile
+    count = xp.where(first <= last_query, tiles - lo + 1, 0)
+    return lo, count
+
+
+def _walked_pairs(first, t: int, tile: int, window: int) -> int:
+    """The (query, key) scores the walk evaluates a head, over rows whose
+    first real slot is ``first``: ``tile x tile`` a key tile visited."""
+    _, count = prefill_attention_tiles(np.asarray(first, np.int64), t, tile, window)
+    return int(count.sum()) * tile * tile
+
+
 def prefill_window_scores(cfg: DecoderConfig, width: int, lengths) -> tuple[int, int, int]:
     """Over the ``"sliding"`` layers, the (query, key) scores a prefill of
     rows of ``lengths`` real tokens, left-padded to ``width``, evaluates for
     those rows, the causal pairs of their real tokens, and the scores their
-    windows need. ``_grouped_query`` walks a row's whole square, ``width``
-    queries by ``width`` keys, and masks it; a row's real token ``i`` (from 0)
-    is causal with ``i + 1`` keys and needs ``min(i + 1, sliding_window)``."""
+    windows need. The walk evaluates a visited key tile's every score
+    (``prefill_attention_tiles``); a row's real token ``i`` (from 0) is
+    causal with ``i + 1`` keys and needs ``min(i + 1, sliding_window)``."""
     layers, window = cfg.attention_pattern.count("sliding"), cfg.sliding_window
     if not layers:
         return 0, 0, 0
@@ -784,8 +841,42 @@ def prefill_window_scores(cfg: DecoderConfig, width: int, lengths) -> tuple[int,
         inside = min(n, window)
         return inside * (inside + 1) // 2 + (n - inside) * window
 
-    walked = layers * len(lengths) * width * width
+    first = [width - n for n in lengths]
+    walked = layers * _walked_pairs(first, width, prefill_tile(width), window)
     return walked, layers * sum(n * (n + 1) // 2 for n in lengths), layers * sum(needed(n) for n in lengths)
+
+
+def prefill_attention_scores(cfg: DecoderConfig, width: int, lengths, rows: int) -> tuple[int, int]:
+    """Over every grouped-query layer and every head, for a prefill of
+    ``rows`` rows of ``width`` slots of which the first ``len(lengths)``
+    hold ``lengths`` real tokens (the rest are padding): the scores the walk
+    evaluates, and those the masked product evaluated (``rows x heads x
+    width²`` a layer). Latent attention and the ``"conv"`` operator count
+    nothing."""
+    kinds = [kind for kind in cfg.attention_pattern if kind not in ("mla", "conv")]
+    first = [width - n for n in lengths] + [width] * (rows - len(lengths))
+    tile = prefill_tile(width)
+    walked = sum(
+        _walked_pairs(first, width, tile, cfg.sliding_window if kind == "sliding" else 0) for kind in kinds
+    )
+    return cfg.heads * walked, len(kinds) * rows * cfg.heads * width * width
+
+
+def _walked_attention(q, k, v, k_valid, window, scale, tile):
+    """Softmax attention of a chunk over its own keys, ``q`` ``[b, t, heads,
+    d]`` over ``k``, ``v`` ``[b, t, kv_heads, d]`` in slot order, walked in
+    tiles of ``tile`` queries by ``tile`` keys: each (row, query tile) visits
+    the key tiles ``prefill_attention_tiles`` gives it
+    (``ops.prefill_attention.walked_attention``, one kernel), under a float32
+    online softmax and ``_mask``'s mask inside a tile. A skipped tile holds
+    only scores the masked product sets to ``-1e30``, which give 0 in
+    float32: the same sums in another order. A query tile that visits
+    nothing gives zeros: a padding position's output is a key and a value in
+    the cache, never NaN. ``[b, t, heads * d]``."""
+    t = q.shape[1]
+    first = jnp.where(k_valid.any(1), jnp.argmax(k_valid, axis=1), t).astype(jnp.int32)
+    lo, count = prefill_attention_tiles(first, t, tile, window)
+    return walked_attention(q, k, v, k_valid, lo, count, window=window, scale=scale, tile=tile)
 
 
 def _gqa_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only, kind="gqa", wraps=False):
@@ -820,6 +911,8 @@ def _gqa_attention(h, lp, cfg, state, start, q_slot, q_pos, k_valid, chunk_only,
             if wraps:  # what each slot holds now, and whether that is a real token
                 k_slot = _ring_positions(slots, start)
                 k_valid = jnp.take(k_valid, jnp.maximum(k_slot, 0), axis=1) & (k_slot >= 0)
+    if t > 1 and (state is None or chunk_only):  # the keys are the chunk's own, in slot order
+        return _walked_attention(q, k, v, k_valid, window, cfg.softmax_scale, prefill_tile(t)), state
     out = _grouped_query(q, k, v, q_slot, k_valid, k_slot, window, cfg)
     return out, state
 

@@ -107,9 +107,13 @@ class TpuPipelineChat(UDF):
     pairs left out, of the real tokens' pairs those whose expert this chip
     holds, the sorted rows the grouped products were handed, the bytes
     of the cache and of the states in it whose size does not follow its
-    slots, and of the windowed layers' prefill the scores walked, the real
-    tokens' causal pairs and the scores the windows need; attention, dense layers and the head are still paid
-    for every row of the cap): ``chat_prefill``
+    slots, of the windowed layers' prefill the scores walked, the real
+    tokens' causal pairs and the scores the windows need, and of every
+    grouped-query layer's prefill the scores walked beside those of the
+    whole square; a prefill's grouped-query attention walks only the key
+    tiles a row's real queries need, and a row of padding none; the
+    projections, dense layers and the head are still paid for every row of
+    the cap): ``chat_prefill``
     (the prompts into a cache of ``max_prompt_len + max_new_tokens`` slots,
     the head at each row's last position) and ``chat_decode`` (the remaining
     tokens through the cache). A prompt over ``max_prompt_len`` tokens keeps
@@ -280,8 +284,14 @@ class TpuPipelineChat(UDF):
                         pairs_per_token = expert_layers * cfg.experts_per_token
                         # of the windowed layers' prefill, the scores walked, the real tokens'
                         # causal pairs, and the scores the windows need
+                        lengths = [len(e) for e, _ in encoded]
                         walked_scores, causal_scores, needed_scores = _decoder.prefill_window_scores(
-                            cfg, width, [len(e) for e, _ in encoded]
+                            cfg, width, lengths
+                        )
+                        # of every grouped-query layer's prefill, every row and head: the scores
+                        # the walk evaluates, and those the masked product evaluated
+                        attention_walked, attention_square = _decoder.prefill_attention_scores(
+                            cfg, width, lengths, max_batch_size
                         )
                         st.add(
                             d2h_bytes=sum(a.nbytes for a in jax.tree.leaves(fetched)),
@@ -300,6 +310,8 @@ class TpuPipelineChat(UDF):
                             window_scores_walked=walked_scores,
                             window_scores_causal=causal_scores,
                             window_scores_needed=needed_scores,
+                            attention_scores_walked=attention_walked,
+                            attention_scores_square=attention_square,
                         )
                     self.last_generation = {
                         "rows": len(prompts), "bucket": width, "tokens": toks, "logits": logits,

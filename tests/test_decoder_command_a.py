@@ -251,12 +251,16 @@ def test_a_chunk_of_several_tokens_into_a_ring_that_wraps_is_refused(model):
 
 
 def test_attention_in_blocks_of_queries_equals_attention_at_once(model, monkeypatch):
+    # a chunk into a cache attends over the cache's keys, the masked product's case (a chunk
+    # over its own keys is walked in tiles); a cache of the window's length never wraps
     cfg, params = model
-    ids = jnp.asarray(np.random.default_rng(7).integers(4, 512, (2, 16)), jnp.int32)
-    whole, _ = dec_mod.decoder_forward(params, ids, cfg)
-    monkeypatch.setattr(dec_mod, "ATTENTION_BLOCK_SCORES", 2 * 8 * 4 * 16)  # four queries a block
-    blocks, _ = dec_mod.decoder_forward(params, ids, cfg)
+    ids = jnp.asarray(np.random.default_rng(7).integers(4, 512, (2, 8)), jnp.int32)
+    whole, _ = dec_mod.decoder_forward(params, ids, cfg, dec_mod.init_cache(cfg, 2, 8))
+    monkeypatch.setattr(dec_mod, "ATTENTION_BLOCK_SCORES", 2 * 8 * 4 * 8)  # four queries a block
+    blocks, _ = dec_mod.decoder_forward(params, ids, cfg, dec_mod.init_cache(cfg, 2, 8))
     np.testing.assert_allclose(np.asarray(blocks), np.asarray(whole), atol=1e-5)
+    walked, _ = dec_mod.decoder_forward(params, ids, cfg)
+    np.testing.assert_allclose(np.asarray(blocks), np.asarray(walked), atol=1e-4)
 
 
 def _one_layer(cfg, params, layer: int):

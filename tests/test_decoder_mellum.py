@@ -261,9 +261,17 @@ def test_the_window_counts_are_the_hand_counts_and_the_chat_reports_them(model):
     # rows of 5, 8 and 11 real tokens in a prefill of 16: three sliding layers
     needed = (15 + 36 + (36 + 3 * 8)) * 3  # 1 + ... + 5; 1 + ... + 8; 1 + ... + 8 and three more of 8
     causal = (15 + 36 + 66) * 3  # 1 + ... + 5; 1 + ... + 8; 1 + ... + 11
+    # a chunk of 16 is one tile: a real row walks its whole square
     assert dec_mod.prefill_window_scores(cfg, 16, [5, 8, 11]) == (3 * 3 * 16 * 16, causal, needed)
-    assert dec_mod.prefill_window_scores(DecoderConfig.from_hf(PUBLISHED), 2048, [1700]) == (
-        21 * 2048 * 2048, 21 * 1700 * 1701 // 2, 21 * (1024 * 1025 // 2 + 676 * 1024))
+    # a row of 1,700 in the 2,048 bucket starts in tile 1 of 8 (slot 348); its query tiles 1-7 visit
+    # 1, 2, 3, 4, 5, 5, 5 tiles of 256 x 256: from the first real slot to themselves, no more than
+    # 1,023 slots behind their first query
+    published = DecoderConfig.from_hf(PUBLISHED)
+    assert dec_mod.prefill_window_scores(published, 2048, [1700]) == (
+        21 * 25 * 256 * 256, 21 * 1700 * 1701 // 2, 21 * (1024 * 1025 // 2 + 676 * 1024))
+    # every layer (the full one visits 1 + ... + 7 tiles) and head, two rows of padding beside it
+    assert dec_mod.prefill_attention_scores(published, 2048, [1700], 3) == (
+        32 * (21 * 25 + 7 * 28) * 256 * 256, 28 * 3 * 32 * 2048 * 2048)
     assert dec_mod.prefill_window_scores(dec_mod.tiny_decoder(), 16, [5]) == (0, 0, 0)
     from pathway_tpu.internals import tracing
     from pathway_tpu.xpacks.llm.llms import TpuPipelineChat
@@ -281,6 +289,9 @@ def test_the_window_counts_are_the_hand_counts_and_the_chat_reports_them(model):
     counts = fetch_counts(DecoderConfig.from_hf(TINY), prompts)
     assert (counts["window_scores_walked"], counts["window_scores_causal"], counts["window_scores_needed"]) == (
         3 * 3 * 16 * 16, causal, needed)
+    # four layers of four heads: three real rows walk their one tile, the padding row none
+    assert (counts["attention_scores_walked"], counts["attention_scores_square"]) == (
+        4 * 4 * 3 * 16 * 16, 4 * 4 * 4 * 16 * 16)
     # a cache of rings: three layers of 8 slots beside one of 35, four rows, bfloat16
     assert counts["cache_bytes"] == 2 * 4 * 2 * 16 * 2 * (3 * WINDOW + 35)
     assert fetch_counts("tiny", prompts)["window_scores_walked"] == 0

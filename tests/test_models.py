@@ -70,7 +70,9 @@ def test_cross_encoder_score():
 
 
 def test_decoder_cache_matches_full_forward():
-    cfg = tiny_decoder()
+    # float32: the pass without a cache walks its keys in tiles under an online softmax, the
+    # chunk into the cache masks the cache's keys, and the two differ by the order of their sums
+    cfg = dataclasses.replace(tiny_decoder(), dtype=jnp.float32)
     params = init_decoder_params(jax.random.key(2), cfg)
     rng = np.random.default_rng(1)
     ids = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 10)), jnp.int32)
