@@ -41,9 +41,12 @@ width of 7 x 128 (an expert 896 wide) or 9 x 256 (a hidden size of 2,304)
 moves in tiles of 64-128 KB where widths of multiples of 512 move in 512 KB,
 and a decode step that reads its touched experts runs far under the memory's
 peak. Where a width is not a multiple of 512 the product is given tiles of
-its own (``tiling``): on each width the widest multiple of 128 that divides
-it, up to ``TILE_MAX``, where that is 512 or more on both. Elsewhere the
-compiler's tiles stand.
+its own (``tiling``), chosen by their bytes: of the multiples of 128 that
+divide each width, the pair whose tile of weights is at most 3 MiB with the
+widest narrower side, where that is 512 or more (an expert 1,408 wide,
+11 x 128, moves whole); and as many rows a tile as leave the kernel's tiles,
+double-buffered, within 12 MiB of its 16 MiB of scoped vector memory.
+Elsewhere the compiler's tiles stand.
 
 A chip that holds a share of a layer's experts says which (``held``: the
 first and how many; the weights it hands over are theirs): the router is as
@@ -127,28 +130,37 @@ def rows_walked(sizes: jax.Array, pairs: int) -> jax.Array:
     return (sizes.sum(dtype=jnp.int32) + (BLOCK_ROWS - 1)) // BLOCK_ROWS * BLOCK_ROWS
 
 
-#: the widest tile ``tiling`` gives a contracted or an output width: two such
-#: bfloat16 tiles of weights, double-buffered, stay inside a kernel's 16 MB of
-#: scoped vector memory beside a block's rows
-TILE_MAX = 1152
+#: the bytes of tiles ``tiling`` lets a grouped product's kernel hold: a tile
+#: of rows and one of an expert's weights (bfloat16) and one of results
+#: (float32), each double-buffered, in three quarters of the kernel's 16 MiB
+#: of scoped vector memory; a tile of weights is at most a quarter of it
+TILE_BYTES = 12 << 20
 
 
 def tiling(rows: int, contracted: int, out: int) -> str | None:
     """The tiles (rows, contracted, output) of a grouped product of ``rows``
-    sorted rows by weights ``[contracted, out]``, as the compiler's
-    ``ragged_dot_tiling`` reads them, where a width is not a multiple of 512
-    and each width has a tile of 512 or more: the widest multiple of 128
-    that divides it, up to ``TILE_MAX``; rows: the largest power of two up to
-    512 that divides them, as the compiler's. ``None`` elsewhere: the
-    compiler's own tiles stand."""
-
-    def wide(n: int) -> int:
-        return max((t for t in range(128, min(n, TILE_MAX) + 1, 128) if n % t == 0), default=0)
-
-    tiles = wide(contracted), wide(out)
-    if (contracted % 512 == 0 and out % 512 == 0) or min(tiles) < 512:
+    sorted rows by bfloat16 weights ``[contracted, out]``, as the compiler's
+    ``ragged_dot_tiling`` reads them, where a width is not a multiple of 512:
+    of the multiples of 128 that divide each width, the pair whose tile of
+    weights stays within a quarter of ``TILE_BYTES`` with the widest
+    narrower side, then the larger, where that side is 512 or more; rows:
+    the largest power of two up to 512 that divides them, as the
+    compiler's, halved until the kernel's tiles fit ``TILE_BYTES``. ``None``
+    elsewhere: the compiler's own tiles stand."""
+    if contracted % 512 == 0 and out % 512 == 0:
         return None
-    return f"{min(rows & -rows, 512)},{tiles[0]},{tiles[1]}"
+
+    def sides(n: int) -> list[int]:
+        return [t for t in range(128, n + 1, 128) if n % t == 0]
+
+    pairs = [(c, o) for c in sides(contracted) for o in sides(out) if c * o * 2 <= TILE_BYTES // 4]
+    c, o = max(pairs, key=lambda p: (min(p), p[0] * p[1]), default=(0, 0))
+    if min(c, o) < 512:
+        return None
+    r = min(rows & -rows, 512)
+    while 2 * (r * c * 2 + c * o * 2 + r * o * 4) > TILE_BYTES:
+        r //= 2
+    return f"{r},{c},{o}"
 
 
 def grouped_product(x: jax.Array, w: jax.Array, groups: jax.Array) -> jax.Array:

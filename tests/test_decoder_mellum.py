@@ -207,7 +207,11 @@ def test_the_softmax_top_8_of_64_weights_sum_to_one_and_are_the_references():
     (2048, 2304, 1792, "512,1152,896"),  # a prefill's block of sorted rows
     (2048, 2048, 3072, None),  # LFM2's widths, multiples of 512: the compiler's tiles
     (64, 4096, 8192, None),  # Command A+'s
-    (64, 2048, 2816, None),  # DeepSeek-V2-Lite's: no tile of 512 or more divides 2,816
+    (64, 2048, 2816, "64,1024,1408"),  # DeepSeek-V2-Lite's: 2,816 halves into 1,408 = 11 x 128
+    (48, 2048, 2816, "16,1024,1408"),  # its gate | up in a decode step (8 tokens x 6 choices)
+    (48, 1408, 2048, "16,1408,1024"),  # and its down: the expert's whole width in one tile
+    (2048, 2048, 2816, "256,1024,1408"),  # a prefill's block: 512 rows a tile would not fit beside them
+    (2048, 1408, 2048, "256,1408,1024"),
     (16, 64, 64, None),  # a test's
 ])
 def test_the_grouped_product_takes_wide_tiles_where_a_width_is_no_multiple_of_512(rows, contracted, out, tiles):

@@ -2,9 +2,14 @@
 blocks, as many as they fill: against a plain loop over the experts in
 float32, at shapes longer than a block and at every edge of the block count;
 at a decode step's shape the program holds no loop; and the count of the rows
-walked is the blocks', from the kernel's own arithmetic to ``chat.fetch``."""
+walked is the blocks', from the kernel's own arithmetic to ``chat.fetch``;
+and the tiles ``tiling`` gives each answerer's products, which change where
+the chip runs them and not what they give."""
 
 from __future__ import annotations
+
+import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -256,3 +261,73 @@ def test_a_row_of_padding_goes_through_no_shared_expert_where_the_call_is_walked
     again, *_ = dec_mod._experts_layer(poisoned, lp, cfg, counted)
     np.testing.assert_array_equal(np.asarray(again)[real], np.asarray(blocks)[real])
     assert not np.asarray(again)[2:].any()
+
+
+# -- the tiles of a grouped product -------------------------------------------
+
+CONFIGS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "configs")
+
+#: the tiles of each answerer's products (a decode step's gate | up and down,
+#: then a prefill block's): ``None`` is the compiler's own; DeepSeek-V2-Lite's
+#: expert, 1,408 = 11 x 128 wide, moves whole, the others as they did before
+TILES = {
+    "command-a-plus-rag-answerer": (None, None, None, None),
+    "dsv2lite-rag-answerer": ("16,1024,1408", "16,1408,1024", "256,1024,1408", "256,1408,1024"),
+    "lfm2-24b-a2b-rag-answerer": (None, None, None, None),
+    "mellum2-12b-a2.5b-rag-answerer": ("64,1152,896", "64,896,1152", "512,1152,896", "512,896,1152"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TILES))
+def test_each_answerers_products_take_their_pinned_tiles(name):
+    with open(os.path.join(CONFIGS, f"{name}.json")) as fh:
+        config = json.load(fh)
+    cfg = dec_mod.DecoderConfig.from_hf(config)
+    tree = jax.eval_shape(lambda: dec_mod.init_decoder_params(jax.random.key(0), cfg, jnp.bfloat16))
+    layer = next(lp for lp, kind in zip(tree["layers"], cfg.layer_pattern) if kind == "experts")
+    products = [layer["experts_gate_w"].shape[1:], layer["experts_down_w"].shape[1:]]
+    decode = config["chat"]["max_batch_size"] * cfg.experts_per_token  # a step's pairs: one product
+    assert not moe.in_blocks(decode)
+    got = tuple(moe.tiling(rows, *product) for rows in (decode, moe.BLOCK_ROWS) for product in products)
+    assert got == TILES[name]
+
+
+#: DeepSeek-V2-Lite's widths (hidden 2,048, an expert 1,408 wide) over 4 of its 64 experts, 2 chosen a token
+DSV2_HIDDEN, DSV2_WIDTH, DSV2_EXPERTS, DSV2_K = 2048, 1408, 4, 2
+
+
+@pytest.fixture(scope="module")
+def dsv2_experts():
+    rng = np.random.default_rng(48)
+    shapes = (DSV2_EXPERTS, DSV2_HIDDEN, 2 * DSV2_WIDTH), (DSV2_EXPERTS, DSV2_WIDTH, DSV2_HIDDEN)
+    return tuple(jnp.asarray(rng.standard_normal(s, np.float32) * 0.02, jnp.bfloat16) for s in shapes)
+
+
+@pytest.mark.parametrize("tokens", [8, 40])  # a decode step; a call walked in blocks
+def test_at_deepseek_v2_lites_widths_the_tiled_products_give_the_untiled_ones(tokens, dsv2_experts, monkeypatch):
+    """Each product carries the rule's tiles and gives what
+    ``lax.ragged_dot`` gives without them, and so does the layer."""
+    gate_up, down = dsv2_experts
+    k = DSV2_K
+    monkeypatch.setattr(moe, "BLOCK_ROWS", 64)  # 40 tokens' 80 pairs in two blocks, as a prefill's are
+    rng = np.random.default_rng(tokens)
+    h = jnp.asarray(rng.standard_normal((tokens, DSV2_HIDDEN), np.float32), jnp.bfloat16)
+    choice = jnp.asarray(np.stack([rng.choice(DSV2_EXPERTS, k, replace=False) for _ in range(tokens)]), jnp.int32)
+    weights = jnp.asarray(rng.uniform(0.05, 0.3, (tokens, k)), jnp.float32)
+    counted = jnp.arange(tokens) >= 2  # two tokens of padding
+    rows = min(tokens * k, moe.BLOCK_ROWS)
+    groups = moe.expert_sizes(choice[: rows // k], DSV2_EXPERTS)
+    for x, w in ((h[: rows // k].repeat(k, 0), gate_up), (jnp.ones((rows, DSV2_WIDTH), jnp.bfloat16), down)):
+        assert moe.tiling(*x.shape, w.shape[-1]) is not None
+        assert "ragged_dot_tiling" in jax.jit(moe.grouped_product).lower(x, w, groups).as_text()
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(moe.grouped_product)(x, w, groups)),
+            np.asarray(jax.lax.ragged_dot(x, w, groups, preferred_element_type=jnp.float32)),
+        )
+    args = (h, weights, choice, gate_up, down, counted)
+    tiled, sizes = jax.jit(moe.routed_experts)(*args)
+    monkeypatch.setattr(moe, "tiling", lambda *shape: None)
+    untiled, untiled_sizes = jax.jit(moe.routed_experts)(*args)
+    np.testing.assert_array_equal(np.asarray(tiled), np.asarray(untiled))
+    np.testing.assert_array_equal(np.asarray(sizes), np.asarray(untiled_sizes))
+    assert np.asarray(tiled)[2:].any() and not np.asarray(tiled)[:2].any()
